@@ -142,7 +142,7 @@ class DeviceDatasetCache(object):
         self._per_dev_bytes = 0
         self._max_bytes = (max_bytes if max_bytes is not None
                            else _default_budget(jax))
-        self._take = None
+        self._take = {}          # column sharding -> jitted row gather
         self._streaming = False
         self._materialized = False
         self._overflow_msg = None
@@ -196,6 +196,7 @@ class DeviceDatasetCache(object):
                                       for sb in self._superbatches),
                 'total_batches': self._total_batches,
                 'nbytes': self._bytes,
+                'max_bytes_per_device': self._max_bytes,
                 'hits': self._hits,
                 'evictions': self._evictions,
                 'fill_paused': self._fill_paused,
@@ -343,13 +344,8 @@ class DeviceDatasetCache(object):
         """Per-field concat of one pending run into a superbatch. The
         transient double-hold is this run only — the per-batch arrays
         free as soon as the caller drops its list."""
-        # NOT jnp.concatenate: this jaxlib's SPMD concat lowering sums
-        # replicas on partially-replicated meshes (see
-        # parallel.mesh.replica_safe_concat); equal-size batches are
-        # already a hard requirement here, so the stack+reshape form
-        # always applies.
-        from petastorm_tpu.parallel.mesh import replica_safe_concat
-        jit_concat = self._jax.jit(lambda *xs: replica_safe_concat(xs))
+        import jax.numpy as jnp
+        jit_concat = self._jax.jit(lambda *xs: jnp.concatenate(xs))
         columns = {
             name: jit_concat(*[getattr(b, name) for b in batches])
             for name in self._nt_type._fields}
@@ -374,24 +370,32 @@ class DeviceDatasetCache(object):
         return None
 
     def _sb_batch(self, sb, batch_index, perm):
-        """One batch out of a resident superbatch — a plain slice in
-        replay order, a jitted gather under the epoch permutation."""
-        jax = self._jax
-        rows = sb.rows
-        local = batch_index - sb.start
+        """One batch out of a resident superbatch: its rows in replay
+        order, or the epoch permutation's rows for that slot — either way
+        one jitted gather."""
+        import jax.numpy as jnp
+
+        start = (batch_index - sb.start) * sb.rows
         if perm is None:
-            return self._nt_type(
-                **{name: col[local * rows:(local + 1) * rows]
-                   for name, col in sb.columns.items()})
-        if self._take is None:
-            # Donation off: the column arrays are reused every epoch. The
-            # gather keeps the column's sharding layout for the output
-            # batch.
-            import jax.numpy as jnp
-            self._take = jax.jit(lambda col, idx: jnp.take(col, idx, axis=0))
-        idx = jax.lax.dynamic_slice_in_dim(perm, local * rows, rows)
-        return self._nt_type(**{name: self._take(col, idx)
+            idx = start + jnp.arange(sb.rows)
+        else:
+            idx = self._jax.lax.dynamic_slice_in_dim(perm, start, sb.rows)
+        return self._nt_type(**{name: self._gather(col, idx)
                                 for name, col in sb.columns.items()})
+
+    def _gather(self, col, idx):
+        """Rows ``idx`` of a resident column, laid out like the column.
+        The output sharding is explicit: left to itself XLA returns a
+        gather over the sharded batch dim replicated, and every chip would
+        hold every batch whole. Donation off: the column arrays are reused
+        every epoch."""
+        take = self._take.get(col.sharding)
+        if take is None:
+            import jax.numpy as jnp
+            take = self._take[col.sharding] = self._jax.jit(
+                lambda c, i: jnp.take(c, i, axis=0),
+                out_shardings=col.sharding)
+        return take(col, idx)
 
     def _epoch_perms(self, epoch_index):
         """Per-superbatch row permutations for one epoch (None each when
@@ -486,7 +490,7 @@ class DeviceDatasetCache(object):
         governor pool. The cache is finished afterwards — ``epoch()``
         raises; build a new cache to train on."""
         self._drop_all()
-        self._take = None
+        self._take = {}
         self._materialized = False
         self._cleared = True
         if self._mem_handle is not None:
@@ -513,9 +517,16 @@ def _per_device_nbytes(batch):
 
 
 def _default_budget(jax):
-    try:
-        stats = jax.devices()[0].memory_stats()
-        limit = stats.get('bytes_limit') if stats else None
-        return int(limit * _DEFAULT_HBM_FRACTION) if limit else 0
-    except Exception:  # noqa: BLE001 - backends without memory_stats
+    """40% of the first device's HBM; 0 (no limit) on a backend that
+    reports no memory stats, which the CPU backend does not. A TPU that
+    reports none is an error: an unbounded cache there ends in an HBM
+    OOM in the middle of training."""
+    device = jax.devices()[0]
+    limit = (device.memory_stats() or {}).get('bytes_limit')
+    if not limit:
+        if device.platform == 'tpu':
+            raise RuntimeError(
+                'TPU device {} reports no memory_stats()["bytes_limit"]; '
+                'pass max_bytes= to DeviceDatasetCache'.format(device))
         return 0
+    return int(limit * _DEFAULT_HBM_FRACTION)

@@ -78,8 +78,7 @@ def _ring_attention_local(q, k, v, axis_name, causal, varying_axes):
     # so shard_map's vma check requires the initial carry be cast varying
     # over every mesh axis the inputs are mapped over (seq + any batch/head
     # axes), not just the ring axis.
-    from petastorm_tpu.models.shard_map_compat import pcast_varying
-    out0, max0, denom0 = (pcast_varying(x, varying_axes)
+    out0, max0, denom0 = (jax.lax.pcast(x, varying_axes, to='varying')
                           for x in (out0, max0, denom0))
     carry = (k, v, my_index, out0, max0, denom0)
     (_, _, _, out, _, denom), _ = jax.lax.scan(step, carry, None,
@@ -106,14 +105,13 @@ def ring_self_attention(q, k, v, mesh, seq_axis, causal=False,
     spec = PartitionSpec(batch_axis, seq_axis, head_axis, None)
     varying = tuple(a for a in (batch_axis, seq_axis, head_axis)
                     if a is not None)
-    from petastorm_tpu.models.shard_map_compat import shard_map
-    fn = shard_map(partial(_ring_attention_local, axis_name=seq_axis,
-                           causal=causal, varying_axes=varying),
-                   mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    fn = jax.shard_map(partial(_ring_attention_local, axis_name=seq_axis,
+                               causal=causal, varying_axes=varying),
+                       mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return fn(q, k, v)
 
 
-def _a2a_attention_local(q, k, v, axis_name, causal):
+def _a2a_attention_local(q, k, v, axis_name, causal, interpret):
     """Per-device Ulysses body: trade sequence shards for head shards.
 
     In: ``[B, T/n, H_local, D]`` (sequence-sharded). Two ``all_to_all``s
@@ -131,18 +129,18 @@ def _a2a_attention_local(q, k, v, axis_name, causal):
     qkv = jax.lax.all_to_all(jnp.stack((q, k, v)), axis_name,
                              split_axis=3, concat_axis=2, tiled=True)
     q, k, v = qkv[0], qkv[1], qkv[2]
-    # Full sequence locally: the Pallas flash kernel gives O(T) memory on
-    # TPU (off-TPU it falls back to dense — fine for tests); the causal mask
-    # needs no global-position bookkeeping because T is whole here.
+    # Full sequence locally: the Pallas flash kernel gives O(T) memory; the
+    # causal mask needs no global-position bookkeeping because T is whole
+    # here.
     from petastorm_tpu.ops.flash_attention import flash_attention
-    out = flash_attention(q, k, v, causal=causal)
+    out = flash_attention(q, k, v, causal=causal, interpret=interpret)
     # [B, T, H/n, D] -> [B, T/n, H, D]
     return jax.lax.all_to_all(out.astype(q.dtype), axis_name, split_axis=1,
                               concat_axis=2, tiled=True)
 
 
 def a2a_self_attention(q, k, v, mesh, seq_axis, causal=False,
-                       batch_axis=None, head_axis=None):
+                       batch_axis=None, head_axis=None, interpret=False):
     """Ulysses-style sequence parallelism: all-to-all over ``mesh[seq_axis]``
     re-shards sequence<->heads so each device runs exact attention on the
     full sequence for ``H/n`` heads, then shards the sequence back.
@@ -156,12 +154,19 @@ def a2a_self_attention(q, k, v, mesh, seq_axis, causal=False,
     :param q, k, v: ``[B, T, H, D]`` global arrays, sequence-shardable over
         ``seq_axis``. Heads (per ``head_axis`` shard, if tensor parallelism
         is also active) must divide by ``mesh.shape[seq_axis]``.
+    :param interpret: passed to :func:`~petastorm_tpu.ops.flash_attention.
+        flash_attention`, the per-device block compute: the compiled kernel
+        needs a TPU; ``True`` runs it in the Pallas interpreter (CPU tests).
     """
     spec = PartitionSpec(batch_axis, seq_axis, head_axis, None)
-    from petastorm_tpu.models.shard_map_compat import shard_map
-    fn = shard_map(partial(_a2a_attention_local, axis_name=seq_axis,
-                           causal=causal),
-                   mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    # The Pallas interpreter evaluates the kernel body op by op and its
+    # scratch buffers carry no varying-axes type, which the static vma check
+    # rejects (jax 0.9.0 says so itself); the compiled call is one opaque
+    # primitive and keeps the check.
+    fn = jax.shard_map(partial(_a2a_attention_local, axis_name=seq_axis,
+                               causal=causal, interpret=interpret),
+                       mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=not interpret)
     return fn(q, k, v)
 
 

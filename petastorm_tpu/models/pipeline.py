@@ -24,9 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from petastorm_tpu.models.shard_map_compat import \
-    shard_map as _compat_shard_map
-
 
 def _stage_index(axis_name):
     return jax.lax.axis_index(axis_name)
@@ -66,7 +63,7 @@ def pipeline_apply(stage_fn, stage_params, x, mesh, pipe_axis='pipe',
     params_spec = jax.tree_util.tree_map(
         lambda p: pipeline_param_spec((), p, mesh), stage_params)
 
-    @partial(_compat_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(params_spec, PartitionSpec()),
              out_specs=PartitionSpec(pipe_axis),
              check_vma=False)

@@ -4,7 +4,8 @@ The long-context flagship: the same flax module runs with
 
 * ``attention='dense'`` — reference XLA attention (small inputs, tests),
 * ``attention='flash'`` — the Pallas blocked kernel
-  (:mod:`petastorm_tpu.ops.flash_attention`), no ``[T, T]`` materialization,
+  (:mod:`petastorm_tpu.ops.flash_attention`), no ``[T, T]`` materialization;
+  compiled, so it needs a TPU,
 * ``attention='ring'`` — sequence parallelism: q/k/v sharded over a mesh
   axis, kv blocks rotating over ICI
   (:mod:`petastorm_tpu.models.attention`), for contexts longer than one
@@ -12,7 +13,10 @@ The long-context flagship: the same flax module runs with
 * ``attention='a2a'`` — Ulysses-style sequence parallelism: two
   ``all_to_all``s re-shard sequence<->heads around full-sequence local
   attention (fewest collectives when heads are plentiful; needs
-  ``heads % mesh[seq_axis] == 0``).
+  ``heads % mesh[seq_axis] == 0``); the local attention is the flash kernel.
+
+``'flash:interpret'`` / ``'a2a:interpret'`` run the same kernels in the
+Pallas interpreter — what CPU tests and dry runs name when they mean it.
 
 TPU-first choices: bfloat16 activations with float32 params, pre-LN
 residual blocks, static shapes throughout, and the sequence axis is the
@@ -20,16 +24,19 @@ only thing that changes between single-chip and pod runs — the module code
 is identical (mesh + shardings, XLA inserts the collectives).
 """
 
+from functools import partial
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 
 class MultiHeadAttention(nn.Module):
     num_heads: int
     attention: str = 'dense'            # dense | flash | ring | a2a
-    causal: bool = True
+    causal: bool = True                 # (flash/a2a take ':interpret')
     mesh: Any = None                    # required for 'ring' / 'a2a'
     seq_axis: Optional[str] = None      # mesh axis name for 'ring' / 'a2a'
     batch_axis: Optional[str] = 'data'  # mesh axis carrying the batch (sp)
@@ -50,33 +57,56 @@ class MultiHeadAttention(nn.Module):
 
         q, k, v = proj('query'), proj('key'), proj('value')   # [B, T, H, Dh]
 
-        if self.attention in ('ring', 'a2a'):
+        backend, _, mode = self.attention.partition(':')
+        interpret = mode == 'interpret'
+        if mode and not (interpret and backend in ('flash', 'a2a')):
+            raise ValueError('unknown attention {!r}'.format(self.attention))
+
+        def usable(axis, dim):
+            # A configured mesh axis carries a dim only when the mesh has
+            # it AND it evenly divides the (static) dim, so e.g. an init
+            # trace with batch 1 falls back to replication for that trace
+            # alone.
+            return (axis if axis in self.mesh.axis_names
+                    and dim % self.mesh.shape[axis] == 0 else None)
+
+        if backend in ('ring', 'a2a'):
             if self.mesh is None or self.seq_axis is None:
                 raise ValueError("attention={!r} needs mesh= and seq_axis="
                                  .format(self.attention))
             from petastorm_tpu.models.attention import (a2a_self_attention,
                                                         ring_self_attention)
-            # Keep batch/head shards local inside the shard_map — each
-            # configured axis is used only when present in the mesh AND it
-            # evenly divides the (static) dim, so e.g. an init trace with
-            # batch 1 falls back to replication for that trace alone.
-            axes = set(self.mesh.axis_names)
-
-            def usable(axis, dim):
-                return (axis if axis in axes
-                        and dim % self.mesh.shape[axis] == 0 else None)
-
+            # Keep batch/head shards local inside the shard_map.
             batch_axis = usable(self.batch_axis, q.shape[0])
             head_axis = usable(self.head_axis, self.num_heads)
-            sp_attention = (ring_self_attention if self.attention == 'ring'
-                            else a2a_self_attention)
+            if backend == 'ring':
+                sp_attention = ring_self_attention
+            else:
+                sp_attention = partial(a2a_self_attention,
+                                       interpret=interpret)
             out = sp_attention(q, k, v, self.mesh, self.seq_axis,
                                causal=self.causal, batch_axis=batch_axis,
                                head_axis=head_axis)
-        elif self.attention == 'flash':
+        elif backend == 'flash':
             from petastorm_tpu.ops.flash_attention import flash_attention
-            out = flash_attention(q, k, v, causal=self.causal)
-        elif self.attention == 'dense':
+            attend = partial(flash_attention, causal=self.causal,
+                             interpret=interpret)
+            if self.mesh is not None:
+                # A Pallas call is opaque to the SPMD partitioner, which
+                # would gather the whole batch onto every device to run it.
+                # Attention is elementwise over batch and heads: map the
+                # kernel over their shards instead.
+                spec = PartitionSpec(usable(self.batch_axis, q.shape[0]),
+                                     None,
+                                     usable(self.head_axis, self.num_heads),
+                                     None)
+                # check_vma: see models.attention.a2a_self_attention.
+                attend = jax.shard_map(attend, mesh=self.mesh,
+                                       in_specs=(spec, spec, spec),
+                                       out_specs=spec,
+                                       check_vma=not interpret)
+            out = attend(q, k, v)
+        elif backend == 'dense':
             from petastorm_tpu.models.attention import dense_attention
             out = dense_attention(q, k, v, causal=self.causal)
         else:

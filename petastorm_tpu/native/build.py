@@ -14,12 +14,14 @@ import os
 import subprocess
 import tempfile
 import threading
+import time
 
 logger = logging.getLogger(__name__)
 
 _SRC_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), 'src')
 _LOCK = threading.Lock()
 _LOADED = {}
+_REPORT = {}
 
 
 def native_cache_dir():
@@ -61,6 +63,7 @@ def build_and_load(name, sources, compile_flags=None, link_flags=None):
 
         key = _build_key(srcs, compile_flags, link_flags)
         out_path = os.path.join(native_cache_dir(), 'lib{}-{}.so'.format(name, key))
+        t0 = time.perf_counter()
         if not os.path.exists(out_path):
             # Cross-process lock: N spawned workers hitting a cold cache
             # should compile once, not N times.
@@ -71,7 +74,18 @@ def build_and_load(name, sources, compile_flags=None, link_flags=None):
                     _compile(srcs, out_path, compile_flags, link_flags)
         lib = ctypes.CDLL(out_path)
         _LOADED[name] = lib
+        _REPORT[name] = {'source_hash': key, 'path': out_path,
+                         'build_or_wait_s': round(time.perf_counter() - t0, 3)}
         return lib
+
+
+def build_report():
+    """``{library: {'source_hash', 'path', 'build_or_wait_s'}}`` for every
+    library this process loaded: the content hash its ``.so`` is keyed by
+    and the seconds this process spent compiling it or waiting for another
+    process that was (0 when the cache already held it)."""
+    with _LOCK:
+        return {name: dict(rec) for name, rec in _REPORT.items()}
 
 
 class NativeBuildError(RuntimeError):

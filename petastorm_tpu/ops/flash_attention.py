@@ -15,10 +15,11 @@ stacks get from fused CUDA kernels — rebuilt the TPU way.
 
 Composes with :mod:`petastorm_tpu.models.attention`: ring attention shards
 the sequence across a mesh axis and rotates kv blocks over ICI; within a
-device, this kernel is the block compute. On non-TPU backends
-``flash_attention`` falls back to the pure-XLA reference; ``interpret=True``
-runs the Pallas interpreter instead — how the tests validate the kernels
-without TPU hardware.
+device, this kernel is the block compute. ``flash_attention`` always runs
+the Pallas kernels: compiled by Mosaic on a TPU, or — only when the caller
+passes ``interpret=True`` — in the Pallas interpreter (how the CPU tests
+validate the numerics). Asking for the compiled kernel on any other backend
+raises; nothing substitutes the dense reference silently.
 """
 
 import functools
@@ -36,20 +37,22 @@ def _mosaic_params(interpret):
     """Compiler hints for the compiled path: all three kernels carry their
     online-softmax / accumulator state only along the LAST grid axis, so the
     first two axes (batch*heads, outer block) are declared parallel —
-    Mosaic may then reorder/pipeline them freely. Interpret mode (CI) takes
-    no TPU compiler params."""
+    Mosaic may then reorder/pipeline them freely. Interpret mode takes no
+    TPU compiler params."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
-    # Renamed TPUCompilerParams -> CompilerParams across jax releases; the
-    # tests only exercise interpret=True, so guard the compiled-only path.
-    params_cls = getattr(pltpu, 'CompilerParams',
-                         getattr(pltpu, 'TPUCompilerParams', None))
-    if params_cls is None:
-        return {}
-    return {'compiler_params': params_cls(
+    return {'compiler_params': pltpu.CompilerParams(
         dimension_semantics=('parallel', 'parallel', 'arbitrary'))}
+
+
+def _out_struct(shape, dtype, like):
+    """``ShapeDtypeStruct`` for a kernel output that varies over the same
+    mesh axes as the input ``like``: inside ``jax.shard_map`` (the a2a
+    sequence-parallel path) ``pallas_call`` refuses an ``out_shape`` that
+    does not say so; outside one the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _block_mask(qi, ki, block_q, block_k, seq_len, causal):
@@ -192,7 +195,7 @@ def _flash_bhtd(q, k, v, seq_len, causal, block_q, block_k, interpret,
     # o/lse blocks ignore ki: revisited across the kv axis, written at the
     # last ki only.
     out_specs = [pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0))]
-    out_shape = [jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype)]
+    out_shape = [_out_struct((bh, t_pad, d), q.dtype, q)]
     if emit_lse:
         # Lane-broadcast [BH, T_pad, _LANES] (all lanes carry the same
         # value) — the layout the official TPU flash kernels use for l/m
@@ -202,8 +205,7 @@ def _flash_bhtd(q, k, v, seq_len, causal, block_q, block_k, interpret,
         # layout every Mosaic version tiles natively.
         out_specs.append(pl.BlockSpec((None, block_q, _LANES),
                                       lambda b, qi, ki: (b, qi, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((bh, t_pad, _LANES),
-                                              jnp.float32))
+        out_shape.append(_out_struct((bh, t_pad, _LANES), jnp.float32, q))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -335,7 +337,7 @@ def _flash_bwd_bhtd(q, k, v, do, lse, dd, seq_len, causal, block_q, block_k,
             pl.BlockSpec((None, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_specs=pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, t_pad, d), q.dtype),
+        out_shape=_out_struct((bh, t_pad, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         **_mosaic_params(interpret),
@@ -358,8 +360,8 @@ def _flash_bwd_bhtd(q, k, v, do, lse, dd, seq_len, causal, block_q, block_k,
             pl.BlockSpec((None, block_k, d), lambda b, ki, qi: (b, ki, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t_pad, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, t_pad, d), v.dtype),
+            _out_struct((bh, t_pad, d), k.dtype, k),
+            _out_struct((bh, t_pad, d), v.dtype, v),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
@@ -376,12 +378,13 @@ def _flash_bwd_bhtd(q, k, v, do, lse, dd, seq_len, causal, block_q, block_k,
 # --------------------------------------------------------------------------
 
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
-                    interpret=None):
+                    interpret=False):
     """Exact multi-head attention, ``[B, T, H, D]`` -> ``[B, T, H, D]``.
 
-    On TPU backends this runs the Pallas blocked kernels; on other backends
-    it falls back to the XLA reference unless ``interpret=True`` forces the
-    Pallas interpreter. ``block_q``/``block_k`` default per dtype on TPU —
+    Runs the Pallas blocked kernels compiled for the TPU; ``interpret=True``
+    runs them in the Pallas interpreter instead (any backend — the CPU
+    tests). The compiled kernel on a backend that is not a TPU raises.
+    ``block_q``/``block_k`` default per dtype on TPU —
     ``(512, 1024)`` for bf16, ``(256, 512)`` for f32 (hardware sweep on a
     v5e, T=8192 causal fwd+bwd: (512,1024) sustains ~40 TF/s vs ~11 at
     (128,128); f32 doubles VMEM so its blocks halve to stay inside the
@@ -399,11 +402,12 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     either direction. The inference (non-differentiated) path skips the lse
     write entirely.
     """
-    if interpret is None:
-        if jax.devices()[0].platform != 'tpu':
-            from petastorm_tpu.models.attention import dense_attention
-            return dense_attention(q, k, v, causal=causal)
-        interpret = False
+    if not interpret and jax.devices()[0].platform != 'tpu':
+        raise RuntimeError(
+            'flash_attention compiles Pallas TPU kernels but the default jax '
+            'backend is {!r}; pass interpret=True to run them in the Pallas '
+            'interpreter, or use dense_attention'.format(
+                jax.devices()[0].platform))
     if block_q is None or block_k is None:
         if interpret:
             dq, dk = 128, 128

@@ -6,8 +6,10 @@ shipping float32) and the cast/scale/shift fuses into one VMEM pass instead
 of materializing float intermediates in HBM.
 
 ``normalize_images`` is a Pallas TPU kernel (VPU elementwise over (8,128)
-tiles); ``normalize_images_reference`` is the pure-XLA equivalent used as a
-fallback on CPU and as the correctness oracle in tests.
+tiles) on a TPU, where nothing catches a Mosaic compile failure;
+``normalize_images_reference`` is the pure-XLA equivalent, the correctness
+oracle in tests and on the chip (bit-exact there, ``chip_smoke.py``) and
+what every other backend runs.
 """
 
 import functools
@@ -97,19 +99,24 @@ def normalize_images(images, mean=_IMAGENET_MEAN, std=_IMAGENET_STD,
                      dtype=jnp.bfloat16):
     """Fused uint8->normalized-``dtype`` conversion.
 
-    Uses the Pallas kernel on TPU; falls back to the XLA reference elsewhere
-    (CPU/interpret mode is only for tests — XLA fuses this fine on CPU).
+    The Pallas kernel on a TPU, always; the XLA reference on any other
+    backend (interpret mode is only for tests — XLA fuses this fine on CPU).
     """
     if images.ndim != 4:
         raise ValueError('Expected NHWC batch, got shape {}'.format(images.shape))
+    if jax.default_backend() == 'tpu':
+        return _normalize_pallas(images, *_scale_shift(mean, std),
+                                 dtype=dtype)
+    return normalize_images_reference(images, mean, std, dtype)
+
+
+def _scale_shift(mean=_IMAGENET_MEAN, std=_IMAGENET_STD):
+    """The kernel's per-channel coefficients: /255, -mean and /std folded
+    into one multiply-add, ``x * scale + shift``."""
     mean = jnp.asarray(mean, jnp.float32)
     std = jnp.asarray(std, jnp.float32)
-    # Fold /255 into a single multiply-add: x*scale + shift.
-    scale = (1.0 / (255.0 * std)).reshape(1, 1, 1, -1)
-    shift = (-mean / std).reshape(1, 1, 1, -1)
-    if jax.default_backend() == 'tpu':
-        return _normalize_pallas(images, scale, shift, dtype=dtype)
-    return normalize_images_reference(images, mean, std, dtype)
+    return ((1.0 / (255.0 * std)).reshape(1, 1, 1, -1),
+            (-mean / std).reshape(1, 1, 1, -1))
 
 
 def random_flip_and_normalize(rng, images, mean=_IMAGENET_MEAN, std=_IMAGENET_STD,

@@ -3,7 +3,6 @@
 from petastorm_tpu.parallel.mesh import (DeviceShardPlan,  # noqa: F401
                                          batch_sharding, device_shard_plan,
                                          make_mesh, process_shard,
-                                         replica_safe_concat,
                                          replicated_sharding,
                                          sequence_sharding)
 from petastorm_tpu.parallel.pod_guard import (PodAbortError,  # noqa: F401

@@ -44,27 +44,6 @@ class DeviceShardPlan(object):
         return len(self.devices)
 
 
-def replica_safe_concat(arrays):
-    """Leading-dim concatenation safe on partially-replicated meshes.
-
-    This jaxlib's SPMD ``jnp.concatenate`` lowering SUMS replicas into
-    the result when inputs carry a replicated mesh axis (e.g. a
-    ``('data', 'model')`` batch sharding — values come back multiplied by
-    the replica count; observed on the forced-multi-device CPU platform,
-    jax 0.4.37). Equal-shaped groups take a stack+reshape instead — the
-    same concatenation through a lowering that keeps replicas
-    replicated. A ragged group (only legal off-mesh, where the bug
-    cannot occur) keeps the plain concatenate. Trace-safe: shapes are
-    static under jit.
-    """
-    import jax.numpy as jnp
-    head = arrays[0]
-    if all(x.shape == head.shape for x in arrays[1:]):
-        return jnp.stack(arrays).reshape(
-            (len(arrays) * head.shape[0],) + tuple(head.shape[1:]))
-    return jnp.concatenate(arrays)
-
-
 def device_shard_plan(sharding, local_shape, process_count=None):
     """Plan per-device shard assembly for one field, or ``None``.
 
@@ -142,6 +121,11 @@ def make_mesh(axis_shapes, devices=None):
 
     Example: ``make_mesh({'data': -1, 'model': 2})`` on 8 devices gives a
     (4, 2) mesh with axes ('data', 'model').
+
+    Not topology-aware: ``jax.devices()`` is reshaped in enumeration order.
+    For a ``'data'``-only mesh every order is equivalent (one all-reduce
+    over all chips); a mesh whose inner axis should sit on ICI neighbours
+    needs ``jax.experimental.mesh_utils.create_device_mesh`` instead.
     """
     devices = list(devices if devices is not None else jax.devices())
     names = list(axis_shapes)

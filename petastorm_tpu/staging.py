@@ -92,23 +92,17 @@ def staging_aliases_host(jax):
     batches the consumer still holds. Probed once per process per backend
     with a buffer large enough to take the zero-copy path; the transfer is
     fenced before the source is mutated so a copying backend whose DMA is
-    still in flight can't be misread as aliasing. Any failure (or a
-    misread) errs toward True — the aliasing mode is the conservative one
-    (GC-gated recycling).
+    still in flight can't be misread as aliasing. A probe that cannot run
+    raises: guessing "aliases" would put a TPU loader in the GC-gated
+    recycling mode and hide that its device is not working.
     """
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 - unknown backend: assume the worst
-        return True
+    backend = jax.default_backend()
     if backend not in _alias_probe_memo:
-        try:
-            src = np.zeros(1 << 20, np.uint8)
-            staged = jax.device_put(src)
-            jax.block_until_ready(staged)
-            src[0] = 1
-            _alias_probe_memo[backend] = int(np.asarray(staged)[0]) == 1
-        except Exception:  # noqa: BLE001
-            _alias_probe_memo[backend] = True
+        src = np.zeros(1 << 20, np.uint8)
+        staged = jax.device_put(src)
+        jax.block_until_ready(staged)
+        src[0] = 1
+        _alias_probe_memo[backend] = int(np.asarray(staged)[0]) == 1
     return _alias_probe_memo[backend]
 
 

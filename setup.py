@@ -12,7 +12,9 @@ setup(
     version='0.1.0',
     description='TPU-native Parquet data access framework for JAX training',
     packages=find_packages(exclude=('tests',)),
-    python_requires='>=3.10',
+    # What the code is written for and was brought up on a TPU v5e with:
+    # Python 3.12, jax/jaxlib 0.9.0, libtpu 0.0.34 (see README, 'Running').
+    python_requires='>=3.12',
     install_requires=[
         'numpy',
         'pyarrow>=10.0.0',
@@ -21,7 +23,10 @@ setup(
         'dill',
     ],
     extras_require={
-        'jax': ['jax', 'flax', 'optax', 'orbax-checkpoint'],
+        # jax.shard_map, jax.lax.pcast, pltpu.CompilerParams and
+        # jax.device_put(donate=) are used directly: no older jax has all four.
+        'jax': ['jax>=0.9.0', 'flax>=0.12.3', 'optax>=0.2.6',
+                'orbax-checkpoint>=0.11.32'],
         'process-pool': ['pyzmq'],
         'images': ['opencv-python'],
         'torch': ['torch'],

@@ -11,13 +11,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from petastorm_tpu.models.attention import a2a_self_attention, dense_attention
+from functools import partial
+
+from petastorm_tpu.models import attention as attention_mod
+from petastorm_tpu.models.attention import dense_attention
 from petastorm_tpu.parallel import make_mesh
+
+# a2a's per-device block compute is the Pallas flash kernel: on the CPU
+# mesh it runs in the Pallas interpreter, by name.
+a2a_self_attention = partial(attention_mod.a2a_self_attention, interpret=True)
 
 
 # Heavyweight (jit compiles of full models / interpret-mode Pallas):
 # excluded from the fast CI lane; run the full suite before shipping.
 pytestmark = pytest.mark.slow
+
 
 def _qkv(key, b=2, t=64, h=8, d=16, dtype=jnp.float32):
     kq, kk, kv = jax.random.split(key, 3)
@@ -75,7 +83,7 @@ def test_transformer_lm_a2a_trains_under_jit():
     mesh = make_mesh({'data': 2, 'sp': 4})
     seq, vocab = 32, 64
     model = TransformerLM(vocab_size=vocab, d_model=32, num_heads=4,
-                          num_layers=1, max_len=seq, attention='a2a',
+                          num_layers=1, max_len=seq, attention='a2a:interpret',
                           mesh=mesh, seq_axis='sp', dtype=jnp.float32)
     tokens = jax.random.randint(jax.random.PRNGKey(4), (4, seq), 0, vocab)
     params = model.init(jax.random.PRNGKey(5), tokens)['params']

@@ -1,8 +1,9 @@
 """Gated end-to-end smoke of the benchmark's imagenet child (bench.py).
 
 Heavy (ResNet compiles at 224x224): runs only with ``PST_BENCH_SMOKE=1`` so
-the default suite stays fast. The round driver exercises the real child on
-TPU; this pin keeps the CPU path (and the JSON contract) from rotting.
+the default suite stays fast. It pins the child's explicit-CPU path
+(``JAX_PLATFORMS=cpu``, output labelled ``platform: cpu``) and its JSON
+contract; what runs on the chip is proven by ``chip_smoke.py``.
 """
 
 import json

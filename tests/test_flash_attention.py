@@ -64,14 +64,14 @@ def test_gradients_flow():
                                    atol=1e-4, rtol=1e-4)
 
 
-def test_cpu_fallback_without_interpret():
-    """interpret=None on a non-TPU backend silently uses the XLA reference."""
+def test_compiled_kernel_off_tpu_raises():
+    """Nothing substitutes for the device: without ``interpret=True`` a
+    backend that is not a TPU is an error, not a silent dense fallback."""
     rng = np.random.default_rng(3)
     q, k, v = (jnp.asarray(rng.standard_normal((1, 16, 1, 4)), jnp.float32)
                for _ in range(3))
-    got = flash_attention(q, k, v, causal=False)
-    ref = dense_attention(q, k, v, causal=False)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+    with pytest.raises(RuntimeError, match='interpret=True'):
+        flash_attention(q, k, v, causal=False)
 
 
 @pytest.mark.parametrize('causal', [False, True])

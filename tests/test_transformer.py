@@ -82,15 +82,37 @@ def test_ring_trains_under_jit():
 
 
 def test_flash_backend_selectable():
-    """attention='flash' falls back to the XLA reference off-TPU, so logits
-    match dense exactly on CPU."""
+    """attention='flash:interpret' runs the Pallas kernel (in the
+    interpreter) through the module and matches dense; plain 'flash' is the
+    compiled kernel, which a CPU cannot run and must not quietly replace."""
     tokens = _tokens()
     dense = _make('dense')
     params = dense.init(jax.random.PRNGKey(0), tokens)
     ref = dense.apply(params, tokens)
-    flash = _make('flash')
-    got = flash.apply(params, tokens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-5)
+    got = _make('flash:interpret').apply(params, tokens)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
+    with pytest.raises(RuntimeError, match='interpret=True'):
+        _make('flash').apply(params, tokens)
+    with pytest.raises(ValueError, match='unknown attention'):
+        _make('ring:interpret').apply(params, tokens)
+
+
+def test_flash_on_a_data_mesh_stays_batch_sharded():
+    """On a mesh the module maps the kernel over the batch shards
+    (shard_map): the SPMD partitioner cannot split a Pallas call and would
+    gather the whole batch onto every device."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    mesh = make_mesh({'data': 8})
+    tokens = _tokens(b=8, t=16)
+    dense = _make('dense')
+    params = dense.init(jax.random.PRNGKey(0), tokens)
+    ref = dense.apply(params, tokens)
+    sharded = jax.device_put(np.asarray(tokens),
+                             NamedSharding(mesh, PartitionSpec('data')))
+    got = jax.jit(_make('flash:interpret', mesh=mesh).apply)(params, sharded)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=1e-4)
+    assert got.sharding.spec[0] == 'data'
 
 
 def test_ring_requires_mesh():

@@ -98,9 +98,9 @@ def test_tensor_parallel_sharding_applies():
 
 def test_flash_kernel_handles_vit_sequence_length():
     """ViT's sequence is patches+CLS = a NON-block-aligned length (e.g. 65).
-    Exercise the actual Pallas kernel (interpret=True — off-TPU the module
-    path falls back to dense, which would test nothing) non-causally at
-    exactly that shape against the dense reference."""
+    Exercise the Pallas kernel (interpret=True: the compiled kernel needs
+    a TPU) non-causally at exactly that shape against the dense
+    reference."""
     from petastorm_tpu.models.attention import dense_attention
     from petastorm_tpu.ops.flash_attention import flash_attention
 
@@ -117,12 +117,12 @@ def test_flash_kernel_handles_vit_sequence_length():
 
 
 def test_flash_backend_forward_runs():
-    """The module-level flash path (whatever backend the platform picks)
-    produces finite logits at ViT shapes."""
+    """The module-level flash path (the Pallas kernel, in the interpreter
+    on this CPU) produces finite logits at ViT shapes."""
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((2, 32, 32, 3)), jnp.float32)
     flash = ViT(num_classes=5, patch_size=4, d_model=32, num_heads=2,
-                num_layers=1, attention='flash', dtype=jnp.float32)
+                num_layers=1, attention='flash:interpret', dtype=jnp.float32)
     params = flash.init(jax.random.PRNGKey(3), x)['params']
     out = flash.apply({'params': params}, x)
     assert out.shape == (2, 5) and np.isfinite(np.asarray(out)).all()
