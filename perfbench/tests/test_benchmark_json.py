@@ -1,0 +1,169 @@
+"""``BENCHMARK.json`` against the contract and against the files it names."""
+
+import json
+import os
+import re
+
+import pytest
+from conftest import PERFBENCH, ROOT
+
+from perfbench import harness
+
+NAME = re.compile(r'^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$')
+UNIT = re.compile(r'^[A-Za-z0-9_/%.\-]{1,16}$')
+SOURCES = {'device_trace', 'program_span', 'program_counter', 'host_clock'}
+
+
+@pytest.fixture(scope='module')
+def bench():
+    return json.load(open(os.path.join(ROOT, 'BENCHMARK.json')))
+
+
+def test_keys_and_sizes(bench):
+    assert set(bench) == {'command', 'paths', 'run_seconds', 'configs',
+                          'workloads', 'end_to_end', 'per_layer'}
+    assert os.path.getsize(os.path.join(ROOT, 'BENCHMARK.json')) <= 64 * 1024
+    assert 1 <= bench['run_seconds'] <= 51 and isinstance(bench['run_seconds'], int)
+    assert bench['paths'] == ['perfbench']
+    assert 1 <= len(bench['workloads']) <= 24
+    # a full check at the full 24 cells fits the driver's allowance
+    assert (2 + 14 * 24) * (bench['run_seconds'] + 60) + 24 * 180 + 1200 <= 43200
+
+
+def test_names_units_and_entry_keys(bench):
+    for c in bench['configs']:
+        assert set(c) == {'name', 'source', 'file', 'reduced', 'why'}
+        assert NAME.match(c['name']) and c['file'].startswith('perfbench/')
+        assert os.path.exists(os.path.join(ROOT, c['file']))
+    for w in bench['workloads']:
+        assert set(w) == {'name', 'config', 'traffic', 'chips', 'why'}
+        assert NAME.match(w['name']) and NAME.match(w['traffic'])
+        assert w['chips'] in (1, 4) and 1 <= len(w['why']) <= 200
+    for m in bench['end_to_end']:
+        assert set(m) - {'workloads'} == {'name', 'unit', 'better', 'bound',
+                                          'source'}
+        assert m['source'] in ('host_clock', 'device_trace')
+        assert 0.01 <= m['bound'] <= 0.1
+    for m in bench['per_layer']:
+        assert set(m) - {'workloads'} == {'name', 'unit', 'better', 'source',
+                                          'layer', 'moves'}
+    for m in bench['end_to_end'] + bench['per_layer']:
+        assert NAME.match(m['name']) and UNIT.match(m['unit'])
+        assert m['better'] in ('lower', 'higher') and m['source'] in SOURCES
+    names = [m['name'] for m in bench['end_to_end'] + bench['per_layer']]
+    assert len(names) == len(set(names))
+
+
+def test_cells_and_configs_agree(bench):
+    configs = {c['name'] for c in bench['configs']}
+    used = {w['config'] for w in bench['workloads']}
+    assert configs == used
+    pairs = [(w['config'], w['traffic']) for w in bench['workloads']]
+    assert len(pairs) == len(set(pairs))
+    four = [w for w in bench['workloads'] if w['chips'] == 4]
+    assert len(four) <= max(1, len(bench['workloads']) // 4)
+    cells = {w['name'] for w in bench['workloads']}
+    for m in bench['end_to_end'] + bench['per_layer']:
+        assert set(m.get('workloads', cells)) <= cells
+    assert 'setup_s' in {m['name'] for m in bench['end_to_end']}
+
+
+def test_end_to_end_metrics_are_the_issue_s_that_hold_a_bound(bench):
+    # host_cpu_ms_per_row spread by 2.7 and 3.8 % over two sets of six runs
+    # of resnet50.ramcache: five times that is past the contract's 10 %, so
+    # it is the per-layer host.cpu_ms_per_row, with host.rss_peak_mb.
+    assert [m['name'] for m in bench['end_to_end']] == [
+        'rows_per_s_per_chip', 'step_interval_p95_ms', 'setup_s']
+    layer = {m['name'] for m in bench['per_layer']}
+    assert {'host.cpu_ms_per_row', 'host.rss_peak_mb'} <= layer
+    assert not any('step_interval_ms' == m['name'] or 'mean' in m['name']
+                   for m in bench['end_to_end'] + bench['per_layer'])
+
+
+def test_every_metric_has_its_reader_and_benchmark_json_alone_describes_it(bench):
+    for m in bench['end_to_end'] + bench['per_layer']:
+        mod = harness.load_module(os.path.join(PERFBENCH, 'metrics',
+                                               m['name'] + '.py'))
+        assert callable(mod.read)
+        # name, unit, layer and the rest stand in BENCHMARK.json and nowhere else
+        assert [n for n in vars(mod) if n.isupper()] == []
+        if 'layer' in m:
+            assert m['moves'] == 'rows_per_s_per_chip'
+    files = {f[:-3] for f in os.listdir(os.path.join(PERFBENCH, 'metrics'))
+             if f.endswith('.py')}
+    assert files == {m['name'] for m in bench['end_to_end'] + bench['per_layer']}
+
+
+def test_the_step_s_share_of_the_peak_is_read_from_the_trace(bench):
+    by_name = {m['name']: m for m in bench['per_layer']}
+    assert by_name['step.mfu']['source'] == 'device_trace'
+    mod = harness.load_module(os.path.join(PERFBENCH, 'metrics', 'step.mfu.py'))
+
+    class Ref(object):
+        @staticmethod
+        def train_flops_per_row(cfg):
+            return 1e9
+
+    ctx = {'trace': {'steps': 10, 'busy_s': 0.5}, 'ref': Ref, 'cfg': {},
+           'batch': 8, 'chips': 4, 'peak': {'bf16_flops_per_s': 1e11},
+           'rate': 1e9}
+    # 2e9 operations a step a chip in 50 ms of device time: 4e10 a second
+    assert mod.read(ctx) == pytest.approx(40.0)
+    assert mod.read(dict(ctx, trace=None)) is None
+
+
+def test_every_cell_reports_step_mfu_and_lm_cells_the_roofline(bench):
+    by_name = {m['name']: m for m in bench['per_layer']}
+    assert 'workloads' not in by_name['step.mfu']
+    lm = [w['name'] for w in bench['workloads']
+          if w['config'].startswith('gpt2')]
+    assert by_name['kernel.flash_roofline']['workloads'] == lm
+    assert by_name['kernel.flash_roofline']['unit'] == '%'
+
+
+def test_every_traffic_file_is_some_cell_s(bench):
+    used = {w['traffic'] + '.json' for w in bench['workloads']}
+    assert set(os.listdir(os.path.join(PERFBENCH, 'traffic'))) == used
+
+
+def test_every_cell_s_files_exist_and_state_their_knobs(bench):
+    files = harness.Files(os.path.join(ROOT, 'BENCHMARK.json'))
+    for w in bench['workloads']:
+        cfg, ref, program = files.config(w['config'])
+        traffic = harness.load_json(files.find('traffic', w['traffic'] + '.json'))
+        assert files.find('stores', cfg['store'] + '.py')
+        assert cfg['reduced'] == []
+        # the rows exactly, a number of the first gradient and one of the
+        # parameters' change; each limit lies between its two readings
+        limits = cfg['limits']
+        assert limits['rows_wrong'] == limits['rows_uneven'] == \
+            limits['shards_misplaced'] == 0
+        assert any(k.startswith('grad_gap') for k in limits)
+        assert any(k.startswith('update_gap') for k in limits)
+        for name, limit in limits.items():
+            if limit == 0:          # an exact comparison
+                continue
+            lower, upper = cfg['limits_from'][name]['lower'], \
+                cfg['limits_from'][name]['upper']
+            assert lower < limit < upper, name
+        assert traffic['reader']['autotune'] is False
+        assert traffic['loader']['autotune'] is False
+        for knob in ('prefetch', 'inflight', 'arena_depth', 'device_inflight',
+                     'pinned_arenas'):
+            assert knob in traffic['loader']
+        for knob in ('reader_pool_type', 'workers_count', 'cache_type'):
+            assert knob in traffic['reader']
+        assert traffic['decode_threads'] >= 1
+        assert traffic['sample_rows'] >= 1
+        # every key of a traffic file is read by the harness or says why
+        assert {k for k in traffic if not k.endswith(('_why', 'what'))} <= {
+            'name', 'store_rows', 'store_writers', 'decode_threads', 'reader',
+            'loader', 'fill_cache_rows', 'warm_steps', 'sample_rows'}
+        assert hasattr(ref, 'loss_and_grad') and hasattr(program, 'build')
+
+
+def test_peaks_carry_their_source():
+    peaks = json.load(open(os.path.join(PERFBENCH, 'peaks.json')))
+    assert 'cloud.google.com' in peaks['_source']
+    assert peaks['TPU v5 lite']['bf16_flops_per_s'] == 197e12
+    assert peaks['TPU v5 lite']['hbm_bytes_per_s'] == 819e9
