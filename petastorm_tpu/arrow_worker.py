@@ -39,11 +39,11 @@ class ArrowWorker(RowGroupWorkerBase):
         piece = self.args['row_groups'][piece_index]
         maybe_inject('decode-corrupt',
                      key=rowgroup_fault_key(piece.path, piece.row_group))
-        # Arrow mode ships raw cells, so its 'decode' span covers the
-        # columnar table prep (the read span nests inside it) — the same
+        # Arrow mode ships raw cells, so its 'decode.decode' span covers the
+        # columnar table prep ('reader.read' nests inside it) — the same
         # three-span vocabulary as the dict/tensor workers on a merged
         # timeline even though codecs don't run here.
-        with get_global_tracer().span('decode', 'worker'):
+        with get_global_tracer().span('decode.decode', 'decode'):
             table, read_fresh = self._load_table_cached(piece, worker_predicate)
         if table is None or table.num_rows == 0:
             return self._publish_hole(pst_det)
@@ -91,7 +91,7 @@ class ArrowWorker(RowGroupWorkerBase):
             md[b'pst.lineage'] = json_mod.dumps(lineage).encode()
             if pst_det is not None:
                 md[b'pst.det'] = json_mod.dumps(pst_det).encode()
-            with get_global_tracer().span('handoff', 'worker'):
+            with get_global_tracer().span('reader.publish', 'reader'):
                 self.publish_func(table.replace_schema_metadata(md))
         else:
             self._publish_hole(pst_det)

@@ -369,10 +369,8 @@ class AutoTuner(object):
         self._telemetry_fn = telemetry_fn
         self.knobs = dict(knobs)
         self.config = config if config is not None else AutotuneConfig()
-        if tracer is None:
-            from petastorm_tpu.trace import NullTracer
-            tracer = NullTracer()
-        self._tracer = tracer
+        from petastorm_tpu.trace import resolve
+        self._tracer = resolve(tracer)
         self._classify_fn = classify_fn
         self._watchdog_active_fn = watchdog_active_fn
         self._memory_state_fn = memory_state_fn

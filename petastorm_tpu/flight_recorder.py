@@ -48,8 +48,11 @@ class FlightRecorder(object):
 
     :param base_dir: where dump directories are created.
     :param tracer: the pipeline's :class:`~petastorm_tpu.trace.Tracer`
-        (its bounded event ring IS the trace flight ring); a
-        ``NullTracer`` yields an empty ``trace.json``.
+        (its bounded event ring IS the trace flight ring). ``None`` means
+        the process-wide default ring, which is on unless somebody switched
+        it off, so a dump holds the last seconds of spans though nobody
+        armed anything; only ``set_global_tracer(NullTracer())`` yields an
+        empty ``trace.json``.
     :param registry: the :class:`~petastorm_tpu.metrics.MetricsRegistry`
         to snapshot (default: the process-wide registry).
     :param metric_ring: periodic samples retained (oldest dropped).
@@ -60,7 +63,8 @@ class FlightRecorder(object):
     def __init__(self, base_dir, tracer=None, registry=None, metric_ring=256,
                  sample_min_interval_s=0.25):
         self._base_dir = base_dir
-        self._tracer = tracer
+        from petastorm_tpu.trace import resolve
+        self._tracer = resolve(tracer)
         if registry is None:
             from petastorm_tpu import metrics
             registry = metrics.get_registry()
@@ -123,12 +127,8 @@ class FlightRecorder(object):
 
     def _write_trace(self, path):
         try:
-            export = getattr(self._tracer, 'export_chrome_trace', None)
-            if export is not None:
-                export(path)
-            else:   # NullTracer / no tracer: an empty-but-valid timeline
-                with open(path, 'w') as f:
-                    json.dump({'traceEvents': [], 'displayTimeUnit': 'ms'}, f)
+            # (a NullTracer exports an empty-but-valid timeline)
+            self._tracer.export_chrome_trace(path)
         except Exception:  # noqa: BLE001
             logger.debug('flight recorder trace dump failed', exc_info=True)
 

@@ -552,10 +552,8 @@ class Watchdog(object):
         from petastorm_tpu import metrics
         self._registry = registry
         self._on_hard_stall = on_hard_stall
-        if tracer is None:
-            from petastorm_tpu.trace import NullTracer
-            tracer = NullTracer()
-        self._tracer = tracer
+        from petastorm_tpu.trace import resolve
+        self._tracer = resolve(tracer)
         #: Optional petastorm_tpu.flight_recorder.FlightRecorder: sampled
         #: every check pass, dumped on hard escalation so the stall's trace
         #: ring + metric history survive the process.

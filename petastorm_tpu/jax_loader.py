@@ -36,6 +36,7 @@ from collections import Counter, deque
 
 import numpy as np
 
+from petastorm_tpu import trace as trace_mod
 from petastorm_tpu.utils import cached_namedtuple
 
 logger = logging.getLogger(__name__)
@@ -724,8 +725,11 @@ class JaxLoader(object):
     :param last_batch: 'drop' (pod-safe default) | 'pad' | 'partial'.
     :param strict_fields: raise (instead of warn-and-drop) when a selected
         field cannot batch — e.g. declared nullable but never actually null.
-    :param tracer: a ``trace.Tracer`` to record assemble/stage/wait spans
-        into a chrome://tracing timeline (default ``NullTracer``, no-op).
+    :param tracer: a ``trace.Tracer`` to record the loader's collate,
+        dispatch and consumer spans into (a chrome://tracing timeline).
+        ``None`` (default) means ``trace.get_global_tracer()``: the
+        process-wide bounded ring, on unless
+        ``trace.set_global_tracer(trace.NullTracer())`` switched it off.
     :param echo: data echoing (Choi et al., "Faster Neural Network Training
         with Data Echoing"): deliver each staged batch ``echo`` times. When
         the pipeline is input-bound (``input_stall_frac`` high) echoed
@@ -862,10 +866,8 @@ class JaxLoader(object):
         from petastorm_tpu import membudget as membudget_mod
         membudget_mod.validate_env_budget()
 
-        if tracer is None:
-            from petastorm_tpu.trace import NullTracer
-            tracer = NullTracer()
-        self._tracer = tracer
+        self._tracer = trace_mod.resolve(tracer)
+        trace_mod.watch_jax_compiles()
 
         self._reader = reader
         self._mesh = mesh
@@ -901,7 +903,6 @@ class JaxLoader(object):
         self._augment_fn = None
         if callable(on_device_augment):
             self._augment_fn = jax.jit(on_device_augment)
-        self._stage_decode_s = 0.0
 
         if mesh is not None or sharding is not None:
             n_proc = jax.process_count()
@@ -978,13 +979,15 @@ class JaxLoader(object):
         self._echo_left = 0
         self._echo_item = None
         self._consumer_staging = prefetch == 0
-        # Inline-staging stage split (prefetch=0): the consumer runs the
-        # whole pipeline, so its blocked time alone can't say WHICH stage
-        # is slow — these bracket the reader pull vs the device dispatch
-        # for the autotuner's classification (and they are interesting
-        # stats in their own right).
-        self._inline_reader_s = 0.0
-        self._inline_dispatch_s = 0.0
+        # Seconds by stage, each fed by the one span that clocks it:
+        # wait_s by consumer.wait (the fetch in __next__), stage_decode_s by
+        # the raw columns' dispatch.decode, and inline_reader_s and stage_s
+        # by collate.batch and dispatch.stage where the consumer runs the
+        # whole pipeline itself (prefetch=0: no engine clocks them) and
+        # its blocked time alone cannot say WHICH stage is slow.
+        self._totals = {'wait_s': 0.0, 'stage_s': 0.0, 'stage_decode_s': 0.0,
+                        'inline_reader_s': 0.0}
+        self._inline_seq = 0
         # `prefetch` bounds staged-but-undelivered batches (device memory).
         # The consumer's batched pop moves queued batches into its local
         # buffer, so the bound is enforced over BOTH: the queue's live
@@ -1074,12 +1077,10 @@ class JaxLoader(object):
             'pst_staged_bytes_total', 'Host bytes handed to device staging')
         # input-stall accounting (BASELINE.json targets <5% input stall)
         self._batches_delivered = 0
-        self._wait_s = 0.0
         self._first_get_t = None
         # staging accounting (VERDICT r1 #4: measure copy/transfer cost).
         # Written by the staging thread, reset by the consumer — lock both.
         self._stats_lock = threading.Lock()
-        self._stage_s = 0.0
         self._staged_bytes = 0
         # Fields staged per transfer tier (stats['stage_tiers']): which of
         # _stage's branches carried the dispatch. Staging thread only.
@@ -1195,7 +1196,8 @@ class JaxLoader(object):
             meter = OverlapMeter()
             hb_assemble = (self._health.registry.register('assemble')
                            if self._health is not None else None)
-            host_reader = MeteredReader(reader, meter, heartbeat=hb_assemble)
+            host_reader = MeteredReader(reader, meter, heartbeat=hb_assemble,
+                                        tracer=self._tracer)
             self._metered_reader = host_reader
             self._arena_pool = ArenaPool(arena_depth, stop_event=self._stop,
                                          tracer=self._tracer, meter=meter,
@@ -1434,7 +1436,7 @@ class JaxLoader(object):
         of :func:`petastorm_tpu.autotune.classify_loader`. Cheap enough
         for a sub-second tick: attribute reads plus two small locks."""
         out = {'batches': self._batches_delivered,
-               'wait_s': self._wait_s,
+               'wait_s': self._totals['wait_s'],
                'queue_depth': self._queue.qsize() + len(self._ready),
                'queue_capacity': self._prefetch_target}
         if self._consumer_staging:
@@ -1443,8 +1445,8 @@ class JaxLoader(object):
             # signals — without them every slow tick would classify as
             # input-bound and ratchet the worker pool to its clamp even
             # when the device dispatch is the bottleneck.
-            out['reader_wait_s'] = self._inline_reader_s
-            out['ready_wait_s'] = self._inline_dispatch_s
+            out['reader_wait_s'] = self._totals['inline_reader_s']
+            out['ready_wait_s'] = self._totals['stage_s']
         if self._metered_reader is not None:
             out['reader_wait_s'] = self._metered_reader.reader_wait_s
         if self._arena_pool is not None:
@@ -1685,7 +1687,6 @@ class JaxLoader(object):
         decode_threads = (budget.total if self._staging_owns_budget
                           else budget.share())
         out = dict(host_batch)
-        t0 = time.perf_counter()
         for name, field in self._raw_specs.items():
             column = out.get(name)
             if column is None or getattr(column, 'dtype', None) != np.dtype(object):
@@ -1706,19 +1707,24 @@ class JaxLoader(object):
                 field, block, lambda i, _c=column: _c[i],
                 decode_threads=decode_threads)
             out[name] = block
-        with self._stats_lock:
-            self._stage_decode_s += time.perf_counter() - t0
         return out
 
-    def _stage(self, host_batch, arena=None):
+    def _stage(self, host_batch, arena, span):
+        """Issue the batch's device puts, inside the caller's
+        ``dispatch.stage`` span (the staging engine's, or inline staging's
+        own): ``span.id`` is the batch's number, and its cause is set to
+        the tiers that carried the fields."""
         from petastorm_tpu.faults import maybe_inject
+        from petastorm_tpu.staging import StagedBatch
         maybe_inject('device-put-delay')
         jax = self._jax
         if self._raw_specs:
-            host_batch = self._decode_raw_columns(host_batch)
+            with self._tracer.span(
+                    'dispatch.decode', 'dispatch', id=span.id,
+                    total=(self._totals, 'stage_decode_s')):
+                host_batch = self._decode_raw_columns(host_batch)
         out = {}
         pending = []   # per-device sharded fields, dispatched as one wave
-        t0 = time.perf_counter()
         nbytes = 0
         # The stager's OverlapMeter: staging batch N+1 counts as 'host'
         # work; its co-activity with the stager's in-flight 'h2d' windows
@@ -1728,7 +1734,8 @@ class JaxLoader(object):
                      if self._stager is not None
                      and self._stager.meter is not None
                      else contextlib.nullcontext())
-        with self._tracer.span('stage', 'device'), host_span:
+        tiers_before = dict(self._stage_tiers)
+        with host_span:
             for name, array in host_batch.items():
                 nbytes += array.nbytes
                 if hasattr(array, 'is_ready'):
@@ -1799,19 +1806,21 @@ class JaxLoader(object):
                 # just-staged device arrays asynchronously — its compute
                 # overlaps the consumer's step exactly like the transfer.
                 out = dict(self._augment_fn(out))
-        # Dispatch time only (device_put is async); the transfer itself
-        # overlaps the consumer's step. Block-to-measure lives in bench.py.
+        span.cause = sorted(tier for tier, n in self._stage_tiers.items()
+                            if n != tiers_before.get(tier, 0))
         with self._stats_lock:
-            self._stage_s += time.perf_counter() - t0
             self._staged_bytes += nbytes
         # Prefetch-queue byte accounting (membudget): depth x the latest
         # batch's bytes. Int rebind is atomic; staging thread only.
         self._last_batch_nbytes = nbytes
         self._m_staged_bytes.inc(nbytes)
-        return out
+        return StagedBatch(out, span.id)
 
     def _next_host_batch(self):
-        with self._tracer.span('assemble', 'host'):
+        """Inline staging's collate (prefetch=0): the consumer's thread."""
+        with self._tracer.span('collate.batch', 'collate',
+                               id=self._inline_seq,
+                               total=(self._totals, 'inline_reader_s')):
             return next(self._host_iter)
 
     # The staging threads themselves live in ``staging.StagingEngine``
@@ -1856,71 +1865,14 @@ class JaxLoader(object):
             raise error
         if self._hb_consumer is not None:
             self._hb_consumer.beat('queue-wait')
-        t0 = time.perf_counter()
         if self._first_get_t is None:
-            self._first_get_t = t0
-        fresh = True
-        if self._echo_left > 0:
-            self._echo_left -= 1
-            item = self._echo_item
-            fresh = False   # source rows already counted on first delivery
-        else:
-            if self._consumer_staging:
-                # Inline staging (prefetch=0): the consumer thread IS the
-                # pipeline, so its heartbeat states must distinguish a
-                # starved reader from a hung device_put here too — without
-                # the brackets a wedged inline transfer would read as
-                # 'queue-wait' (an innocent state) and never classify.
-                try:
-                    if self._hb_consumer is not None:
-                        self._hb_consumer.beat('reader-wait')
-                    t_inline = time.perf_counter()
-                    host_batch = self._next_host_batch()
-                    t_staged = time.perf_counter()
-                    self._inline_reader_s += t_staged - t_inline
-                    if self._hb_consumer is not None:
-                        self._hb_consumer.beat('device_put')
-                    item = self._stage(host_batch)
-                    self._inline_dispatch_s += time.perf_counter() - t_staged
-                except StopIteration:
-                    item = _END
-                except Exception as e:  # noqa: BLE001 - match staged path
-                    item = e
-            elif self._ready:
-                # Batched pop: a previous fetch drained the staging queue
-                # into this consumer-local buffer. Consuming one gives a
-                # capacity slot back to the dispatch thread (the drain
-                # below converted queue slots into buffer debt, not into
-                # refillable capacity).
-                item = self._ready.popleft()
-                staging_queue = self._queue
-                with staging_queue.mutex:
-                    staging_queue.maxsize = max(
-                        1, self._prefetch_target - len(self._ready))
-                    staging_queue.not_full.notify()
-            else:
-                with self._tracer.span('wait', 'consumer'):
-                    item = self._queue.get()
-                # Batched pop: move every staged batch into the local
-                # buffer under ONE mutex acquisition (vs one Queue.get
-                # lock round trip per batch — the warm-cache rate is
-                # queue-pop bound, PROFILE_r05 §2). The queue's live
-                # maxsize shrinks by the same count (no notify): drained
-                # slots must NOT become capacity the dispatch thread
-                # refills, or staged-but-undelivered device batches would
-                # reach ~2x the documented `prefetch` bound.
-                staging_queue = self._queue
-                with staging_queue.mutex:
-                    while staging_queue.queue:
-                        self._ready.append(staging_queue.queue.popleft())
-                    staging_queue.maxsize = max(
-                        1, self._prefetch_target - len(self._ready))
-            if self._echo > 1 and isinstance(item, dict):
-                self._echo_item = item
-                self._echo_left = self._echo - 1
-        batch_wait = time.perf_counter() - t0
-        self._wait_s += batch_wait
-        self._m_batch_wait.observe(batch_wait)
+            self._first_get_t = time.perf_counter()
+        # The consumer's blocked time for this fetch: the input-stall
+        # signal (wait_s, pst_batch_wait_seconds), end-of-stream included.
+        with self._tracer.span('consumer.wait', 'consumer',
+                               hist=self._m_batch_wait,
+                               total=(self._totals, 'wait_s')):
+            item, fresh = self._fetch()
         if item is _END:
             self._exhausted = True
             if self._hb_consumer is not None:
@@ -1933,6 +1885,12 @@ class JaxLoader(object):
         nt = cached_namedtuple(self._namedtuple_cache, 'JaxBatch', names)
         self._batches_delivered += 1
         self._m_batches.inc()
+        # Which batch the training loop took and when its puts had been
+        # issued: taken less staged is its time in the prefetch queue, the
+        # loader's lead over the step.
+        self._tracer.instant('consumer.deliver', 'consumer',
+                             {'staged_ns': getattr(item, 'staged_ns', None)},
+                             id=getattr(item, 'seq', None))
         if self._lineage is not None and fresh:
             # Mint this batch's provenance record (FIFO against the host-
             # batch iterator's collector pushes — the staging engine
@@ -1966,6 +1924,70 @@ class JaxLoader(object):
             # final batch over-reports; mark_delivered drains empty.)
             self._shuffler.mark_delivered(self._local_batch)
         return nt(**{k: item[k] for k in names})
+
+    def _fetch(self):
+        """``(item, fresh)``: the next staged batch (or ``_END``, or an
+        exception to raise), and whether its source rows are delivered for
+        the first time (an echo is not)."""
+        fresh = True
+        if self._echo_left > 0:
+            self._echo_left -= 1
+            item = self._echo_item
+            fresh = False   # source rows already counted on first delivery
+        else:
+            if self._consumer_staging:
+                # Inline staging (prefetch=0): the consumer thread IS the
+                # pipeline, so its heartbeat states must distinguish a
+                # starved reader from a hung device_put here too — without
+                # the brackets a wedged inline transfer would read as
+                # 'queue-wait' (an innocent state) and never classify.
+                try:
+                    if self._hb_consumer is not None:
+                        self._hb_consumer.beat('reader-wait')
+                    host_batch = self._next_host_batch()
+                    if self._hb_consumer is not None:
+                        self._hb_consumer.beat('device_put')
+                    with self._tracer.span(
+                            'dispatch.stage', 'dispatch', id=self._inline_seq,
+                            total=(self._totals, 'stage_s')) as span:
+                        item = self._stage(host_batch, None, span)
+                    self._inline_seq += 1
+                except StopIteration:
+                    item = _END
+                except Exception as e:  # noqa: BLE001 - match staged path
+                    item = e
+            elif self._ready:
+                # Batched pop: a previous fetch drained the staging queue
+                # into this consumer-local buffer. Consuming one gives a
+                # capacity slot back to the dispatch thread (the drain
+                # below converted queue slots into buffer debt, not into
+                # refillable capacity).
+                item = self._ready.popleft()
+                staging_queue = self._queue
+                with staging_queue.mutex:
+                    staging_queue.maxsize = max(
+                        1, self._prefetch_target - len(self._ready))
+                    staging_queue.not_full.notify()
+            else:
+                item = self._queue.get()
+                # Batched pop: move every staged batch into the local
+                # buffer under ONE mutex acquisition (vs one Queue.get
+                # lock round trip per batch — the warm-cache rate is
+                # queue-pop bound, PROFILE_r05 §2). The queue's live
+                # maxsize shrinks by the same count (no notify): drained
+                # slots must NOT become capacity the dispatch thread
+                # refills, or staged-but-undelivered device batches would
+                # reach ~2x the documented `prefetch` bound.
+                staging_queue = self._queue
+                with staging_queue.mutex:
+                    while staging_queue.queue:
+                        self._ready.append(staging_queue.queue.popleft())
+                    staging_queue.maxsize = max(
+                        1, self._prefetch_target - len(self._ready))
+            if self._echo > 1 and isinstance(item, dict):
+                self._echo_item = item
+                self._echo_left = self._echo - 1
+        return item, fresh
 
     def superbatches(self, k):
         """Yield ``k``-batch on-device concatenations (for scan training).
@@ -2016,14 +2038,10 @@ class JaxLoader(object):
         """Zero the stall counters — call after warmup so ``stats`` reflects
         the steady-state window, not reader-pool spin-up."""
         self._batches_delivered = 0
-        self._wait_s = 0.0
-        self._inline_reader_s = 0.0
-        self._inline_dispatch_s = 0.0
+        trace_mod.reset_totals(self._totals)
         self._first_get_t = None
         with self._stats_lock:
-            self._stage_s = 0.0
             self._staged_bytes = 0
-            self._stage_decode_s = 0.0
         if self._engine is not None:
             self._engine.reset_stats()
         if self._stager is not None:
@@ -2051,12 +2069,17 @@ class JaxLoader(object):
         elapsed = (time.perf_counter() - self._first_get_t
                    if self._first_get_t is not None else 0.0)
         with self._stats_lock:
-            stage_s, staged_bytes = self._stage_s, self._staged_bytes
-            stage_decode_s = self._stage_decode_s
+            staged_bytes = self._staged_bytes
+        wait_s = self._totals['wait_s']
+        # dispatch.stage is the engine's span, or inline staging's own; the
+        # raw columns' decode inside it is reported apart.
+        stage_s = (self._engine.stats()['dispatch_s']
+                   if self._engine is not None else self._totals['stage_s'])
         out = {'batches': self._batches_delivered,
-               'wait_s': round(self._wait_s, 4),
-               'input_stall_frac': round(self._wait_s / elapsed, 4) if elapsed else 0.0,
-               'stage_dispatch_s': round(stage_s, 4),
+               'wait_s': round(wait_s, 4),
+               'input_stall_frac': round(wait_s / elapsed, 4) if elapsed else 0.0,
+               'stage_dispatch_s': round(
+                   stage_s - self._totals['stage_decode_s'], 4),
                'staged_bytes': staged_bytes,
                # Fields staged per transfer tier since construction: which
                # branch of _stage carried the dispatch.
@@ -2065,7 +2088,7 @@ class JaxLoader(object):
         if self._raw_specs:
             # Staging-step decode seconds of the on-device path (host
             # fallback; 0 when a device decode op carried the batches).
-            out['stage_decode_s'] = round(stage_decode_s, 4)
+            out['stage_decode_s'] = round(self._totals['stage_decode_s'], 4)
         if self._engine is not None:
             # Pipeline shape of the staging engine: per-stage busy seconds,
             # how much of the smaller stage ran concurrently with the other

@@ -91,7 +91,7 @@ class PyDictWorker(RowGroupWorkerBase):
                        'rows': rows}
             if pst_det is not None:
                 payload['det'] = pst_det
-            with get_global_tracer().span('handoff', 'worker'):
+            with get_global_tracer().span('reader.publish', 'reader'):
                 self.publish_func(payload)
         else:
             self._publish_hole(pst_det)
@@ -137,7 +137,7 @@ class PyDictWorker(RowGroupWorkerBase):
             decode_schema = (self.args['full_schema'].create_schema_view(
                 [n for n in field_names if n in self.args['full_schema'].fields])
                 if self.args['ngram'] is not None else schema)
-            with get_global_tracer().span('decode', 'worker'):
+            with get_global_tracer().span('decode.decode', 'decode'):
                 return decode_rows(encoded_rows, decode_schema,
                                    num_threads=self.args.get('decode_threads'),
                                    fault_key=rowgroup_fault_key(

@@ -50,9 +50,11 @@ import threading
 import time
 import weakref
 from collections import deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 
 import numpy as np
+
+from petastorm_tpu import trace
 
 logger = logging.getLogger(__name__)
 
@@ -316,10 +318,7 @@ class ArenaPool(object):
         # arena_wait_s reports the stall instead).
         self._meter = meter
         self._meter_stage = meter_stage
-        if tracer is None:
-            from petastorm_tpu.trace import NullTracer
-            tracer = NullTracer()
-        self._tracer = tracer
+        self._tracer = trace.resolve(tracer)
         self._cond = threading.Condition()
         self._free = []
         self._spec = None
@@ -332,9 +331,10 @@ class ArenaPool(object):
         # counters (reset_stats() zeroes these, never the pool itself)
         self._alloc = 0
         self._reuse = 0
-        self._wait_s = 0.0
-        # Registry mirror (petastorm_tpu.metrics): per-acquisition wait
-        # latency — the machine-scrapable arena-backpressure signal.
+        # Fed by the ``collate.arena_wait`` span, as is the registry mirror
+        # (petastorm_tpu.metrics): per-acquisition wait latency — the
+        # machine-scrapable arena-backpressure signal.
+        self._totals = {'arena_wait_s': 0.0}
         from petastorm_tpu import metrics as metrics_mod
         self._m_wait = metrics_mod.histogram(
             'pst_arena_wait_seconds',
@@ -357,57 +357,68 @@ class ArenaPool(object):
         ``grow_timeout_s`` allocate past ``depth`` instead of deadlocking.
         """
         with self._cond:
-            if not self._matches(spec):
+            if not self._matches(spec) or self._stop.is_set():
                 return None
-            waited = 0.0
-            waiting_hb = False
-            while True:
-                if self._stop.is_set():
-                    return None
-                if self._free:
-                    arena = self._free.pop()
-                    arena._reclaimed = False
-                    self._reuse += 1
-                    break
-                if self._allocated < self._depth or waited >= self._grow_timeout_s:
-                    arena = self._new_arena()
-                    self._allocated += 1
-                    self._alloc += 1
-                    # Growth is STICKY: depth tracks the high-water mark so
-                    # a consumer that legitimately pins more than the
-                    # initial depth (superbatches(k)) pays the grow timeout
-                    # once, not once per extra arena on every cycle.
-                    if self._allocated > self._depth:
-                        self._depth = self._allocated
-                    break
-                if self._heartbeat is not None and not waiting_hb:
-                    # One beat on entry, then let the age accrue: a wedged
-                    # pool must read as a stale 'arena-wait' heartbeat.
-                    self._heartbeat.beat('arena-wait')
-                    waiting_hb = True
+            arena = self._acquire(0.0)
+            if arena is None:
+                arena = self._wait_for_arena()
+            if arena is None:       # stopping
+                return None
+            self._pending = arena
+            self._tracer.counter('arena_pool_free', len(self._free), 'collate')
+            return arena.borrowed_buffers()
+
+    def _acquire(self, waited):
+        """(condition held) A free arena, or a new one while the pool may
+        still grow or the wait has outlived the grow deadline; else None."""
+        if self._free:
+            arena = self._free.pop()
+            arena._reclaimed = False
+            self._reuse += 1
+            return arena
+        if self._allocated < self._depth or waited >= self._grow_timeout_s:
+            arena = self._new_arena()
+            self._allocated += 1
+            self._alloc += 1
+            # Growth is STICKY: depth tracks the high-water mark so a
+            # consumer that legitimately pins more than the initial depth
+            # (superbatches(k)) pays the grow timeout once, not once per
+            # extra arena on every cycle.
+            if self._allocated > self._depth:
+                self._depth = self._allocated
+            return arena
+        return None
+
+    def _wait_for_arena(self):
+        """(condition held) Block until an arena can be had, or return None
+        when the pool stops: one ``collate.arena_wait`` span however many
+        times the condition woke, which is also what ``arena_wait_s`` and
+        ``pst_arena_wait_seconds`` are fed by."""
+        if self._heartbeat is not None:
+            # One beat on entry, then let the age accrue: a wedged pool
+            # must read as a stale 'arena-wait' heartbeat.
+            self._heartbeat.beat('arena-wait')
+        arena = None
+        with self._tracer.span('collate.arena_wait', 'collate',
+                               hist=self._m_wait,
+                               total=(self._totals, 'arena_wait_s')) as span, \
+                (self._meter.pause(self._meter_stage)
+                 if self._meter is not None else nullcontext()):
+            while arena is None and not self._stop.is_set():
                 # Real wakeups: release and GC-settle notify the condition
                 # (see _reclaim) and stop() paths call wake(), so acquire
-                # latency is no longer quantized to a poll interval and a
-                # missed wakeup cannot masquerade as arena starvation. The
-                # timeout is the grow deadline, capped only so an EXTERNAL
+                # latency is not quantized to a poll interval. The timeout
+                # is the grow deadline, capped only so an EXTERNAL
                 # stop_event set without wake() is still observed promptly
                 # (that cap bounds stop latency, not acquire latency).
-                timeout = min(max(self._grow_timeout_s - waited, 0.005), 0.25)
-                t0 = time.perf_counter()
-                if self._meter is not None:
-                    with self._meter.pause(self._meter_stage):
-                        self._cond.wait(timeout=timeout)
-                else:
-                    self._cond.wait(timeout=timeout)
-                waited += time.perf_counter() - t0
-                self._wait_s += time.perf_counter() - t0
-            if waiting_hb:
-                self._heartbeat.beat('collate')
-            if waited:
-                self._m_wait.observe(waited)
-            self._pending = arena
-            self._tracer.counter('arena_pool_free', len(self._free), 'staging')
-            return arena.borrowed_buffers()
+                waited = (time.perf_counter_ns() - span.start_ns) / 1e9
+                arena = self._acquire(waited)
+                if arena is None:
+                    self._cond.wait(timeout=min(
+                        max(self._grow_timeout_s - waited, 0.005), 0.25))
+        if self._heartbeat is not None:
+            self._heartbeat.beat('collate')
+        return arena
 
     def _new_arena(self):
         """One arena in the pool's current allocation mode (called with
@@ -485,7 +496,7 @@ class ArenaPool(object):
             else:
                 self._allocated -= 1   # grown-past-depth arena: let it die
             self._cond.notify_all()
-            self._tracer.counter('arena_pool_free', len(self._free), 'staging')
+            self._tracer.counter('arena_pool_free', len(self._free), 'collate')
 
     def reclaim_pending(self):
         """Shutdown path: an arena handed out but never claimed (the
@@ -564,14 +575,13 @@ class ArenaPool(object):
     def wait_seconds(self):
         """Cumulative assembler backpressure seconds (the autotuner's
         arena-bound signal)."""
-        with self._cond:
-            return self._wait_s
+        return self._totals['arena_wait_s']
 
     def stats(self):
         with self._cond:
             return {'arena_alloc': self._alloc,
                     'arena_reuse': self._reuse,
-                    'arena_wait_s': round(self._wait_s, 4),
+                    'arena_wait_s': round(self._totals['arena_wait_s'], 4),
                     'arena_depth': self._depth,
                     'arena_allocated': self._allocated,
                     'arena_pinned': self._pinned,
@@ -588,7 +598,7 @@ class ArenaPool(object):
         with self._cond:
             self._alloc = 0
             self._reuse = 0
-            self._wait_s = 0.0
+        trace.reset_totals(self._totals)
 
 
 class OverlapMeter(object):
@@ -703,15 +713,25 @@ class MeteredReader(object):
     starvation — an input-bound run must not read as perfectly overlapped
     pipelining. Every non-iteration attribute passes through."""
 
-    def __init__(self, reader, meter, stage='assemble', heartbeat=None):
+    def __init__(self, reader, meter, stage='assemble', heartbeat=None,
+                 tracer=None):
         self._pst_reader = reader
         self._pst_meter = meter
         self._pst_stage = stage
         self._pst_hb = heartbeat
+        self._pst_tracer = trace.resolve(tracer)
         # Cumulative seconds the assembler spent blocked in the reader —
-        # the autotuner's reader-starved signal (written by the assemble
-        # thread only; float rebinding is atomic for readers).
-        self.reader_wait_s = 0.0
+        # the autotuner's reader-starved signal, fed by the
+        # ``collate.reader_wait`` span (assemble thread only).
+        self._pst_totals = {'reader_wait_s': 0.0}
+
+    @property
+    def reader_wait_s(self):
+        return self._pst_totals['reader_wait_s']
+
+    @reader_wait_s.setter
+    def reader_wait_s(self, value):
+        self._pst_totals['reader_wait_s'] = value
 
     def __iter__(self):
         return self
@@ -724,12 +744,13 @@ class MeteredReader(object):
             # decode/IO tier produced nothing (reader-starved); 'collate'
             # = the batch-assembly work itself wedged (assemble-stuck).
             hb.beat('reader-wait')
-        t0 = time.perf_counter()
         try:
-            with self._pst_meter.pause(self._pst_stage):
+            with self._pst_tracer.span(
+                    'collate.reader_wait', 'collate',
+                    total=(self._pst_totals, 'reader_wait_s')), \
+                    self._pst_meter.pause(self._pst_stage):
                 return next(self._pst_reader)
         finally:
-            self.reader_wait_s += time.perf_counter() - t0
             if hb is not None:
                 hb.beat('collate')
 
@@ -795,10 +816,7 @@ class DeviceStager(object):
         self._h2d_tokens = 0
         self._h2d_span = None
         self._stop = stop_event if stop_event is not None else threading.Event()
-        if tracer is None:
-            from petastorm_tpu.trace import NullTracer
-            tracer = NullTracer()
-        self._tracer = tracer
+        self._tracer = trace.resolve(tracer)
         from petastorm_tpu import metrics as metrics_mod
         self._m_put = metrics_mod.histogram(
             'pst_device_put_seconds',
@@ -814,7 +832,7 @@ class DeviceStager(object):
         self._put_bytes = {k: 0 for k in self._keys}
         self._shards_put = 0
         self._donated = 0
-        self._ready_wait_s = 0.0
+        self._totals = {'ready_wait_s': 0.0}    # the streams' fence spans
         self._window_bytes = 0
         self._leaked_threads = []
         # Bounded (pstlint bounded-queues): one submission wave queues at
@@ -971,13 +989,14 @@ class DeviceStager(object):
         already-complete transfer. Returns whether an entry retired."""
         staged, nbytes = window.popleft()
         if block and not self._stop.is_set():
-            t0 = time.perf_counter()
             try:
-                self._ready_fn(staged)
+                with self._tracer.span('dispatch.fence', 'dispatch',
+                                       cause='device-stream',
+                                       total=(self._totals, 'ready_wait_s')):
+                    self._ready_fn(staged)
             except Exception:  # noqa: BLE001 - a dying fence must not kill the stream
                 logger.debug('device stager ready_fn failed', exc_info=True)
             with self._stats_lock:
-                self._ready_wait_s += time.perf_counter() - t0
                 self._window_bytes -= nbytes
             self._h2d_exit()
             return True
@@ -1065,8 +1084,7 @@ class DeviceStager(object):
         """Cumulative seconds streams spent fenced on their oldest
         in-flight transfer — folded into the autotuner's dispatch-bound
         signal next to the engine's batch-level fence."""
-        with self._stats_lock:
-            return self._ready_wait_s
+        return self._totals['ready_wait_s']
 
     @property
     def window_nbytes(self):
@@ -1086,7 +1104,8 @@ class DeviceStager(object):
                 'device_inflight': self._inflight,
                 'shards_put': self._shards_put,
                 'shards_donated': self._donated,
-                'device_ready_wait_s': round(self._ready_wait_s, 4),
+                'device_ready_wait_s': round(self._totals['ready_wait_s'],
+                                             4),
                 'device_put_s': {k: round(v, 4)
                                  for k, v in self._put_s.items()},
                 'device_put_bytes': dict(self._put_bytes),
@@ -1107,7 +1126,7 @@ class DeviceStager(object):
             self._put_bytes = {k: 0 for k in self._keys}
             self._shards_put = 0
             self._donated = 0
-            self._ready_wait_s = 0.0
+        trace.reset_totals(self._totals)
 
     @property
     def alive(self):
@@ -1140,6 +1159,21 @@ class DeviceStager(object):
                 '%.1fs join — a hung device_put is leaking them past '
                 'shutdown.', leaked, join_timeout_s)
         return leaked
+
+
+class StagedBatch(dict):
+    """A staged batch (field -> device array) that knows which batch it is:
+    ``seq``, the loader's batch sequence number (the id of its collate,
+    dispatch and consumer spans), and ``staged_ns``, when its puts had been
+    issued on ``time.perf_counter_ns()`` — the consumer's ``deliver`` record
+    says how long it then sat in the prefetch queue."""
+
+    __slots__ = ('seq', 'staged_ns')
+
+    def __init__(self, fields, seq=None):
+        super().__init__(fields)
+        self.seq = seq
+        self.staged_ns = time.perf_counter_ns()
 
 
 class _StageError(object):
@@ -1183,11 +1217,12 @@ class StagingEngine(object):
                  stage_with_arena=False):
         self._host_iter = host_iter
         self._stage_fn = stage_fn
-        # stage_with_arena: call ``stage_fn(batch, arena)`` so a device-
-        # sharded stage can reuse the arena's memoized per-device
+        # stage_with_arena: call ``stage_fn(batch, arena, span)`` so a
+        # device-sharded stage can reuse the arena's memoized per-device
         # sub-slice views (HostArena.shard_views) instead of re-slicing
-        # every batch. The arena still joins the in-flight window AFTER
-        # staging, exactly as before.
+        # every batch, and say in the ``dispatch.stage`` span (whose id is
+        # the batch's number) what carried it. The arena still joins the
+        # in-flight window AFTER staging, exactly as before.
         self._stage_with_arena = bool(stage_with_arena)
         self._out = out_queue
         self._stop = stop_event
@@ -1198,13 +1233,11 @@ class StagingEngine(object):
         self._is_ready_fn = is_ready_fn
         self._holds_mode = holds_mode
         self._on_drop = on_drop
-        if tracer is None:
-            from petastorm_tpu.trace import NullTracer
-            tracer = NullTracer()
-        self._tracer = tracer
+        self._tracer = trace.resolve(tracer)
+        # The meter measures co-activity of the two stages (overlap_frac),
+        # which no single span holds; every reported interval is a span's.
         self.meter = meter if meter is not None else OverlapMeter()
-        # Registry mirror (petastorm_tpu.metrics): per-batch assemble and
-        # dispatch latencies — the staging halves of the scrape surface.
+        # Registry mirror (petastorm_tpu.metrics) of the two stages' spans.
         from petastorm_tpu import metrics as metrics_mod
         self._m_assemble = metrics_mod.histogram(
             'pst_assemble_seconds', 'Host-batch collate latency per batch')
@@ -1213,7 +1246,12 @@ class StagingEngine(object):
             'batch (put issue time, not transfer completion)')
         self._stats_lock = threading.Lock()
         self._retired = 0
-        self._ready_wait_s = 0.0
+        # assemble_s: the collate.batch spans' SELF seconds (what their
+        # reader_wait and arena_wait children cover left out, so it cannot
+        # go negative); dispatch_s: the dispatch.stage spans' seconds;
+        # ready_wait_s: the dispatch.fence spans'.
+        self._totals = {'assemble_s': 0.0, 'dispatch_s': 0.0,
+                        'ready_wait_s': 0.0}
         self._leaked_threads = []
         # Health hookup (petastorm_tpu.health): both stage threads beat a
         # named heartbeat at every phase transition, so the watchdog can
@@ -1283,26 +1321,28 @@ class StagingEngine(object):
 
     def _assemble_body(self, hb):
         try:
+            seq = 0
             while not self._stop.is_set():
                 if hb is not None:
                     hb.beat('collate')
-                try:
-                    t_assemble = time.perf_counter()
-                    with self.meter.track('assemble'):
-                        with self._tracer.span('assemble', 'host'):
-                            batch = next(self._host_iter)
-                    self._m_assemble.observe(
-                        time.perf_counter() - t_assemble)
-                except StopIteration:
+                with self.meter.track('assemble'), self._tracer.span(
+                        'collate.batch', 'collate', id=seq,
+                        hist=self._m_assemble,
+                        self_total=(self._totals, 'assemble_s')) as span:
+                    batch = next(self._host_iter, _DONE)
+                    if batch is _DONE:
+                        span.id, span.cause = None, 'end-of-data'
+                if batch is _DONE:
                     break
                 arena = self._pool.claim_pending() if self._pool else None
                 if hb is not None:
                     hb.beat('stageq-put')
-                if not self._put(self._stage_q, (batch, arena)):
+                if not self._put(self._stage_q, (batch, arena, seq)):
                     if arena is not None:
                         arena.retire()
                     self._notify_drop()
                     return
+                seq += 1
         except Exception as e:  # noqa: BLE001 - surfaced to consumer
             if self._pool is not None:
                 self._pool.reclaim_pending()
@@ -1329,16 +1369,15 @@ class StagingEngine(object):
         except Exception:  # noqa: BLE001 - readiness probe must not kill dispatch
             return False
 
-    def _retire(self, staged, arena, wait):
+    def _retire(self, staged, arena, seq, wait):
         if arena is None:
             return
         if wait and not self._stop.is_set():
             if self._hb_dispatch is not None:
                 self._hb_dispatch.beat('ready-wait')
-            t0 = time.perf_counter()
-            self._ready_fn(staged)
-            with self._stats_lock:
-                self._ready_wait_s += time.perf_counter() - t0
+            with self._tracer.span('dispatch.fence', 'dispatch', id=seq,
+                                   total=(self._totals, 'ready_wait_s')):
+                self._ready_fn(staged)
         # Seeded use-after-reclaim (fault site 'arena-stale-view'): keep a
         # borrow-tagged view across the retire and touch it after. Armed
         # (PETASTORM_TPU_SANITIZE) the touch raises StaleViewError at the
@@ -1385,7 +1424,7 @@ class StagingEngine(object):
                         self._retire(*inflight.popleft(), wait=True)
                     self._put(self._out, item.exc)
                     return
-                batch, arena = item
+                batch, arena, seq = item
                 if self._stop.is_set():
                     # Never issue device puts into a stopping pipe (the old
                     # stage loop's fetch/stage stop-check): on a wedged
@@ -1403,26 +1442,31 @@ class StagingEngine(object):
                 # pipeline error.
                 from petastorm_tpu.analysis import sanitize
                 sanitize.maybe_inject_lock_inversion()
-                t_dispatch = time.perf_counter()
-                with self.meter.track('dispatch'):
-                    with self._tracer.span('dispatch', 'device'):
-                        if self._stage_with_arena:
-                            staged = self._stage_fn(batch, arena)
-                        else:
-                            staged = self._stage_fn(batch)
-                self._m_dispatch.observe(time.perf_counter() - t_dispatch)
+                # Dispatch time only (device_put is async): the transfer
+                # overlaps the consumer's step and ends under a fence.
+                with self.meter.track('dispatch'), self._tracer.span(
+                        'dispatch.stage', 'dispatch', id=seq,
+                        hist=self._m_dispatch,
+                        total=(self._totals, 'dispatch_s')) as span:
+                    if self._stage_with_arena:
+                        staged = self._stage_fn(batch, arena, span)
+                    else:
+                        staged = self._stage_fn(batch)
                 if arena is not None:
                     if self._holds_mode:
                         for value in staged.values():
                             arena.add_hold(value)
-                    inflight.append((staged, arena))
+                    inflight.append((staged, arena, seq))
                     arena = None
                     self._tracer.counter('staging_inflight', len(inflight),
-                                         'staging')
+                                         'dispatch')
                 del batch
                 if hb is not None:
                     hb.beat('out-put')
-                if not self._put(self._out, staged):
+                with self._tracer.span('dispatch.queue_put', 'dispatch',
+                                       id=seq):
+                    delivered = self._put(self._out, staged)
+                if not delivered:
                     self._notify_drop()
                     return
                 del staged
@@ -1473,8 +1517,7 @@ class StagingEngine(object):
         """Cumulative seconds the dispatch stage spent fenced on the
         oldest in-flight transfer — the autotuner's dispatch-bound signal
         (cheaper than a full :meth:`stats` sample on a sub-second tick)."""
-        with self._stats_lock:
-            return self._ready_wait_s
+        return self._totals['ready_wait_s']
 
     def stop(self, join_timeout_s=10):
         """Idempotent: set stop, unblock both threads, join them, settle
@@ -1527,10 +1570,11 @@ class StagingEngine(object):
         m = self.meter.stats()
         total = self.meter.stats(total=True)
         with self._stats_lock:
-            retired, ready_wait = self._retired, self._ready_wait_s
+            retired = self._retired
             leaked = list(self._leaked_threads)
-        return {'assemble_s': m['busy_s'].get('assemble', 0.0),
-                'dispatch_s': m['busy_s'].get('dispatch', 0.0),
+        ready_wait = self._totals['ready_wait_s']
+        return {'assemble_s': round(self._totals['assemble_s'], 4),
+                'dispatch_s': round(self._totals['dispatch_s'], 4),
                 'overlap_s': m['overlap_s'],
                 'overlap_frac': m['overlap_frac'],
                 'overlap_frac_total': total['overlap_frac'],
@@ -1542,4 +1586,4 @@ class StagingEngine(object):
         self.meter.reset()
         with self._stats_lock:
             self._retired = 0
-            self._ready_wait_s = 0.0
+        trace.reset_totals(self._totals)

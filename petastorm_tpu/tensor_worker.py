@@ -21,7 +21,6 @@ static shapes anyway); ``make_tensor_reader`` validates this up front.
 """
 
 import logging
-import time
 
 import numpy as np
 import pyarrow as pa
@@ -71,40 +70,43 @@ class TensorWorker(RowGroupWorkerBase):
 
     def process(self, piece_index, worker_predicate=None,
                 shuffle_row_drop_partition=None, pst_det=None):
+        from petastorm_tpu import metrics
         from petastorm_tpu.faults import maybe_inject, rowgroup_fault_key
+        from petastorm_tpu.trace import get_global_tracer
 
         piece = self.args['row_groups'][piece_index]
         schema = self.args['schema']
         maybe_inject('decode-corrupt',
                      key=rowgroup_fault_key(piece.path, piece.row_group))
+        # One span a stage, each adding its seconds to the chunk's
+        # ``timings`` (they cross process pools on the payload): the miss's
+        # read and decode nest inside ``reader.cache_get``, whose SELF time
+        # is ``cache_s``: the cache's own bookkeeping, neither counted twice.
+        key = self._trace_id = chunk_key(piece_index,
+                                         shuffle_row_drop_partition)
         timings = {}
+        self._read_total = (timings, 'read_s')
+        tracer = get_global_tracer()
         decoded_fresh = []    # load() ran => served from decode, not a cache
 
         def load():
-            from petastorm_tpu import metrics
-            from petastorm_tpu.trace import get_global_tracer
-
             decoded_fresh.append(True)
-            t0 = time.perf_counter()
             table = self._load_table(piece, worker_predicate)
-            timings['read_s'] = time.perf_counter() - t0
             if table is None or table.num_rows == 0:
                 return None
-            t0 = time.perf_counter()
-            # The decode span (process-local global tracer — a sidecar
-            # spiller inside pool workers, see trace.install_worker_tracer)
-            # is what makes worker-subprocess decode visible on a merged
-            # timeline; the histogram is its scrape-surface twin.
-            with get_global_tracer().span('decode', 'worker'):
+            # Recorded by the process-local global tracer (a sidecar
+            # spiller inside pool workers, see trace.install_worker_tracer),
+            # which is what makes worker-subprocess decode visible on a
+            # merged timeline.
+            with tracer.span('decode.decode', 'decode', id=key,
+                             total=(timings, 'decode_s'),
+                             hist=metrics.histogram(
+                                 'pst_decode_seconds',
+                                 'Row-group decode latency inside workers')):
                 cols = decode_table_to_blocks(
                     table, schema, self.args.get('decode_threads'),
                     fault_key=rowgroup_fault_key(piece.path, piece.row_group),
                     raw_fields=self.args.get('raw_image_fields') or ())
-            timings['decode_s'] = time.perf_counter() - t0
-            metrics.histogram(
-                'pst_decode_seconds',
-                'Row-group decode latency inside workers').observe(
-                    timings['decode_s'])
             return cols
 
         from petastorm_tpu.cache import NullCache
@@ -127,13 +129,10 @@ class TensorWorker(RowGroupWorkerBase):
             from petastorm_tpu.chunk_store import tensor_chunk_key
             cache_key = tensor_chunk_key(self.args['dataset_path_hash'],
                                          piece.path, piece.row_group, schema)
-            t0 = time.perf_counter()
-            cols = self.args['cache'].get(cache_key, load)
-            # Cache bookkeeping only: the miss's read/decode seconds are
-            # reported under their own keys, not double-counted here.
-            timings['cache_s'] = (time.perf_counter() - t0
-                                  - timings.get('read_s', 0.0)
-                                  - timings.get('decode_s', 0.0))
+            with tracer.span('reader.cache_get', 'reader', id=key,
+                             self_total=(timings, 'cache_s')) as span:
+                cols = self.args['cache'].get(cache_key, load)
+                span.cause = 'miss' if decoded_fresh else 'hit'
         else:
             cols = load()
         if cols is None:
@@ -181,7 +180,6 @@ class TensorWorker(RowGroupWorkerBase):
 
         if n_rows:
             from petastorm_tpu.lineage import chunk_lineage
-            from petastorm_tpu.trace import get_global_tracer
             # Serving tier: a fresh decode when load() actually ran (incl.
             # every predicate read, which bypasses the cache), else the
             # cache's own tier label (memory / chunk-store / disk).
@@ -194,14 +192,14 @@ class TensorWorker(RowGroupWorkerBase):
                 filtered=worker_predicate is not None,
                 worker_id=self.worker_id)
             payload = {'__pst_tensor_chunk__': 1,
-                       'key': chunk_key(piece_index, shuffle_row_drop_partition),
+                       'key': key,
                        'cols': cols,
                        'private': private,
                        'lineage': lineage,
                        'timings': timings}
             if pst_det is not None:
                 payload['det'] = pst_det
-            with get_global_tracer().span('handoff', 'worker'):
+            with tracer.span('reader.publish', 'reader', id=key):
                 self.publish_func(payload)
         else:
             self._publish_hole(pst_det)

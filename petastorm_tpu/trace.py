@@ -1,26 +1,42 @@
-"""Input-pipeline tracing: chrome://tracing timelines across processes.
+"""Input-pipeline tracing: one span stream from the reader to the consumer.
 
-The reference's observability stops at per-thread cProfile aggregates
-(SURVEY §5.1 — "No distributed tracing"). This records *spans* — named,
-timestamped durations per thread — and exports the Chrome trace-event JSON
-that chrome://tracing / Perfetto render as a timeline, which is how you SEE
-an input stall: the consumer's ``wait`` spans grow exactly when the staging
-thread's ``device_put`` spans (or the workers' decode) stretch.
+Every interval the pipeline clocks is clocked HERE, once: a :class:`Span`
+reads ``time.perf_counter_ns()`` (and, on the pool's worker threads, the
+thread's own CPU clock) on entry and exit, and closing it (a) appends one
+record to the tracer's bounded ring, (b) adds its *self* seconds (its
+duration less what spans nested inside it on the same thread covered) to the
+running total that ``loader.stats`` / ``worker_stage_timings`` report, and
+(c) observes the site's ``pst_*_seconds`` histogram. No call site keeps a
+``perf_counter()`` pair or an ``observe()`` of its own.
 
-Cross-process story (the piece a single in-memory tracer cannot give you —
-worker-subprocess decode dominates the cold path, PROFILE_r05): every
-:class:`Tracer` can additionally *spill* its events to a per-process JSONL
-sidecar file. Setting the ``PETASTORM_TPU_TRACE_DIR`` environment variable
-arms spill for every tracer built afterwards — including the ones the
-process-pool worker bootstraps install (workers are spawned and inherit the
-environment, the same activation channel ``faults.py`` uses). Sidecars are
-append-only, line-buffered, and bounded: a worker that dies mid-write
-leaves at most one torn trailing line, which :meth:`Tracer.
-merge_process_files` (and the ``python -m petastorm_tpu.tools.trace_merge``
-CLI) skip. After a run, merging folds every process's events — shifted
-onto the parent's timebase via each sidecar's wall-clock anchor — into one
-timeline where worker ``decode`` tracks (real pids) sit next to the
-loader's ``assemble``/``stage``/``wait`` tracks.
+Records are plain tuples:
+
+* a span is ``(name, layer, start_ns, dur_ns, cpu_ns, tid, id, cause)``:
+  ``start_ns`` on ``time.perf_counter_ns()`` (absolute: the clock a training
+  loop pins to a device trace), ``cpu_ns`` the thread's CPU time inside the
+  span for the layers in :data:`CPU_LAYERS` (the pool's worker threads) and
+  ``None`` for the others, ``id`` the row-group key
+  (``'<piece>:<drop-partition>'``) for reader and decode spans and the
+  loader's batch sequence number from collate onwards, ``cause`` what
+  produced it (``reader.cache_get`` says hit or miss, ``dispatch.stage``
+  the transfer tiers, ``consumer.deliver`` when its batch was staged);
+* an instant is a span record with ``dur_ns`` ``None``; in those layers its
+  ``cpu_ns`` slot holds the thread's CPU clock at that moment, so successive
+  instants of one thread say how much CPU it burnt between them, inside
+  spans or not;
+* a counter is ``(name, layer, t_ns, value)``.
+
+**On by default.** :func:`get_global_tracer` returns a process-wide ring of
+:data:`DEFAULT_RING_EVENTS` records (about 7 MB when full: minutes of a
+training run, oldest dropped first) unless :func:`set_global_tracer` installed
+another tracer, and every pipeline object built with ``tracer=None`` records
+there. ``set_global_tracer(NullTracer())`` is the one way to switch it off
+(totals and histograms keep working; nothing is recorded). A watchdog stall
+dump therefore holds the last seconds of spans though nobody armed anything.
+
+The chrome://tracing / Perfetto export, the per-process JSONL sidecars
+(``PETASTORM_TPU_TRACE_DIR``), :meth:`Tracer.merge_process_files` and
+:meth:`Tracer.summary` are all derived from those tuples.
 
 Usage::
 
@@ -32,7 +48,8 @@ Usage::
     tracer.merge_process_files()
     tracer.export_chrome_trace('/tmp/input_pipeline.json')
 
-Pure stdlib, thread-safe, bounded (drops oldest beyond ``max_events``;
+Pure stdlib (jax is touched only by :func:`watch_jax_compiles`, which the
+loader calls), thread-safe, bounded (drops oldest beyond ``max_events``;
 sidecars stop at ``spill_max_events`` lines).
 """
 
@@ -44,16 +61,105 @@ import threading
 import time
 import uuid
 from collections import deque
-from contextlib import contextmanager
 
 logger = logging.getLogger(__name__)
 
 #: Directory that arms per-process sidecar spill for every Tracer built
-#: while it is set (inherited by spawned worker processes).
+#: while it is set (inherited by spawned worker processes). The default
+#: ring never spills: sidecars are for tracers somebody built.
 TRACE_DIR_ENV = 'PETASTORM_TPU_TRACE_DIR'
+
+#: Bound of the default process-wide ring, in records. A full ring measures
+#: about 7 MB (216 bytes a span with its numbers). The two benchmark cells
+#: write 60 and 160 records a second on the chip and hold 2,000 and 7,600
+#: when a traced run's metrics are read (PERF.md); a streamed four-chip cell
+#: should write about 600 a second, 50 s of which fit.
+DEFAULT_RING_EVENTS = 32768
 
 _SIDECAR_GLOB = 'trace-*.jsonl'
 _HEADER_KEY = '__pst_trace_sidecar__'
+
+#: The layers whose spans and instants read ``time.thread_time_ns()``: the
+#: pool's worker threads, whose idle CPU a benchmark metric names. The
+#: loader's own threads do not: on a TPU host one read measured 6 µs (a
+#: span with it 15 µs, without 2.5, PERF.md), it holds the interpreter lock
+#: the collate and dispatch threads need, and that clock ticks in 10 ms
+#: steps, so a span of microseconds would read 0 anyway.
+CPU_LAYERS = ('reader', 'decode')
+
+_perf_ns = time.perf_counter_ns
+_cpu_ns = time.thread_time_ns
+_tid = threading.get_ident
+# The innermost open span of each thread: a closing span hands its duration
+# to the one it was opened inside, which is how self time is known without
+# subtracting totals from one another.
+_open = threading.local()
+# Several threads' spans may feed one total (the per-device streams' fences),
+# and a reset may race them: adds and resets take this lock.
+_totals_lock = threading.Lock()
+
+
+def reset_totals(totals):
+    """Zero every key of a mapping that closing spans add to."""
+    with _totals_lock:
+        for key in totals:
+            totals[key] = 0.0
+
+
+class Span(object):
+    """One clocked interval; use as a context manager. After it closed,
+    ``dur_ns``, ``cpu_ns`` (``None`` outside :data:`CPU_LAYERS`) and
+    ``self_ns`` (duration less nested spans of the same thread) are
+    readable, and ``id`` / ``cause`` may be set any time before it closes."""
+
+    __slots__ = ('_sink', 'name', 'layer', 'id', 'cause', '_hist', '_total',
+                 '_self_total', '_parent', '_child_ns', 'start_ns', 'cpu0_ns',
+                 'dur_ns', 'cpu_ns')
+
+    def __init__(self, sink, name, layer, id, cause, hist, total,
+                 self_total):
+        self._sink = sink
+        self.name = name
+        self.layer = layer
+        self.id = id
+        self.cause = cause
+        self._hist = hist
+        self._total = total
+        self._self_total = self_total
+        self._child_ns = 0
+
+    def __enter__(self):
+        self._parent = getattr(_open, 'span', None)
+        _open.span = self
+        self.cpu0_ns = _cpu_ns() if self.layer in CPU_LAYERS else None
+        self.start_ns = _perf_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur_ns = dur = _perf_ns() - self.start_ns
+        self.cpu_ns = (None if self.cpu0_ns is None
+                       else _cpu_ns() - self.cpu0_ns)
+        parent = _open.span = self._parent
+        if parent is not None:
+            parent._child_ns += dur
+        if self._total is not None or self._self_total is not None:
+            with _totals_lock:
+                if self._total is not None:
+                    totals, key = self._total
+                    totals[key] = totals.get(key, 0.0) + dur / 1e9
+                if self._self_total is not None:
+                    totals, key = self._self_total
+                    totals[key] = totals.get(key, 0.0) + self.self_ns / 1e9
+        if self._hist is not None:
+            self._hist.observe(dur / 1e9)
+        if self._sink is not None:
+            self._sink._record((self.name, self.layer, self.start_ns, dur,
+                                self.cpu_ns, _tid(), self.id, self.cause))
+        return False
+
+    @property
+    def self_ns(self):
+        return max(0, self.dur_ns - self._child_ns)
 
 
 class Tracer(object):
@@ -73,11 +179,11 @@ class Tracer(object):
 
     def __init__(self, max_events=100000, spill_dir=None, role=None,
                  spill_max_events=None):
-        # deque(maxlen=...): O(1) drop-oldest — a full list.pop(0) buffer
-        # would shift max_events pointers inside the hot-path lock.
+        # deque(maxlen=...): O(1) drop-oldest, and append is atomic under
+        # the GIL, so recording takes no lock unless a sidecar is armed.
         self._events = deque(maxlen=max_events)
         self._lock = threading.Lock()
-        self._t0 = time.perf_counter()
+        self._t0_ns = _perf_ns()
         # Wall-clock anchor of t0: what lets merge align sidecars recorded
         # by other processes (perf_counter is process-local) onto one
         # timeline. Same-host clocks, so the alignment is ~exact.
@@ -101,51 +207,70 @@ class Tracer(object):
 
     # -- recording ---------------------------------------------------------
 
-    @contextmanager
-    def span(self, name, cat='pipeline'):
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            end = time.perf_counter()
-            self._append({
-                'name': name, 'cat': cat, 'ph': 'X',
-                'ts': (start - self._t0) * 1e6,      # microseconds
-                'dur': (end - start) * 1e6,
-                'pid': self._pid, 'tid': threading.get_ident(),
-            })
+    def span(self, name, cat='pipeline', id=None, cause=None, hist=None,
+             total=None, self_total=None):
+        """A :class:`Span` of layer ``cat``. On close ``total=(mapping,
+        key)`` gets the span's seconds added, ``self_total`` its self
+        seconds, and ``hist`` (a metrics histogram) observes its
+        duration."""
+        return Span(self, name, cat, id, cause, hist, total, self_total)
 
-    def instant(self, name, cat='pipeline', args=None):
-        """A zero-duration marker event. ``args`` (a JSON-safe dict)
-        renders in the trace viewer's detail pane — the autotuner attaches
-        each decision's knob changes so the timeline shows *what* changed
-        at the marker, not just that something did."""
-        event = {
-            'name': name, 'cat': cat, 'ph': 'i', 's': 't',
-            'ts': (time.perf_counter() - self._t0) * 1e6,
-            'pid': self._pid, 'tid': threading.get_ident(),
-        }
-        if args:
-            event['args'] = dict(args)
-        self._append(event)
+    def instant(self, name, cat='pipeline', args=None, id=None):
+        """A zero-duration marker. ``args`` (JSON-safe) renders in the
+        trace viewer's detail pane — the autotuner attaches each decision's
+        knob changes, ``consumer.deliver`` the time its batch was staged.
+        In :data:`CPU_LAYERS` the record's ``cpu_ns`` slot holds the
+        thread's CPU clock."""
+        self._record((name, cat, _perf_ns(), None,
+                      _cpu_ns() if cat in CPU_LAYERS else None, _tid(), id,
+                      args))
 
     def counter(self, name, value, cat='pipeline'):
-        """A counter-track sample (chrome trace 'C' event): renders as a
-        filled area chart. Used by the staging engine for arena-pool
-        occupancy and the in-flight transfer window, so a timeline shows
-        backpressure (pool pinned at 0 free) next to the spans it stalls."""
-        self._append({
-            'name': name, 'cat': cat, 'ph': 'C',
-            'ts': (time.perf_counter() - self._t0) * 1e6,
-            'pid': self._pid, 'tid': threading.get_ident(),
-            'args': {name: value},
-        })
+        """A counter sample (chrome trace 'C' event: a filled area chart)
+        next to the spans it explains: arena-pool occupancy, the in-flight
+        transfer window, a wait's empty wake-ups."""
+        self._record((name, cat, _perf_ns(), value))
 
-    def _append(self, event):
-        with self._lock:
-            self._events.append(event)
-            if self._spill_dir is not None:
-                self._spill(event)
+    def _record(self, record):
+        self._events.append(record)
+        if self._spill_dir is not None:
+            with self._lock:
+                self._spill(self._chrome(record))
+
+    def records(self):
+        """A snapshot of the ring's raw tuples, oldest first (see the module
+        docstring for the two shapes)."""
+        while True:
+            try:
+                return list(self._events)
+            except RuntimeError:     # appended to while copying: again
+                continue
+
+    def _chrome(self, record):
+        """One raw record as a Chrome trace event (``ts``/``dur`` in
+        microseconds since this tracer was built)."""
+        if len(record) == 4:
+            name, layer, t_ns, value = record
+            return {'name': name, 'cat': layer, 'ph': 'C',
+                    'ts': (t_ns - self._t0_ns) / 1e3, 'pid': self._pid,
+                    'tid': 0, 'args': {name: value}}
+        name, layer, start_ns, dur_ns, cpu_ns, tid, id, cause = record
+        event = {'name': name, 'cat': layer, 'ph': 'X',
+                 'ts': (start_ns - self._t0_ns) / 1e3, 'pid': self._pid,
+                 'tid': tid}
+        if dur_ns is None:
+            event.update(ph='i', s='t')
+            args = dict(cause) if isinstance(cause, dict) else {}
+        else:
+            event['dur'] = dur_ns / 1e3
+            args = {} if cpu_ns is None else {'cpu_us': cpu_ns / 1e3}
+            if cause is not None:
+                args['cause'] = cause
+        if id is not None:
+            args['id'] = id
+        if args:
+            event['args'] = args
+        return event
 
     # -- sidecar spill -----------------------------------------------------
 
@@ -279,8 +404,10 @@ class Tracer(object):
 
     @property
     def events(self):
+        """Chrome trace events: this tracer's records, then merged ones."""
         with self._lock:
-            return list(self._events) + list(self._merged)
+            merged = list(self._merged)
+        return [self._chrome(r) for r in self.records()] + merged
 
     def summary(self):
         """Per-span-name latency digest — the quick-look view that makes a
@@ -372,10 +499,11 @@ def install_worker_tracer(role=None):
     """Worker-bootstrap hook: when ``PETASTORM_TPU_TRACE_DIR`` is set
     (inherited from the parent through the spawn environment), build a
     spilling tracer, install it as this process's global tracer, and
-    return it (the bootstrap ``close()``\\ s it on shutdown). Returns
-    ``None`` when tracing is unarmed — instrumentation points then hit
-    the shared :class:`NullTracer` at near-zero cost."""
+    return it (the bootstrap closes it on shutdown). With no directory
+    set nobody could read this process's ring, so recording is switched
+    off (the chunk's ``timings`` are still clocked) and ``None`` returned."""
     if not os.environ.get(TRACE_DIR_ENV):
+        set_global_tracer(NullTracer())
         return None
     tracer = Tracer(role=role or 'worker-{}'.format(os.getpid()))
     set_global_tracer(tracer)
@@ -383,13 +511,15 @@ def install_worker_tracer(role=None):
 
 
 _global_tracer = None
+_default_ring = None
 
 
 def set_global_tracer(tracer):
-    """Install a process-wide tracer that instrumentation points with no
-    Tracer argument (e.g. fault-injection sites in ``faults.py`` and the
-    worker-side read/decode/handoff spans) report to. Pass ``None`` to
-    reset. Returns the previous global tracer."""
+    """Install the process-wide tracer: what :func:`get_global_tracer`
+    returns, hence what every pipeline object built with ``tracer=None``
+    and every worker-side site records to. ``NullTracer()`` switches
+    recording off; ``None`` goes back to the default ring. Returns the
+    previous setting (``None`` when the default ring was in use)."""
     global _global_tracer
     previous = _global_tracer
     _global_tracer = tracer
@@ -397,12 +527,60 @@ def set_global_tracer(tracer):
 
 
 def get_global_tracer():
-    """The tracer installed by :func:`set_global_tracer`, or a shared
-    :class:`NullTracer` when none is set (call sites never branch)."""
-    return _global_tracer if _global_tracer is not None else _NULL_TRACER
+    """The tracer installed by :func:`set_global_tracer`, else the
+    process-wide default ring (:data:`DEFAULT_RING_EVENTS` records, never
+    spilled), built on first use."""
+    global _default_ring
+    if _global_tracer is not None:
+        return _global_tracer
+    if _default_ring is None:
+        _default_ring = Tracer(max_events=DEFAULT_RING_EVENTS,
+                               spill_dir=False)
+    return _default_ring
+
+
+def resolve(tracer):
+    """``tracer`` itself, or the global one for ``None``: what every
+    ``tracer=None`` constructor argument means."""
+    return get_global_tracer() if tracer is None else tracer
+
+
+# -- compilations ----------------------------------------------------------------
+
+# Of a compilation's stages only this one is recorded: tracing fires once a
+# traced sub-function (thousands of records for one train step, enough to
+# push a run's set-up out of the ring) and says nothing the last stage does
+# not.
+_JAX_BACKEND_COMPILE = '/jax/core/compile/backend_compile_duration'
+_jax_watched = False
+
+
+def _on_jax_duration(event, duration_secs, **kwargs):
+    if event == _JAX_BACKEND_COMPILE:
+        # The listener fires as the stage ends: it began that long ago.
+        dur_ns = int(duration_secs * 1e9)
+        get_global_tracer()._record(
+            ('jax.compile', 'step', _perf_ns() - dur_ns, dur_ns, 0, _tid(),
+             'backend_compile', kwargs.get('fun_name')))
+
+
+def watch_jax_compiles():
+    """Register, once a process, a ``jax.monitoring`` listener that writes
+    a ``jax.compile`` span (``id``: ``backend_compile``; ``cause``: the
+    function's name) to the global tracer for every program handed to the
+    backend. That stage covers the persistent cache's lookup, so a program
+    met for the first time shows even when the cache had it: in a training
+    loop's timed window there should be none."""
+    global _jax_watched
+    if not _jax_watched:
+        _jax_watched = True
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 class _NullSpan(object):
+    id = cause = None
+
     def __enter__(self):
         return self
 
@@ -410,22 +588,23 @@ class _NullSpan(object):
         return False
 
 
-class NullTracer(object):
-    """No-op stand-in so call sites never branch."""
+class NullTracer(Tracer):
+    """Records nothing. A span that feeds a total or a histogram is still
+    clocked (``loader.stats`` must not depend on tracing being on); one
+    that feeds neither costs nothing."""
 
     _SPAN = _NullSpan()
 
-    def span(self, name, cat='pipeline'):
-        return self._SPAN
+    def __init__(self):
+        super().__init__(max_events=0, spill_dir=False, role='off')
 
-    def instant(self, name, cat='pipeline', args=None):
+    def span(self, name, cat='pipeline', id=None, cause=None, hist=None,
+             total=None, self_total=None):
+        if hist is None and total is None and self_total is None:
+            return self._SPAN
+        return Span(None, name, cat, id, cause, hist, total, self_total)
+
+    def instant(self, *args, **kwargs):
         pass
 
-    def counter(self, name, value, cat='pipeline'):
-        pass
-
-    def close(self):
-        pass
-
-
-_NULL_TRACER = NullTracer()
+    counter = _record = instant

@@ -409,9 +409,10 @@ def _worker_bootstrap(worker_class, worker_id, worker_args,
 
     _start_orphan_watchdog(parent_pid)
     # Cross-process tracing (trace.py): when PETASTORM_TPU_TRACE_DIR is set
-    # (inherited through the spawn environment), this worker's read/decode/
-    # handoff spans spill to a per-process JSONL sidecar the parent merges
-    # into one timeline. None when unarmed — spans then hit the NullTracer.
+    # (inherited through the spawn environment), this worker's reader.read /
+    # decode.decode / reader.publish spans spill to a per-process JSONL
+    # sidecar the parent merges into one timeline. None when unarmed —
+    # recording is then off in this process (nobody could read its ring).
     worker_tracer = install_worker_tracer(
         role='worker-{}'.format(worker_id))
 

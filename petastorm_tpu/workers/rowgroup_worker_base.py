@@ -38,6 +38,11 @@ class RowGroupWorkerBase(WorkerBase):
         self._native_parquet = None      # resolved lazily at first read
         self._native_required = False
         self._leaf_index_cache = {}
+        #: What the ``reader.read`` span is tagged with and where it adds
+        #: its seconds: the worker sets both to the chunk it is producing
+        #: (its key, its ``timings`` dict).
+        self._trace_id = None
+        self._read_total = None
 
     def initialize(self):
         self._store = self.args['store_factory']()
@@ -101,7 +106,9 @@ class RowGroupWorkerBase(WorkerBase):
         pyarrow for remote stores, nested columns, or build failure.
         """
         from petastorm_tpu.trace import get_global_tracer
-        with get_global_tracer().span('read', 'worker'):
+        with get_global_tracer().span('reader.read', 'reader',
+                                      id=self._trace_id,
+                                      total=self._read_total):
             return self._read_row_group_traced(piece, columns)
 
     def _read_row_group_traced(self, piece, columns):

@@ -14,6 +14,7 @@ import threading
 from collections import deque
 
 from petastorm_tpu.membudget import approx_nbytes, get_governor
+from petastorm_tpu.trace import get_global_tracer
 from petastorm_tpu.utils import drain_queue
 from petastorm_tpu.workers import (EmptyResultError, RowGroupQuarantined,
                                    TimeoutWaitingForResultError,
@@ -59,20 +60,16 @@ class WorkerThread(threading.Thread):
                 self.profile = None
         try:
             self._worker.initialize()
-            while not self._pool._stop_event.is_set():
-                # Retire check sits BETWEEN items only: a worker that has
-                # already popped a ventilated item always processes it, so
-                # a shrinking resize() can never drop work on the floor.
-                if self._pool._should_retire(self):
+            while True:
+                item = self._take()
+                if item is None:
                     return
-                try:
-                    args, kwargs = self._pool._ventilator_queue.get(
-                        timeout=_VENTILATION_POLL_TIMEOUT_S)
-                except queue.Empty:
-                    continue
+                args, kwargs = item
                 try:
                     self._worker.process(*args, **kwargs)
-                    self._pool._put_result(VentilatedItemProcessedMessage())
+                    with get_global_tracer().span('reader.publish', 'reader'):
+                        self._pool._put_result(
+                            VentilatedItemProcessedMessage())
                 except _WorkerTerminationRequested:
                     return
                 except Exception as e:  # noqa: BLE001 - surfaces to consumer
@@ -84,6 +81,36 @@ class WorkerThread(threading.Thread):
             if self._profiling_enabled and self.profile is not None:
                 self.profile.disable()
             self._worker.shutdown()
+
+    def _take(self):
+        """The next ventilated item, or ``None`` once the pool stops or this
+        worker retires. One ``reader.take`` span from entering the wait to
+        leaving it, however many times the poll woke empty: the wake-ups
+        are counted here and written once (``reader.vent_polls``), or ten
+        idle workers would put 10,000 records a second into the ring. The
+        instant before it carries this thread's CPU clock, so CPU burnt
+        under no span shows too."""
+        pool = self._pool
+        tracer = get_global_tracer()
+        tracer.instant('reader.thread_cpu', 'reader')
+        polls = 0
+        with tracer.span('reader.take', 'reader'):
+            # Retire check sits BETWEEN items only: a worker that has
+            # already popped a ventilated item always processes it, so
+            # a shrinking resize() can never drop work on the floor.
+            while not pool._stop_event.is_set() \
+                    and not pool._should_retire(self):
+                try:
+                    item = pool._ventilator_queue.get(
+                        timeout=_VENTILATION_POLL_TIMEOUT_S)
+                    break
+                except queue.Empty:
+                    polls += 1
+            else:
+                item = None
+        if polls:
+            tracer.counter('reader.vent_polls', polls, 'reader')
+        return item
 
 
 class ThreadPool(object):
@@ -292,13 +319,18 @@ class ThreadPool(object):
             if get_governor().armed:
                 self.result_nbytes_ema += 0.25 * (approx_nbytes(data)
                                                   - self.result_nbytes_ema)
+        retries = 0
         while True:
             if self._stop_event.is_set():
                 raise _WorkerTerminationRequested()
             try:
                 self._results_queue.put(data, timeout=_RESULTS_POLL_TIMEOUT_S)
             except queue.Full:
+                retries += 1
                 continue
+            if retries:     # written once, by the put that got through
+                get_global_tracer().counter('reader.publish_retries',
+                                            retries, 'reader')
             depth = (self._results_queue.qsize()
                      + len(self._pending_results))
             if depth > self._results_peak:   # racy double-check is fine: a

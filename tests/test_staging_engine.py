@@ -582,6 +582,36 @@ def test_fence_pipelining_window_never_drains():
                    for t in threading.enumerate() if t.is_alive())
 
 
+def test_four_streams_fencing_at_once_lose_no_wait_seconds():
+    """``device_ready_wait_s`` is fed by the ``dispatch.fence`` spans of
+    every stream's thread: with four streams fencing together it is still
+    the sum of those spans."""
+    import sys
+
+    from petastorm_tpu.staging import DeviceStager
+    from petastorm_tpu.trace import Tracer
+    tracer = Tracer()
+    st = DeviceStager(['d0', 'd1', 'd2', 'd3'],
+                      lambda array, stream, donate: _FakeStaged(array.tag),
+                      inflight=1, ready_fn=lambda staged: None, tracer=tracer)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for i in range(300):
+            st.put_shards([(d, _FakeShard('s{}.{}'.format(i, d)), False)
+                           for d in range(4)])
+        waited = st.stats()['device_ready_wait_s']
+        assert st.ready_wait_seconds == pytest.approx(waited, abs=1e-4)
+    finally:
+        sys.setswitchinterval(interval)
+        st.stop()
+    fences = [r for r in tracer.records() if r[0] == 'dispatch.fence']
+    assert len(fences) == 4 * 299 and len({r[5] for r in fences}) == 4
+    assert waited == pytest.approx(sum(r[3] for r in fences) / 1e9, abs=1e-4)
+    st.reset_stats()
+    assert st.ready_wait_seconds == 0.0
+
+
 def test_fence_pipelining_under_device_put_delay(monkeypatch):
     """The device-put-delay fault site slows every transfer; the window
     discipline holds regardless — puts keep issuing behind a full
