@@ -2,7 +2,7 @@
 
 Single-device exact attention without materializing the ``[T, T]`` score
 matrix: a 3-D grid ``(batch*heads, q_blocks, kv_blocks)`` streams one
-``[block_q, d]`` query tile and one ``[block_k, d]`` kv tile into VMEM per
+``[block_q, d]`` query block and one ``[block_k, d]`` kv block into VMEM per
 step — VMEM use is O(block) regardless of sequence length, so context is
 bounded by HBM, not VMEM. The online softmax (running max / normalizer)
 lives in VMEM scratch that persists across the kv-block axis (TPU grids
@@ -13,6 +13,30 @@ logsumexp rows the training forward saves — O(block) memory in both
 directions. Role parity: the attention compute the reference's training
 stacks get from fused CUDA kernels — rebuilt the TPU way.
 
+**Two tile sizes** (:func:`tile_plan`, the one place they are decided). The
+*DMA block* ``(block_q, block_k)`` is what one grid step holds in VMEM; it
+is large (``(512, 1024)`` for bf16) because a grid step costs about 0.35 µs
+whatever it does. The *compute sub-tile* ``(sub_q, sub_k)`` (:data:`_SUB_TILES`,
+128 or 256 on a side) is the grain at which work inside a block is left out: of a
+block pair the kernels compute, for every q sub-tile, the columns up to the
+causal diagonal and the last real column in one piece (:func:`_bands`; the
+dk/dv pass, for every kv sub-tile, the rows from the diagonal on:
+:func:`_col_bands`), so a sub-tile that holds no unmasked score is never
+multiplied, exponentiated or masked, and only the sub-tiles that the
+diagonal or the kv tail crosses pay for the mask's iota / compare /
+selects. The causal bound therefore engages from ``T > 128`` on, not from
+``T > block_k``: at ``T = 1024`` the grid has one kv block and skips
+nothing, the bands compute 36 of 64 sub-tiles (20 of 32 in the dk/dv pass). How a q block lies against a
+kv block (:func:`_block_case`: where the diagonal enters it, how many of its
+columns are real) takes a handful of values that are known when the kernel
+is traced, so each is straight-line code over static slices under a
+``pl.when``, not a loop: a rolled loop over 256 x 256 tiles ran the same
+shape at half the parent's speed on a v5e (PERF.md §6, PR 27), because
+nothing overlaps across its iterations. A block below the diagonal is
+still one piece, as it was before there were sub-tiles; whole DMA blocks
+above it are skipped at the grid level, and their ``index_map`` points at
+the last needed block so that nothing is fetched for them.
+
 Composes with :mod:`petastorm_tpu.models.attention`: ring attention shards
 the sequence across a mesh axis and rotates kv blocks over ICI; within a
 device, this kernel is the block compute. ``flash_attention`` always runs
@@ -22,15 +46,27 @@ validate the numerics). Asking for the compiled kernel on any other backend
 raises; nothing substitutes the dense reference silently.
 """
 
+import collections
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
 
+from petastorm_tpu.trace import get_global_tracer
+
 NEG_INF = -1e30  # large-finite: -inf breaks the running-max rescale at init
 
 _LANES = 128     # VPU lane width: in-kernel scratch vectors are lane-broadcast
+
+#: Compute sub-tile ``(rows, columns)`` of each pass, clamped to the DMA
+#: block. Chosen on a v5e at ``[192, 1024, 64]`` bf16 causal and confirmed at
+#: T = 8192 (PERF.md §6, PR 27): the forward and dq passes are fastest with
+#: 128-row bands cut to the nearest 128 columns; the dk/dv pass, whose bands
+#: run down the columns, pays for narrow ones (1.35 ms a layer at 128
+#: columns against 1.03 at 256). Multiples of 128, so that a band's masked
+#: part starts on a vreg boundary.
+_SUB_TILES = {'fwd': (128, 128), 'dq': (128, 128), 'dkv': (128, 256)}
 
 
 def _mosaic_params(interpret):
@@ -53,31 +89,6 @@ def _out_struct(shape, dtype, like):
     sequence-parallel path) ``pallas_call`` refuses an ``out_shape`` that
     does not say so; outside one the set is empty."""
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
-
-
-def _block_mask(qi, ki, block_q, block_k, seq_len, causal):
-    """[block_q, block_k] validity mask: kv tail padding + causal triangle."""
-    k_pos = ki * block_k + jax.lax.iota(jnp.int32, block_k)
-    mask = k_pos[None, :] < seq_len
-    if causal:
-        q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-        mask = mask & (q_pos[:, None] >= k_pos[None, :])
-    return mask
-
-
-def _recompute_p(q, k_blk, lse_vec, qi, ki, block_q, block_k, seq_len,
-                 causal, scale):
-    """Rebuild this tile's probabilities ``P = exp(S - lse)`` (backward).
-
-    Operands stay in their input dtype (bf16 matmuls run the MXU at twice
-    the f32 rate); the product accumulates in f32 and the scalar scale is
-    applied to the f32 product — scale*(QK) == (scale*Q)K up to rounding,
-    and post-scaling in f32 keeps more bits than pre-scaling bf16 Q.
-    """
-    s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    mask = _block_mask(qi, ki, block_q, block_k, seq_len, causal)
-    return jnp.where(mask, jnp.exp(s - lse_vec[:, None]), 0.0)
 
 
 def _to_bhtd(x, t_pad):
@@ -112,12 +123,277 @@ def _pad_plan(t, block_q, block_k):
 
 
 # --------------------------------------------------------------------------
+# the tile plan: which sub-tiles run, which of those are masked
+# --------------------------------------------------------------------------
+
+def _kv_range(q0, n_k, rows, sub_k, limit, causal):
+    """``(n_full, n_run)`` for rows ``q0 .. q0 + rows - 1`` against ``n_k``
+    kv sub-tiles of ``sub_k`` columns from column 0 on, of which ``limit``
+    are real: sub-tiles ``[0, n_full)`` hold no masked score, ``[n_full,
+    n_run)`` are crossed by the diagonal or the kv tail and take the mask,
+    the rest hold no unmasked score and are not run."""
+    # A sub-tile runs if its first column is real and on or below the
+    # diagonal of the last row; it is unmasked if its last column is real
+    # and on or below the diagonal of the first row.
+    first_unseen = min(limit, q0 + rows) if causal else limit
+    first_masked = min(limit, q0 + 1) if causal else limit
+    n_run = min(max(first_unseen + sub_k - 1, 0) // sub_k, n_k)
+    n_full = min(max(first_masked, 0) // sub_k, n_k)
+    return n_full, n_run
+
+
+def _block_case(qi, ki, block_q, block_k, seq_len, causal):
+    """How q block ``qi`` lies against kv block ``ki``: ``(off, rem)``, or
+    ``None`` where they share no unmasked score (the kv block is padding, or
+    wholly above the diagonal). ``rem`` is how many of the kv block's
+    columns are real; ``off`` is the q block's first row counted from the kv
+    block's first column, ``None`` where every row sees every column (no
+    diagonal, or the block lies wholly below it)."""
+    rem = min(seq_len - ki * block_k, block_k)
+    if rem <= 0:
+        return None
+    off = qi * block_q - ki * block_k
+    if not causal or off >= block_k - 1:
+        return (None, rem)
+    return (off, rem) if off + block_q > 0 else None
+
+
+def _bands(case, block_q, block_k, sub_q, sub_k):
+    """What is computed of a block pair, in the block's own coordinates: row
+    bands ``(r0, rows, c_full, c_run)``. Band rows ``r0 .. r0 + rows - 1``
+    take columns ``[0, c_run)`` in one piece, of which ``[c_full, c_run)``
+    (the sub-tiles that the diagonal or the kv tail crosses) get the mask;
+    columns from ``c_run`` on hold no unmasked score and are never touched.
+    One band a q sub-tile, or one for the whole block where the mask is the
+    same for every row; a band with nothing to compute is left out."""
+    off, rem = case
+    rows = block_q if off is None else sub_q
+    bands = []
+    for r0 in range(0, block_q, rows):
+        n_full, n_run = _kv_range(0 if off is None else off + r0,
+                                  block_k // sub_k, rows, sub_k, rem,
+                                  off is not None)
+        if n_run:
+            bands.append((r0, rows, n_full * sub_k, n_run * sub_k))
+    return bands
+
+
+def _col_bands(case, block_q, block_k, sub_q, sub_k):
+    """The same region as :func:`_bands`, cut the other way for the dk/dv
+    pass, whose accumulators follow the columns: column bands ``(c0, cols,
+    r_lo, r_full)``. Band columns ``c0 .. c0 + cols - 1`` take rows ``[r_lo,
+    block_q)`` in one piece, of which ``[r_lo, r_full)`` get the mask; rows
+    before ``r_lo`` lie wholly above the diagonal. One band a kv sub-tile,
+    neighbours that every row sees unmasked joined into one."""
+    off, rem = case
+    n_q = block_q // sub_q
+    bands = []
+    for c0 in range(0, rem, sub_k):
+        i_lo = i_full = 0
+        if off is not None:
+            # First q sub-tile whose last row sees column c0, and first
+            # whose first row sees the band's last column.
+            i_lo = min(max((c0 - off) // sub_q, 0), n_q)
+            i_full = min(max(-((off - c0 - sub_k + 1) // sub_q), i_lo), n_q)
+        if c0 + sub_k > rem:        # the tail crosses it: every row masked
+            i_full = n_q
+        if i_lo == n_q:
+            continue
+        if bands and i_full == 0 and bands[-1][2:] == (0, 0):
+            bands[-1] = (bands[-1][0], bands[-1][1] + sub_k, 0, 0)
+        else:
+            bands.append((c0, sub_k, i_lo * sub_q, i_full * sub_q))
+    return bands
+
+
+@functools.lru_cache(maxsize=None)
+def tile_plan(t, causal, dtype, hd, block_q, block_k):
+    """The tiling of one ``flash_attention`` call, from what the call can
+    see: sequence length, ``causal``, dtype, head width and the caller's
+    blocks. Nothing else decides it: each kernel computes its bands
+    (:func:`_bands`, :func:`_col_bands`) for the ``cases`` listed here and
+    nothing more.
+
+    Returns a JSON-safe dict: the DMA blocks (``block_q``, ``block_k``,
+    clamped as :func:`_pad_plan` says), ``t_pad``, the distinct ``cases`` of
+    :func:`_block_case` that the grid meets, and under ``passes`` for each of
+    ``fwd``, ``dq`` and ``dkv`` its compute sub-tile (``sub_q``, ``sub_k``:
+    :data:`_SUB_TILES` clamped to the DMA block), how many sub-tiles it
+    computes (``tiles_run``), how many of those take the mask
+    (``tiles_masked``) and how many the padded square holds
+    (``tiles_total``). ``share`` is the part of the three padded squares
+    that is computed: 1 where nothing can be left out (``causal=False`` with
+    no sub-tile of padding), 0.583 at T = 1024 causal (36 of 64 sub-tiles
+    forward and dq, 20 of 32 dk/dv), towards a half as T grows."""
+    block_q, block_k, t_pad = _pad_plan(t, block_q, block_k)
+    met = collections.Counter(
+        _block_case(qi, ki, block_q, block_k, t, causal)
+        for qi in range(t_pad // block_q) for ki in range(t_pad // block_k))
+    met.pop(None, None)
+    passes, computed = {}, 0
+    for name, (sub_q, sub_k) in _SUB_TILES.items():
+        sub_q, sub_k = min(block_q, sub_q), min(block_k, sub_k)
+        run = masked = 0            # in scores; a sub-tile holds sub_q * sub_k
+        for case, times in met.items():
+            if name == 'dkv':
+                for _, cols, r_lo, r_full in _col_bands(
+                        case, block_q, block_k, sub_q, sub_k):
+                    run += times * cols * (block_q - r_lo)
+                    masked += times * cols * (r_full - r_lo)
+            else:
+                for _, rows, c_full, c_run in _bands(
+                        case, block_q, block_k, sub_q, sub_k):
+                    run += times * rows * c_run
+                    masked += times * rows * (c_run - c_full)
+        computed += run
+        passes[name] = {'sub_q': sub_q, 'sub_k': sub_k,
+                        'tiles_run': run // (sub_q * sub_k),
+                        'tiles_masked': masked // (sub_q * sub_k),
+                        'tiles_total': t_pad * t_pad // (sub_q * sub_k)}
+    return {'t': t, 't_pad': t_pad, 'causal': bool(causal),
+            'dtype': jnp.dtype(dtype).name, 'hd': hd,
+            'block_q': block_q, 'block_k': block_k,
+            'cases': sorted(met, key=lambda c: (c[0] is None, c)),
+            'passes': passes, 'share': computed / (3 * t_pad * t_pad)}
+
+
+_plans_reported = set()
+
+
+def _plan_for(q, causal, block_q, block_k):
+    """The plan of a call on ``[B, T, H, D]`` operands; the first time a
+    process traces a kernel with it, one ``kernel.flash_plan`` instant on
+    the global tracer carries it (a model's layers share one plan, so one
+    record, not one a layer)."""
+    _, t, _, hd = q.shape
+    key = (t, bool(causal), jnp.dtype(q.dtype).name, hd, block_q, block_k)
+    plan = tile_plan(*key)
+    if key not in _plans_reported:
+        _plans_reported.add(key)
+        get_global_tracer().instant('kernel.flash_plan', cat='kernel',
+                                    args=plan)
+    return plan
+
+
+def _is_case(case, off, rem, block_k, causal):
+    """Whether a grid step whose q block starts ``off`` rows after its kv
+    block, ``rem`` of whose columns are real (capped at the block), is
+    ``case``; on Python ints or on traced grid indices."""
+    is_case = rem == case[1]
+    if causal:
+        is_case &= (off >= block_k - 1) if case[0] is None else (
+            off == case[0])
+    return is_case
+
+
+def _for_each_case(args, q_axis, kv_axis, body):
+    """Run ``body(case)`` under a ``pl.when`` for each case of the plan: the
+    grid indices say which one this step is, and a step that is none of
+    them (a kv block above the diagonal, or of padding) does nothing."""
+    import jax.experimental.pallas as pl
+
+    block_q, block_k = args['tiling'][:2]
+    qi, ki = pl.program_id(q_axis), pl.program_id(kv_axis)
+    off = qi * block_q - ki * block_k
+    rem = jnp.minimum(args['seq_len'] - ki * block_k, block_k)
+    for case in args['cases']:
+        pl.when(_is_case(case, off, rem, block_k, args['causal']))(
+            functools.partial(body, case))
+
+
+def _band_mask(case, r0, rows, c0, cols):
+    """[rows, cols] validity mask of the masked part of a band, whose first
+    row and column in the block's own coordinates are ``r0`` and ``c0``: kv
+    tail padding + causal triangle; None where that part is empty."""
+    if rows == 0 or cols == 0:
+        return None
+    off, rem = case
+    k_pos = c0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    mask = k_pos < rem
+    if off is not None:
+        q_pos = off + r0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+        mask = mask & (q_pos >= k_pos)
+    return mask
+
+
+def _masked(x, mask, fill):
+    """``x`` with ``fill`` where ``mask`` says so; ``mask`` covers the last
+    columns of ``x`` (a row band) or its first rows (a column band), and the
+    selects run over that part only."""
+    if mask is None:
+        return x
+    if mask.shape[0] == x.shape[0]:
+        c_full = x.shape[1] - mask.shape[1]
+        tail = jnp.where(mask, x[:, c_full:], fill)
+        return tail if c_full == 0 else jnp.concatenate(
+            [x[:, :c_full], tail], axis=1)
+    head = jnp.where(mask, x[:mask.shape[0]], fill)
+    return jnp.concatenate([head, x[mask.shape[0]:]], axis=0)
+
+
+def _scores(q, k_sub, scale):
+    """``scale * Q K^T`` of one band. Operands stay in their input dtype
+    (bf16 matmuls run the MXU at twice the f32 rate); the product
+    accumulates in f32 and the scalar scale is applied to the f32 product —
+    scale*(QK) == (scale*Q)K up to rounding, and post-scaling in f32 keeps
+    more bits than pre-scaling bf16 Q."""
+    return jax.lax.dot_general(q, k_sub, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32) * scale
+
+
+def _recompute_p(q, k_sub, lse, mask, scale):
+    """Rebuild a band's probabilities ``P = exp(S - lse)`` (backward)."""
+    return _masked(jnp.exp(_scores(q, k_sub, scale) - lse), mask, 0.0)
+
+
+def _index_maps(block_q, block_k, seq_len, causal):
+    """``(q_map, kv_map)`` over grid ``(b, qi, ki)``: the q side follows
+    ``qi``; the kv side follows ``ki`` as far as the last block this q block
+    needs and stays there, so the steps that compute nothing fetch nothing
+    new."""
+    last_real = (seq_len - 1) // block_k
+
+    def q_map(b, qi, ki):
+        return (b, qi, 0)
+
+    def kv_map(b, qi, ki):
+        last = last_real
+        if causal:
+            last = jnp.minimum(last, (qi * block_q + block_q - 1) // block_k)
+        return (b, jnp.minimum(ki, last), 0)
+
+    return q_map, kv_map
+
+
+def _index_maps_dkv(block_q, block_k, causal):
+    """``(q_map, kv_map)`` over the dk/dv grid ``(b, ki, qi)``: the q side
+    starts at the first q block this kv block needs."""
+    def q_map(b, ki, qi):
+        if causal:
+            qi = jnp.maximum(qi, (ki * block_k) // block_q)
+        return (b, qi, 0)
+
+    def kv_map(b, ki, qi):
+        return (b, ki, 0)
+
+    return q_map, kv_map
+
+
+def _kernel_args(plan, name, d):
+    """The static arguments of pass ``name``'s kernel."""
+    tiling = (plan['block_q'], plan['block_k'],
+              plan['passes'][name]['sub_q'], plan['passes'][name]['sub_k'])
+    return dict(tiling=tiling, seq_len=plan['t'], causal=plan['causal'],
+                cases=tuple(plan['cases']), scale=1.0 / math.sqrt(d))
+
+
+# --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
-                  seq_len, causal, scale, emit_lse):
-    """One grid step: one (block_q, d) query tile x one (block_k, d) kv tile.
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
+    """One grid step: one (block_q, d) query block x one (block_k, d) kv
+    block, computed in the bands of :func:`_bands`.
 
     acc/m/l scratch persists across the kv axis (axis 2, innermost): init at
     ki == 0, accumulate every step, normalize + store at the last ki. m/l
@@ -129,10 +405,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
         lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         lse_ref, (acc_ref, m_ref, l_ref) = None, rest
-
-    qi = pl.program_id(1)
+    scale = args['scale']
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -140,61 +414,54 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, block_q, block_k,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # Causal: kv blocks wholly above the diagonal contribute nothing — skip
-    # their matmuls entirely (the diagonal block still needs the mask).
-    needed = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    def step(case):
+        for r0, rows, c_full, c_run in _bands(case, *args['tiling']):
+            at, to = pl.ds(r0, rows), pl.ds(0, c_run)
+            mask = _band_mask(case, r0, rows, c_full, c_run - c_full)
+            # Native-dtype operands, f32 accumulation.
+            s = _masked(_scores(q_ref[at, :], k_ref[to, :], scale), mask,
+                        NEG_INF)
+            m_prev = m_ref[at, 0:1]
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            correction = jnp.exp(m_prev - m_new)
+            # A row with nothing unmasked so far has m_new == NEG_INF and
+            # p == 1 where it is masked: the second select zeroes it.
+            p = _masked(jnp.exp(s - m_new), mask, 0.0)
+            l_new = l_ref[at, 0:1] * correction + p.sum(axis=-1, keepdims=True)
+            v_sub = v_ref[to, :]
+            acc_ref[at, :] = acc_ref[at, :] * correction + jax.lax.dot_general(
+                p.astype(v_sub.dtype), v_sub, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[at, :] = jnp.broadcast_to(m_new, (rows, _LANES))
+            l_ref[at, :] = jnp.broadcast_to(l_new, (rows, _LANES))
 
-    @pl.when(needed)
-    def _step():
-        q = q_ref[...]
-        k_blk = k_ref[...]
-        v_blk = v_ref[...]
-        # Native-dtype operands, f32 accumulation: bf16 matmuls run the
-        # MXU at twice the f32 rate; scale applies to the f32 product.
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _block_mask(qi, ki, block_q, block_k, seq_len, causal)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        correction = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, 0.0)
-        l_new = l_ref[:, 0] * correction + p.sum(axis=-1)
-        acc_ref[...] = (acc_ref[...] * correction[:, None]
-                        + jax.lax.dot_general(
-                            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+    _for_each_case(args, 1, 2, step)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
-        l = l_ref[:, 0]
+        l = l_ref[:, 0:1]
         l = jnp.where(l == 0.0, 1.0, l)                   # fully masked rows
-        o_ref[...] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
         if emit_lse:
             # logsumexp rows: the backward kernels reconstruct P without
             # re-running the online softmax.
-            lse_ref[...] = m_ref[...] + jnp.log(l[:, None])
+            lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
-def _flash_bhtd(q, k, v, seq_len, causal, block_q, block_k, interpret,
-                emit_lse):
+def _flash_bhtd(q, k, v, plan, interpret, emit_lse):
     """Padded ``[BH, T_pad, D]`` -> ``out`` (+ ``lse [BH, T_pad, _LANES]`` when
     ``emit_lse`` — the training forward; inference skips the write)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_pad, d = q.shape
-    scale = 1.0 / math.sqrt(d)
-    grid = (bh, t_pad // block_q, t_pad // block_k)
-    kernel = functools.partial(_flash_kernel, block_q=block_q, block_k=block_k,
-                               seq_len=seq_len, causal=causal, scale=scale,
-                               emit_lse=emit_lse)
+    block_q, block_k = plan['block_q'], plan['block_k']
+    kernel = functools.partial(_flash_kernel, emit_lse=emit_lse,
+                               **_kernel_args(plan, 'fwd', d))
+    q_map, kv_map = _index_maps(block_q, block_k, plan['t'], plan['causal'])
     # o/lse blocks ignore ki: revisited across the kv axis, written at the
     # last ki only.
-    out_specs = [pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0))]
+    out_specs = [pl.BlockSpec((None, block_q, d), q_map)]
     out_shape = [_out_struct((bh, t_pad, d), q.dtype, q)]
     if emit_lse:
         # Lane-broadcast [BH, T_pad, _LANES] (all lanes carry the same
@@ -203,16 +470,15 @@ def _flash_bhtd(q, k, v, seq_len, causal, block_q, block_k, interpret,
         # Mosaic's (8,128)-or-full rule on real chips (found on first
         # hardware contact); the 128x HBM redundancy is the price of a
         # layout every Mosaic version tiles natively.
-        out_specs.append(pl.BlockSpec((None, block_q, _LANES),
-                                      lambda b, qi, ki: (b, qi, 0)))
+        out_specs.append(pl.BlockSpec((None, block_q, _LANES), q_map))
         out_shape.append(_out_struct((bh, t_pad, _LANES), jnp.float32, q))
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, t_pad // block_q, t_pad // block_k),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, qi, ki: (b, ki, 0)),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
@@ -232,132 +498,128 @@ def _flash_bhtd(q, k, v, seq_len, causal, block_q, block_k, interpret,
 # --------------------------------------------------------------------------
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
-                     acc_ref, *, block_q, block_k, seq_len, causal, scale):
-    """dQ pass: grid (bh, q_blocks, kv_blocks); dq accumulates across ki.
+                     acc_ref, **args):
+    """dQ pass: grid (bh, q_blocks, kv_blocks); dq accumulates across ki,
+    over the same bands as the forward.
 
     dS = P * (dO V^T - D);  dQ = scale * dS K, with D = rowsum(dO * O).
     """
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(1)
+    scale = args['scale']
     ki = pl.program_id(2)
-    nk = pl.num_programs(2)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    needed = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    def step(case):
+        for r0, rows, c_full, c_run in _bands(case, *args['tiling']):
+            at, to = pl.ds(r0, rows), pl.ds(0, c_run)
+            k_sub, do = k_ref[to, :], do_ref[at, :]
+            p = _recompute_p(q_ref[at, :], k_sub, lse_ref[at, 0:1],
+                             _band_mask(case, r0, rows, c_full, c_run - c_full),
+                             scale)
+            dp = jax.lax.dot_general(do, v_ref[to, :], (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - dd_ref[at, 0:1])
+            acc_ref[at, :] += scale * jax.lax.dot_general(
+                ds.astype(k_sub.dtype), k_sub, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(needed)
-    def _step():
-        q = q_ref[...]
-        k_blk = k_ref[...]
-        v_blk = v_ref[...]
-        do = do_ref[...]
-        p = _recompute_p(q, k_blk, lse_ref[:, 0], qi, ki, block_q, block_k,
-                         seq_len, causal, scale)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dd_ref[:, 0:1])
-        acc_ref[...] += scale * jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_each_case(args, 1, 2, step)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(ki == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
-                      dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, *,
-                      block_q, block_k, seq_len, causal, scale):
-    """dK/dV pass: grid (bh, kv_blocks, q_blocks); accumulates across qi.
+                      dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, **args):
+    """dK/dV pass: grid (bh, kv_blocks, q_blocks); accumulates across qi,
+    over the column bands of :func:`_col_bands`.
 
     dV = P^T dO;  dK = dS^T (scale * Q).
     """
     import jax.experimental.pallas as pl
 
-    ki = pl.program_id(1)
+    scale = args['scale']
     qi = pl.program_id(2)
-    nq = pl.num_programs(2)
 
     @pl.when(qi == 0)
     def _init():
         dk_acc_ref[...] = jnp.zeros_like(dk_acc_ref)
         dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
 
-    needed = (qi * block_q + block_q - 1 >= ki * block_k) if causal else True
+    def step(case):
+        block_q = args['tiling'][0]
+        for c0, cols, r_lo, r_full in _col_bands(case, *args['tiling']):
+            at, to = pl.ds(r_lo, block_q - r_lo), pl.ds(c0, cols)
+            q, do = q_ref[at, :], do_ref[at, :]
+            p = _recompute_p(q, k_ref[to, :], lse_ref[at, 0:1],
+                             _band_mask(case, r_lo, r_full - r_lo, c0, cols),
+                             scale)
+            dv_acc_ref[to, :] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do, v_ref[to, :], (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - dd_ref[at, 0:1])
+            # dK = dS^T (scale*Q): scale folds onto the f32 accumulator so Q
+            # stays a native-dtype operand.
+            dk_acc_ref[to, :] += scale * jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
-    @pl.when(needed)
-    def _step():
-        q = q_ref[...]
-        k_blk = k_ref[...]
-        v_blk = v_ref[...]
-        do = do_ref[...]
-        p = _recompute_p(q, k_blk, lse_ref[:, 0], qi, ki, block_q, block_k,
-                         seq_len, causal, scale)
-        dv_acc_ref[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - dd_ref[:, 0:1])
-        # dK = dS^T (scale*Q): scale folds onto the f32 accumulator so Q
-        # stays a native-dtype operand.
-        dk_acc_ref[...] += scale * jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _for_each_case(args, 2, 1, step)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(qi == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_bhtd(q, k, v, do, lse, dd, seq_len, causal, block_q, block_k,
-                    interpret):
+def _flash_bwd_bhtd(q, k, v, do, lse, dd, plan, interpret):
     """Backward over padded ``[BH, T_pad, D]`` tensors -> (dq, dk, dv)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t_pad, d = q.shape
-    scale = 1.0 / math.sqrt(d)
-
+    block_q, block_k = plan['block_q'], plan['block_k']
+    q_map, kv_map = _index_maps(block_q, block_k, plan['t'], plan['causal'])
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, causal=causal, scale=scale),
+        functools.partial(_flash_dq_kernel, **_kernel_args(plan, 'dq', d)),
         grid=(bh, t_pad // block_q, t_pad // block_k),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, block_q, _LANES), q_map),
+            pl.BlockSpec((None, block_q, _LANES), q_map),
         ],
-        out_specs=pl.BlockSpec((None, block_q, d), lambda b, qi, ki: (b, qi, 0)),
+        out_specs=pl.BlockSpec((None, block_q, d), q_map),
         out_shape=_out_struct((bh, t_pad, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
         **_mosaic_params(interpret),
     )(q, k, v, do, lse, dd)
 
+    q_map, kv_map = _index_maps_dkv(block_q, block_k, plan['causal'])
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, block_q=block_q, block_k=block_k,
-                          seq_len=seq_len, causal=causal, scale=scale),
+        functools.partial(_flash_dkv_kernel, **_kernel_args(plan, 'dkv', d)),
         grid=(bh, t_pad // block_k, t_pad // block_q),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((None, block_q, d), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES), lambda b, ki, qi: (b, qi, 0)),
-            pl.BlockSpec((None, block_q, _LANES), lambda b, ki, qi: (b, qi, 0)),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_q, d), q_map),
+            pl.BlockSpec((None, block_q, _LANES), q_map),
+            pl.BlockSpec((None, block_q, _LANES), q_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda b, ki, qi: (b, ki, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, ki, qi: (b, ki, 0)),
+            pl.BlockSpec((None, block_k, d), kv_map),
+            pl.BlockSpec((None, block_k, d), kv_map),
         ],
         out_shape=[
             _out_struct((bh, t_pad, d), k.dtype, k),
@@ -384,16 +646,26 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     Runs the Pallas blocked kernels compiled for the TPU; ``interpret=True``
     runs them in the Pallas interpreter instead (any backend — the CPU
     tests). The compiled kernel on a backend that is not a TPU raises.
-    ``block_q``/``block_k`` default per dtype on TPU —
-    ``(512, 1024)`` for bf16, ``(256, 512)`` for f32 (hardware sweep on a
-    v5e, T=8192 causal fwd+bwd: (512,1024) sustains ~40 TF/s vs ~11 at
-    (128,128); f32 doubles VMEM so its blocks halve to stay inside the
-    16MB scoped budget) — and ``(128, 128)`` under the interpreter. Blocks
-    are clamped to the sequence length and rounded down to powers of two
-    (keeping pad overhead bounded by one block — see ``_pad_plan``);
-    sequences are zero-padded up to a block multiple and the pad is
-    masked/stripped (padding tolerance is what lets ring attention hand
-    this kernel arbitrary per-device slice lengths).
+
+    ``block_q``/``block_k`` are the *DMA block*: what one grid step holds in
+    VMEM. They default per dtype on TPU — ``(512, 1024)`` for bf16, ``(256,
+    512)`` for f32, whose operands take twice the VMEM — and to ``(128,
+    128)`` under the interpreter; large, because the grid pays per step
+    ((128, 128) blocks ran at a quarter of (512, 1024)'s rate in the v5e
+    sweep at T=8192 that chose them). Blocks are clamped to the sequence
+    length and rounded down to powers of two (keeping pad overhead bounded
+    by one block — see ``_pad_plan``); sequences are zero-padded up to a
+    block multiple and the pad is masked/stripped (padding tolerance is what
+    lets ring attention hand this kernel arbitrary per-device slice
+    lengths). Inside a block the kernels leave work out by *compute
+    sub-tiles* of 128 x 128 (128 x 256 in the dk/dv pass): what lies above
+    the causal diagonal or beyond the last real column is not computed, and
+    only the sub-tiles those cross are masked. So ``causal=True`` skips
+    masked work from T > 128 on, whatever the DMA block (0.583 of the three
+    squares is computed at T = 1024, 0.54 at T = 2048; the limit is a half),
+    and ``causal=False`` computes everything that is not padding. :func:`tile_plan` is the
+    account, and a ``kernel.flash_plan`` instant on the global tracer
+    reports it once a plan (PERF.md has the times measured on a v5e).
 
     Differentiable end to end in O(block) memory: the training forward saves
     the logsumexp rows and the backward runs two more Pallas passes (a dq
@@ -433,10 +705,21 @@ def _flash_diff_fwd(q, k, v, causal, block_q, block_k, interpret):
     return out, (q, k, v, out, lse)
 
 
+# One trace a shape, not one a layer: a model's layers call the kernels with
+# the same shapes, and tracing three kernel bodies for each of twelve layers
+# cost a train step 25 s of set-up on a TPU host (PERF.md §6, PR 27).
+# ``inline`` leaves no trace of the jit in the caller's program: the kernels
+# keep the name of the scope they were called in (a device trace names them
+# by it).
+_once_a_shape = functools.partial(jax.jit, inline=True)
+
+
+@functools.partial(_once_a_shape, static_argnums=(0, 1, 2, 3))
 def _flash_diff_bwd(causal, block_q, block_k, interpret, residuals, g):
     q, k, v, out, lse = residuals
     b, t, h, d = q.shape
-    block_q, block_k, t_pad = _pad_plan(t, block_q, block_k)
+    plan = _plan_for(q, causal, block_q, block_k)
+    t_pad = plan['t_pad']
 
     # D = rowsum(dO * O): cheap elementwise+reduce, left to XLA.
     dd = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
@@ -449,7 +732,7 @@ def _flash_diff_bwd(causal, block_q, block_k, interpret, residuals, g):
 
     dq, dk, dv = _flash_bwd_bhtd(
         _to_bhtd(q, t_pad), _to_bhtd(k, t_pad), _to_bhtd(v, t_pad),
-        _to_bhtd(g, t_pad), lse, dd, t, causal, block_q, block_k, interpret)
+        _to_bhtd(g, t_pad), lse, dd, plan, interpret)
 
     def from_bhtd(x):
         return jnp.moveaxis(x[:, :t].reshape(b, h, t, d), 1, 2)
@@ -460,12 +743,13 @@ def _flash_diff_bwd(causal, block_q, block_k, interpret, residuals, g):
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
+@functools.partial(_once_a_shape, static_argnums=(3, 4, 5, 6, 7))
 def _flash_pallas(q, k, v, causal, block_q, block_k, interpret, emit_lse):
     """Returns ``(out [B,T,H,D], lse [BH, T_pad, _LANES] | None)``."""
     b, t, h, d = q.shape
-    block_q, block_k, t_pad = _pad_plan(t, block_q, block_k)
+    plan = _plan_for(q, causal, block_q, block_k)
+    t_pad = plan['t_pad']
     out, lse = _flash_bhtd(_to_bhtd(q, t_pad), _to_bhtd(k, t_pad),
-                           _to_bhtd(v, t_pad), t, causal, block_q, block_k,
-                           interpret, emit_lse)
+                           _to_bhtd(v, t_pad), plan, interpret, emit_lse)
     out = out[:, :t]
     return jnp.moveaxis(out.reshape(b, h, t, d), 1, 2), lse

@@ -6,7 +6,8 @@ import numpy as np
 import pytest
 
 from petastorm_tpu.models.attention import dense_attention
-from petastorm_tpu.ops.flash_attention import flash_attention
+from petastorm_tpu.ops.flash_attention import (_bands, _flash_bhtd,
+                                               flash_attention, tile_plan)
 
 
 # Heavyweight (jit compiles of full models / interpret-mode Pallas):
@@ -19,6 +20,12 @@ pytestmark = pytest.mark.slow
     ((1, 100, 2, 8), (32, 16)),      # padded tail (100 % 16 != 0)
     ((1, 7, 1, 4), (8, 8)),          # seq shorter than a block
     ((2, 48, 3, 8), (16, 24)),       # block_q != block_k
+    # DMA blocks of several 128-wide compute sub-tiles, T no multiple of one:
+    ((1, 600, 2, 16), (512, 1024)),  # one 512 block a side; sub-tiles of pad
+    ((1, 1100, 1, 8), (512, 1024)),  # two kv blocks: grid skip + band bounds
+    ((1, 700, 1, 8), (256, 1024)),   # a 256-row q block against a 512 kv one
+    ((1, 520, 1, 8), (512, 64)),     # sub_q 128 > sub_k 64: rows of a q
+                                     # sub-tile fully masked in a computed one
 ])
 def test_matches_dense(shape, blocks, causal):
     rng = np.random.default_rng(0)
@@ -79,6 +86,9 @@ def test_compiled_kernel_off_tpu_raises():
     ((2, 64, 2, 16), (16, 16)),
     ((1, 100, 2, 8), (32, 16)),      # padded tail exercises zero-dO rows
     ((2, 48, 3, 8), (16, 24)),       # uneven blocks
+    ((1, 600, 2, 16), (512, 1024)),  # several sub-tiles a DMA block, ragged T
+    ((1, 1100, 1, 8), (512, 1024)),  # two kv blocks of four sub-tiles
+    ((1, 520, 1, 8), (512, 64)),     # sub_q 128 > sub_k 64
 ])
 def test_pallas_backward_matches_dense(shape, blocks, causal):
     """The dq/dk/dv Pallas kernels reproduce dense-attention gradients."""
@@ -101,3 +111,34 @@ def test_pallas_backward_matches_dense(shape, blocks, causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=2e-4,
                                    err_msg='d{} mismatch'.format(name))
+
+
+def test_rows_fully_masked_in_a_computed_tile_keep_their_statistics():
+    """T=520 at (512, 64) blocks: sub_q 128 > sub_k 64, so the q block's
+    first rows lie wholly above kv block 1 and are still in the masked tile
+    computed there (all their scores ``NEG_INF``: the running max must not
+    move and the probabilities must come out 0, not ``exp(0)``). The saved
+    logsumexp rows say whether they did; the padding rows stay finite."""
+    rng = np.random.default_rng(5)
+    t, d = 520, 16
+    plan = tile_plan(t, True, 'float32', d, 512, 64)
+    fwd = plan['passes']['fwd']
+    assert (plan['t_pad'], fwd['sub_q'], fwd['sub_k']) == (1024, 128, 64)
+    # q block 0 against kv block 1: rows 0..127 in one masked 128 x 64 tile.
+    assert (0, 128, 0, 64) in _bands((-64, 64), 512, 64, 128, 64)
+    q, k, v = (jnp.pad(jnp.asarray(rng.standard_normal((2, t, d)),
+                                   jnp.float32), ((0, 0), (0, 504), (0, 0)))
+               for _ in range(3))
+    out, lse = _flash_bhtd(q, k, v, plan, True, True)
+    assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(lse).all())
+    scores = jnp.einsum('bqd,bkd->bqk', q[:, :t], k[:, :t]) / np.sqrt(d)
+    scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
+    np.testing.assert_allclose(np.asarray(lse[:, :t, 0]),
+                               np.asarray(jax.nn.logsumexp(scores, axis=-1)),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(lse[:, :, 0]),
+                                  np.asarray(lse[:, :, -1]))  # lane-broadcast
+    want = jnp.einsum('bqk,bkd->bqd', jax.nn.softmax(scores, axis=-1),
+                      v[:, :t])
+    np.testing.assert_allclose(np.asarray(out[:, :t]), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
