@@ -285,7 +285,7 @@ def run(argv, process_start):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-    from perfbench import check, faults, trace_reduce
+    from perfbench import check, faults, span_reduce, trace_reduce
 
     work = os.path.join(files.base, WORK_DIR)
     phases = {}
@@ -450,6 +450,7 @@ def run(argv, process_start):
         options.host_tracer_level = 0
         del spans[:]
         jax.profiler.start_trace(trace_dir, profiler_options=options)
+        t_trace_ns = time.perf_counter_ns()
         t_trace = time.perf_counter()
         traced_steps = 0
         while time.perf_counter() - t_trace < TRACE_SECONDS or traced_steps < 5:
@@ -462,11 +463,27 @@ def run(argv, process_start):
         say('trace: {} steps in {:.2f} s, then {:.2f} s to stop and write it'
             .format(traced_steps, t_stop - t_trace,
                     time.perf_counter() - t_stop))
+        # The program's own spans of the traced window go onto the trace's
+        # clock with the loop's, so that a gap names the pipeline stage too.
+        # Where the program keeps no ring, or the ring has dropped part of
+        # the window, there are none and the gaps keep the loop's names alone.
+        stages = None
+        ring = span_reduce.ring_records()
+        ring = ring and span_reduce.clip(ring, t_trace_ns, last_step_done_ns)
+        if ring and ring['covered']:
+            stages = [(s[span_reduce.TID], s[span_reduce.NAME],
+                       s[span_reduce.START], s[span_reduce.DUR])
+                      for s in ring['spans']]
+            say('trace: {} spans of the program on {} threads'.format(
+                len(stages), len({s[0] for s in stages})))
         try:
             xplane = trace_reduce.find_xplane(trace_dir)
             trace = trace_reduce.load_xplane(xplane)
             trace['host'] = trace_reduce.spans_on_the_trace_clock(
                 spans, last_step_done_ns, trace['devices'])
+            if stages is not None:
+                trace['stages'] = trace_reduce.stages_on_the_trace_clock(
+                    stages, last_step_done_ns, trace['devices'])
             for plane, events in trace['devices'].items():
                 say('trace: {} holds {} operations from {:.3f} s to {:.3f} s'
                     .format(plane, len(events),
@@ -559,8 +576,11 @@ def run(argv, process_start):
     if reduced is not None:
         device['busy_s'] = reduced['busy_s']
         device['window_s'] = reduced['window_s']
+        # The loop's entries (four at the most), then the program's stages
+        # under the program's own names: ten in all, as the driver keeps.
+        gaps = reduced['idle_gaps'] + (reduced['stage_gaps'] or [])
         result['breakdown'] = {'device_ops': reduced['device_ops'],
-                               'idle_gaps': reduced['idle_gaps']}
+                               'idle_gaps': gaps[:10]}
     result['steps'] = steps
     result['check_s'] = check_s
     if args.rehearse:

@@ -50,6 +50,8 @@ def tiny_benchmark(folder, extra_paths=(), extra_cells=(), extra_metrics=()):
             m['workloads'] = tokens
         elif m['name'] == 'dispatch.h2d_overlap_frac':
             m['workloads'] = images
+        elif m['name'] == 'step.collective_ms_per_step':
+            m['workloads'] = [c['name'] for c in cells if c['chips'] == 4]
         elif 'workloads' in m:
             m['workloads'] = streamed
         per_layer.append(m)

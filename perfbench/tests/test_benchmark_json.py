@@ -146,6 +146,10 @@ def test_every_cell_s_files_exist_and_state_their_knobs(bench):
             lower, upper = cfg['limits_from'][name]['lower'], \
                 cfg['limits_from'][name]['upper']
             assert lower < limit < upper, name
+            # the same on four chips, where a cell has been read there
+            four = cfg['limits_from'][name]
+            assert four.get('lower_four_chips', lower) < limit < four.get(
+                'upper_four_chips', upper), name
         assert traffic['reader']['autotune'] is False
         assert traffic['loader']['autotune'] is False
         for knob in ('prefetch', 'inflight', 'arena_depth', 'device_inflight',
@@ -160,6 +164,48 @@ def test_every_cell_s_files_exist_and_state_their_knobs(bench):
             'name', 'store_rows', 'store_writers', 'decode_threads', 'reader',
             'loader', 'fill_cache_rows', 'warm_steps', 'sample_rows'}
         assert hasattr(ref, 'loss_and_grad') and hasattr(program, 'build')
+
+
+def test_the_four_chip_streamed_cell(bench):
+    cells = {w['name']: w for w in bench['workloads']}
+    cell = cells['resnet50.decode.x4']
+    assert (cell['config'], cell['traffic'], cell['chips']) == (
+        'resnet50-imagenet224', 'decode-every-epoch', 4)
+    # the one four-chip cell: a quarter of the cells rounded down, and one
+    # always may
+    four = [w['name'] for w in bench['workloads'] if w['chips'] == 4]
+    assert four == ['resnet50.decode.x4']
+    # what it measures exists only across chips, and read and decode carry load
+    by_name = {m['name']: m for m in bench['per_layer']}
+    for name in ('decode.decode_s_per_krow', 'reader.read_s_per_krow',
+                 'dispatch.h2d_overlap_frac', 'step.collective_ms_per_step'):
+        assert 'resnet50.decode.x4' in by_name[name]['workloads'], name
+    collective = by_name['step.collective_ms_per_step']
+    assert collective['workloads'] == four and collective['layer'] == 'step'
+    assert collective['source'] == 'device_trace'
+    # the accepted cells keep the lists they had
+    assert by_name['decode.decode_s_per_krow']['workloads'][0] == 'gpt2s.tokens'
+    assert by_name['dispatch.h2d_overlap_frac']['workloads'][0] == 'resnet50.ramcache'
+    traffic = json.load(open(os.path.join(PERFBENCH, 'traffic',
+                                          'decode-every-epoch.json')))
+    cached = json.load(open(os.path.join(PERFBENCH, 'traffic',
+                                         'ram-cached-epochs.json')))
+    # the RAM-cached mix with the cache taken away, nothing to fill, the
+    # four-chip host's decode threads and a longer warm-up: no other knob moved
+    assert traffic['reader'] == dict(cached['reader'], cache_type='null')
+    assert traffic['loader'] == cached['loader']
+    assert traffic['store_rows'] == cached['store_rows'] == 32768
+    assert (traffic['fill_cache_rows'], traffic['warm_steps'],
+            traffic['decode_threads'], traffic['sample_rows']) == (0, 24, 30, 512)
+    knobs = {k for k in traffic if not k.endswith(('_why', 'what', 'name'))}
+    assert all(k + '_why' in traffic for k in knobs - {'store_writers'}), knobs
+    # the rehearsal's tiny file has the real file's keys and shape
+    tiny = json.load(open(os.path.join(PERFBENCH, 'tests', 'tiny', 'traffic',
+                                       'tiny-decode.json')))
+    assert set(tiny) == {k for k in traffic if not k.endswith('_why')}
+    assert set(tiny['reader']) == set(traffic['reader'])
+    assert set(tiny['loader']) == set(traffic['loader'])
+    assert tiny['reader']['cache_type'] == 'null' and tiny['fill_cache_rows'] == 0
 
 
 def test_peaks_carry_their_source():

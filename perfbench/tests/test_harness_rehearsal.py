@@ -52,8 +52,25 @@ def test_four_virtual_devices_traced(tiny):
     assert {'collate.reader_wait_share', 'collate.assemble_ms_per_batch',
             'dispatch.ms_per_batch', 'consumer.input_stall_frac'} <= names
     assert result['metrics']['cache.hit_share.cpu_rehearsal']['value'] == 0.0
-    # device-trace metrics find nothing to read on a CPU and are left out
+    # the streamed cell's own lists: read and decode carry load, the batch is
+    # staged through the per-device streams, and the ring held the window
+    assert {'reader.read_s_per_krow', 'decode.decode_s_per_krow',
+            'dispatch.h2d_overlap_frac', 'collate.self_ms_per_batch',
+            'reader.idle_poll_cpu_share', 'consumer.queue_lead_batches'} <= names
+    assert result['metrics']['decode.decode_s_per_krow.cpu_rehearsal']['value'] > 0
+    traffic = window['settings']['traffic']
+    assert traffic['reader']['cache_type'] == 'null'
+    assert traffic['fill_cache_rows'] == 0
+    # device-trace metrics find nothing to read on a CPU and are left out,
+    # the collectives' time among them; with no device plane no trace is
+    # reduced, so there is no breakdown, but the program's spans of the
+    # traced window were taken from the ring and handed over
     assert 'device.idle_share' not in names and 'step.mfu' not in names
+    assert 'step.collective_ms_per_step' not in names
+    assert 'breakdown' not in result
+    handed = [line for line in err.splitlines()
+              if 'spans of the program on' in line]
+    assert len(handed) == 1 and int(handed[0].split()[2]) > 0, err[-3000:]
 
 
 def test_token_cell(tiny):

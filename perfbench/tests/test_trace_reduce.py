@@ -6,9 +6,9 @@ import json
 import os
 
 import pytest
-from conftest import HERE
+from conftest import HERE, PERFBENCH
 
-from perfbench import trace_reduce
+from perfbench import harness, trace_reduce
 
 MS = 1000000
 
@@ -40,6 +40,79 @@ def test_busy_idle_ops_and_gaps_by_hand():
                                   'outside_loop_spans': 0.002})
     assert trace_reduce.kernel_seconds(r, r'^attn') == pytest.approx(0.016)
     assert trace_reduce.kernel_seconds(r, r'^nothing') is None
+
+
+def _stages():
+    # The gap (20..30 ms) by the program's own spans. Thread 1, the
+    # consumer's: consumer.wait 22..28. Thread 2 collates: collate.batch
+    # 18..29 holding collate.reader_wait 21..27. Threads 3 and 4 are pool
+    # workers: decode.decode 19..24 inside reader.cache_get 18..25 on one,
+    # decode.decode 23..26 on the other, then both in reader.take.
+    return {1: [('consumer.wait', 22 * MS, 6 * MS)],
+            2: [('collate.batch', 18 * MS, 11 * MS),
+                ('collate.reader_wait', 21 * MS, 6 * MS)],
+            3: [('reader.cache_get', 18 * MS, 7 * MS),
+                ('decode.decode', 19 * MS, 5 * MS),
+                ('reader.take', 25 * MS, 20 * MS)],
+            4: [('decode.decode', 23 * MS, 3 * MS),
+                ('reader.take', 26 * MS, 20 * MS)]}
+
+
+def test_a_gap_is_charged_to_each_thread_s_span_and_the_loop_s_stay():
+    plain = trace_reduce.reduce_trace(_trace())
+    assert plain['stage_gaps'] is None
+    r = trace_reduce.reduce_trace(dict(_trace(), stages=_stages()))
+    # the loop's three entries and the rest come out as before
+    for key in ('idle_gaps', 'busy_s', 'window_s', 'steps', 'device_ops'):
+        assert r[key] == plain[key], key
+    # every thread's span under the gap is charged, the innermost one of a
+    # nest, and two workers in one stage are that stage once
+    assert dict(r['stage_gaps']) == pytest.approx({
+        'consumer.wait': 0.006,             # 22..28
+        'collate.reader_wait': 0.006,       # 21..27
+        'collate.batch': 0.003,             # 20..21 and 27..29: its self time
+        'decode.decode': 0.006,             # 20..24 and 23..26: 20..26
+        'reader.cache_get': 0.001,          # 24..25
+        'reader.take': 0.005})              # 25..30 and 26..30: 25..30
+    assert [name for name, _ in r['stage_gaps']][-1] == 'reader.cache_get'
+    assert trace_reduce.reduce_trace(dict(_trace(), stages={}))['stage_gaps'] == []
+
+
+def test_the_program_s_spans_take_the_loop_s_shift():
+    ahead = 10 ** 12
+    t = _trace()
+    spans = [(tid, name, start + ahead, dur)
+             for tid, events in _stages().items() for name, start, dur in events]
+    assert trace_reduce.stages_on_the_trace_clock(
+        spans, 50 * MS + ahead, t['devices']) == _stages()
+
+
+def test_nested_spans_are_cut_to_the_innermost():
+    cut = trace_reduce._innermost([('a', 0, 10), ('b', 2, 3), ('c', 3, 1),
+                                   ('b', 6, 2), ('d', 12, 1)])
+    assert cut == [(0, 2, 'a'), (2, 3, 'b'), (3, 4, 'c'), (4, 5, 'b'),
+                   (5, 6, 'a'), (6, 8, 'b'), (8, 10, 'a'), (12, 13, 'd')]
+    # a child that a clock read lets end after its parent loses nothing
+    assert trace_reduce._innermost([('a', 0, 5), ('b', 3, 4)]) == [
+        (0, 3, 'a'), (3, 7, 'b')]
+
+
+def test_collective_time_counts_a_start_and_done_pair_once():
+    mod = harness.load_module(os.path.join(
+        PERFBENCH, 'metrics', 'step.collective_ms_per_step.py'))
+    per_op = {'all-reduce-start.3_f32_64_': 0.002, 'all-reduce-done.3_f32_64_': 0.004,
+              'all-reduce.7_f32_1000_': 0.001, 'all-gather.1_bf16_8_': 0.0005,
+              'collective-permute-start_f32_2_': 0.0005,
+              'fusion.14_f32_256_': 0.5, 'all-reduce-scatter-like-fusion': 0.3}
+    # 8 ms over four steps: a pair's two operations are its time once, and a
+    # name that merely holds the word is not a collective
+    assert mod.read({'trace': {'per_op_s': per_op, 'steps': 4},
+                     'trace_reduce': trace_reduce}) == pytest.approx(2.0)
+    # one chip: no collective in the trace, nothing to read (never 0)
+    assert mod.read({'trace': {'per_op_s': {'fusion.14_f32_256_': 0.5},
+                               'steps': 4},
+                     'trace_reduce': trace_reduce}) is None
+    assert mod.read({'trace': None}) is None
 
 
 def test_two_chips_are_averaged_and_overlap_is_not_counted_twice():
@@ -93,6 +166,12 @@ def test_recorded_chip_trace():
     # idled, and the gaps are charged to next_batch before anything else
     assert 0 < r['busy_s'] < r['window_s']
     assert r['idle_gaps'][0][0] == 'next_batch'
+    # with the program's spans beside them the loop's entries do not move
+    staged = trace_reduce.reduce_trace(dict(trace, stages={
+        7: [('consumer.wait', s, d) for _, s, d in trace['host']['next_batch']]}))
+    assert staged['idle_gaps'] == r['idle_gaps']
+    assert dict(staged['stage_gaps'])['consumer.wait'] == pytest.approx(
+        dict(r['idle_gaps'])['next_batch'])
 
 
 def test_the_xplane_itself_reads_to_the_same_events():

@@ -1,8 +1,11 @@
 """From a profiler trace to numbers: device busy and idle time, time per
 operation, a kernel's time, and each idle gap charged to the host span that
-covered it. Works on plain event lists, so it is checked on a recorded trace
-(``tests/data``) without a chip; :func:`load_xplane` turns the profiler's
-``.xplane.pb`` into those lists with nothing but jax.
+covered it: once to the training loop's three spans, and once more, thread by
+thread, to the program's own spans (``trace['stages']``), so that a gap names
+the pipeline stage the host was in. Works on plain event lists, so it is
+checked on a recorded trace (``tests/data``) without a chip;
+:func:`load_xplane` turns the profiler's ``.xplane.pb`` into those lists with
+nothing but jax.
 
 An event is ``(name, start_ns, duration_ns)``.
 """
@@ -54,19 +57,36 @@ def load_xplane(path):
     return {'devices': devices, 'host': host}
 
 
-def spans_on_the_trace_clock(spans, last_step_done_ns, devices):
-    """The loop's spans, taken by the host's clock as ``(name, start_ns,
-    duration_ns)``, moved onto the trace's clock: the host saw the last step
-    done at ``last_step_done_ns``, which is when the last device operation
-    ended, to the tenth of a millisecond the call takes to return."""
+def _shift(last_step_done_ns, devices):
+    """What the host's clock lacks to the trace's: the host saw the last
+    step done at ``last_step_done_ns``, which is when the last device
+    operation ended, to the tenth of a millisecond the call takes to return."""
     ends = [s + d for events in devices.values() for _, s, d in events]
     if not ends:
         raise RuntimeError('no device operation in the trace')
-    shift = max(ends) - last_step_done_ns
+    return max(ends) - last_step_done_ns
+
+
+def spans_on_the_trace_clock(spans, last_step_done_ns, devices):
+    """The loop's spans, taken by the host's clock as ``(name, start_ns,
+    duration_ns)``, moved onto the trace's clock."""
+    shift = _shift(last_step_done_ns, devices)
     host = {name: [] for name in HOST_SPANS}
     for name, start, duration in spans:
         host[name].append((name, start + shift, duration))
     return host
+
+
+def stages_on_the_trace_clock(spans, last_step_done_ns, devices):
+    """The program's own spans, ``(thread, name, start_ns, duration_ns)`` on
+    the same host clock (``petastorm_tpu.trace`` records on
+    ``time.perf_counter_ns()``, as the loop's spans are taken), moved by the
+    same shift: ``{thread: [event, ...]}``."""
+    shift = _shift(last_step_done_ns, devices)
+    threads = {}
+    for thread, name, start, duration in spans:
+        threads.setdefault(thread, []).append((name, start + shift, duration))
+    return threads
 
 
 def find_xplane(trace_dir):
@@ -98,6 +118,53 @@ def _clip(events, window):
     return out
 
 
+def _innermost(events):
+    """One thread's nested spans as disjoint ``(start, end, name)`` pieces,
+    each moment under the innermost span open at it: a ``collate.batch``
+    that waits for the reader is ``collate.reader_wait`` while it waits."""
+    out, stack, cursor = [], [], 0          # stack of (name, end)
+    for name, start, dur in sorted(events, key=lambda e: (e[1], -e[2])):
+        while stack and stack[-1][1] <= start:
+            top, end = stack.pop()
+            if end > cursor:
+                out.append((cursor, end, top))
+                cursor = end
+        if stack and start > cursor:
+            out.append((cursor, start, stack[-1][0]))
+        cursor = max(cursor, start) if stack else start
+        stack.append((name, start + dur))
+    while stack:
+        top, end = stack.pop()
+        if end > cursor:
+            out.append((cursor, end, top))
+            cursor = end
+    return out
+
+
+def stage_intervals(threads, window):
+    """``{span name: [[start, end], ...]}``: for each of the program's span
+    names the moments of the window at which some thread was in it (and in
+    no span nested inside it), sorted and disjoint. Ten pool workers that
+    all sit in ``reader.take`` are one stage waiting, not ten."""
+    by_name = {}
+    for events in threads.values():
+        for start, end, name in _innermost(_clip(events, window)):
+            by_name.setdefault(name, []).append((start, end))
+    return {name: _merge(ivs) for name, ivs in by_name.items()}
+
+
+def _overlap(a, b):
+    """Nanoseconds that two sorted lists of disjoint intervals share."""
+    total, i, j = 0, 0, 0
+    while i < len(a) and j < len(b):
+        total += max(0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] <= b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
 def whole_steps_window(host):
     """The traced window: from the end of the first ``await_step`` to the end
     of the last, so that the bubble the profiler's own start leaves and the
@@ -111,7 +178,11 @@ def whole_steps_window(host):
 def reduce_trace(trace, window=None, top=10):
     """Busy seconds (union of operation intervals, averaged over the chips),
     the window's seconds, seconds per operation name and idle gaps by host
-    span. Raises where no operation ran on a device in the window."""
+    span: by the loop's (``idle_gaps``) and, where the trace carries the
+    program's spans by thread (``trace['stages']``), by those
+    (``stage_gaps``; a gap is charged to every stage some thread was in, so
+    these do not add up to the idle time; ``None`` without them). Raises
+    where no operation ran on a device in the window."""
     if window is None:
         window = whole_steps_window(trace['host'])
     if window is None:
@@ -126,6 +197,9 @@ def reduce_trace(trace, window=None, top=10):
     spans = sorted((s, s + d, name) for name in HOST_SPANS
                    for _, s, d in _clip(trace['host'].get(name, ()), window))
     span_ends = [e for _, e, _ in spans]
+    stages = None if trace.get('stages') is None else stage_intervals(
+        trace['stages'], window)
+    stage_gaps = {}
     for events in trace['devices'].values():
         events = _clip(events, window)
         merged = _merge((s, s + d) for _, s, d in events)
@@ -133,9 +207,11 @@ def reduce_trace(trace, window=None, top=10):
         for name, _, dur in events:
             per_op[name] = per_op.get(name, 0) + dur
         edges = [window[0]] + [t for iv in merged for t in iv] + [window[1]]
-        for gap_start, gap_end in zip(edges[0::2], edges[1::2]):
-            if gap_end <= gap_start:
-                continue
+        idle = [gap for gap in zip(edges[0::2], edges[1::2]) if gap[1] > gap[0]]
+        for name, intervals in (stages or {}).items():
+            stage_gaps[name] = stage_gaps.get(name, 0) + _overlap(
+                idle, intervals)
+        for gap_start, gap_end in idle:
             left = gap_end - gap_start
             k = bisect.bisect_right(span_ends, gap_start)
             while k < len(spans) and spans[k][0] < gap_end:
@@ -163,7 +239,9 @@ def reduce_trace(trace, window=None, top=10):
             'steps': steps,
             'per_op_s': {k: v / n / 1e9 for k, v in per_op.items()},
             'device_ops': ranked(per_op),
-            'idle_gaps': ranked(gaps)}
+            'idle_gaps': ranked(gaps),
+            'stage_gaps': None if stages is None else ranked(
+                {k: v for k, v in stage_gaps.items() if v > 0})}
 
 
 def kernel_seconds(reduced, pattern):
