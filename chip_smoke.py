@@ -55,6 +55,11 @@ FULL = {
            'seq': 1024, 'per_chip': 8, 'steps': 3, 'store_batches': 4},
     # (name, dtype, B, T, H, D): the LM's shape; a long sequence at the
     # default (512, 1024) bf16 blocks; f32 at a T no block divides.
+    # the hybrid LM at the published head widths (96-wide keys, 192-wide
+    # values, 128-wide attention heads), four of eight heads held
+    'hybrid': {'vocab': 4096, 'd_model': 1024, 'd_ff': 2048, 'heads': 4,
+               'published': 8, 'key': 96, 'value': 192, 'seq': 1024,
+               'chunk': 64},
     'flash': [('bf16-lm-shape', 'bfloat16', 8, 1024, 8, 64),
               ('bf16-T8192', 'bfloat16', 1, 8192, 8, 64),
               ('f32-ragged-T1000', 'float32', 2, 1000, 4, 64)],
@@ -65,6 +70,8 @@ TINY = {
     'store_batches': 6, 'proof_epochs': 4, 'train_steps': 2, 'scan_k': 2,
     'lm': {'vocab': 256, 'd_model': 32, 'layers': 1, 'heads': 2,
            'seq': 64, 'per_chip': 2, 'steps': 2, 'store_batches': 4},
+    'hybrid': {'vocab': 128, 'd_model': 32, 'd_ff': 64, 'heads': 2,
+               'published': 4, 'key': 8, 'value': 16, 'seq': 48, 'chunk': 16},
     'flash': [('bf16-small', 'bfloat16', 2, 64, 2, 16),
               ('bf16-multiblock', 'bfloat16', 1, 256, 2, 16),
               ('f32-ragged-T50', 'float32', 2, 50, 2, 16)],
@@ -526,6 +533,69 @@ def phase_lm(run):
         stats['stage_tiers'], stats['input_stall_frac']))
 
 
+def phase_hybrid(run):
+    """One train step of ``HybridLM`` (a gated delta-rule layer and a
+    full-attention layer, both recomputed in the backward pass) through the
+    compiled Pallas kernels, against the same step through plain XLA (the
+    rule chunked in ``jax.numpy``, dense attention): a Mosaic lowering
+    failure of ``gdn`` on a new runtime shows here."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from petastorm_tpu.models import HybridLM
+    from petastorm_tpu.models.train import TrainState, make_train_step
+
+    h = run.cfg['hybrid']
+    batch = run.n
+
+    def model(linear_attention, attention):
+        return HybridLM(
+            vocab_size=h['vocab'], d_model=h['d_model'], d_ff=h['d_ff'],
+            layer_types=('linear_attention', 'full_attention'),
+            heads_held=h['heads'], heads_published=h['published'],
+            key_dim=h['key'], value_dim=h['value'], chunk=h['chunk'],
+            attention=attention, linear_attention=linear_attention,
+            remat=True, mesh=run.mesh)
+
+    tokens = jax.device_put(
+        np.random.default_rng(0).integers(
+            0, h['vocab'], (batch, h['seq'] + 1), dtype=np.int32),
+        NamedSharding(run.mesh, PartitionSpec('data')))
+    x, y = tokens[:, :-1], tokens[:, 1:]
+    suffix = ':interpret' if run.interpret else ''
+    kernels, plain = model('pallas' + suffix, 'flash' + suffix), \
+        model('chunked', 'dense')
+    params = jax.jit(plain.init)(jax.random.PRNGKey(0), x)['params']
+    run.say('HybridLM: {:.1f}M parameters, d={} heads {} of {} ({}/{}-wide '
+            'linear, {}-wide full) T={} chunk={}, global batch {}'.format(
+                sum(p.size for p in jax.tree_util.tree_leaves(params)) / 1e6,
+                h['d_model'], h['heads'], h['published'], h['key'],
+                h['value'], h['d_model'] // h['published'], h['seq'],
+                h['chunk'], batch))
+    losses, moved = {}, {}
+    for name, m in (('kernels', kernels), ('plain', plain)):
+        state = TrainState.create(apply_fn=m.apply, params=params,
+                                  tx=optax.sgd(0.1))
+        state = jax.device_put(state, NamedSharding(run.mesh, PartitionSpec()))
+        step = make_train_step(mesh=run.mesh)
+        if name == 'kernels':
+            step = run.compile('HybridLM train_step', step, state, x, y)
+        new, metrics = step(jax.tree_util.tree_map(jnp.copy, state), x, y)
+        losses[name] = float(metrics['loss'])
+        moved[name] = new.params['head']['kernel']
+    gap = abs(losses['kernels'] - losses['plain']) / abs(losses['plain'])
+    update = _rel_err(moved['kernels'] - params['head']['kernel'],
+                      moved['plain'] - params['head']['kernel'])
+    run.say('HybridLM step: loss {:.5f} through the kernels, {:.5f} through '
+            'plain XLA (gap {:.3%}); the head moved alike to {:.3%}'.format(
+                losses['kernels'], losses['plain'], gap, update))
+    assert np.isfinite(losses['kernels']) and gap < 2e-3, losses
+    # both sides multiply in bfloat16; they differ by the order of the sums
+    assert update < 0.05, update
+
+
 def phase_device_cache(run):
     """Epoch 0 streams and caches, epoch 1 comes out of HBM: the same rows,
     byte for byte. Then every chip must hold its share of what is resident."""
@@ -735,6 +805,7 @@ def main(argv=None):
                             ('byte proof', phase_byte_proof),
                             ('resnet train', phase_resnet),
                             ('lm train', phase_lm),
+                            ('hybrid lm train', phase_hybrid),
                             ('device cache', phase_device_cache),
                             ('kernels', phase_kernels)):
             with run.phase(name):

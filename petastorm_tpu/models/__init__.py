@@ -5,6 +5,7 @@ The reference ships example workloads (``examples/mnist``, ``examples/imagenet``
 equivalents: flax models consumed through ``jax_loader`` with mesh sharding.
 """
 
+from petastorm_tpu.models.hybrid import HybridLM  # noqa: F401
 from petastorm_tpu.models.mlp import MLP  # noqa: F401
 from petastorm_tpu.models.resnet import ResNet, ResNet18, ResNet50  # noqa: F401
 from petastorm_tpu.models.moe import SwitchMoE  # noqa: F401

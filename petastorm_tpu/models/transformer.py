@@ -33,6 +33,64 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 
+def usable_axis(mesh, axis, dim):
+    """A configured mesh axis carries a dim only when the mesh has it AND it
+    evenly divides the (static) dim, so e.g. an init trace with batch 1
+    falls back to replication for that trace alone."""
+    return (axis if axis in mesh.axis_names and dim % mesh.shape[axis] == 0
+            else None)
+
+
+def self_attention(q, k, v, attention='dense', causal=True, mesh=None,
+                   seq_axis=None, batch_axis='data', head_axis='model'):
+    """``[B, T, H, Dh]`` projections -> ``[B, T, H, Dh]`` through the backend
+    ``attention`` names (see the module docstring): what lies between a
+    layer's projections and its output projection, shared by
+    :class:`MultiHeadAttention` and ``models.hybrid``'s full-attention layer."""
+    num_heads = q.shape[2]
+    backend, _, mode = attention.partition(':')
+    interpret = mode == 'interpret'
+    if mode and not (interpret and backend in ('flash', 'a2a')):
+        raise ValueError('unknown attention {!r}'.format(attention))
+
+    if backend in ('ring', 'a2a'):
+        if mesh is None or seq_axis is None:
+            raise ValueError("attention={!r} needs mesh= and seq_axis="
+                             .format(attention))
+        from petastorm_tpu.models.attention import (a2a_self_attention,
+                                                    ring_self_attention)
+        # Keep batch/head shards local inside the shard_map.
+        batch_axis = usable_axis(mesh, batch_axis, q.shape[0])
+        head_axis = usable_axis(mesh, head_axis, num_heads)
+        if backend == 'ring':
+            sp_attention = ring_self_attention
+        else:
+            sp_attention = partial(a2a_self_attention, interpret=interpret)
+        return sp_attention(q, k, v, mesh, seq_axis, causal=causal,
+                            batch_axis=batch_axis, head_axis=head_axis)
+    if backend == 'flash':
+        from petastorm_tpu.ops.flash_attention import flash_attention
+        attend = partial(flash_attention, causal=causal, interpret=interpret)
+        if mesh is not None:
+            # A Pallas call is opaque to the SPMD partitioner, which
+            # would gather the whole batch onto every device to run it.
+            # Attention is elementwise over batch and heads: map the
+            # kernel over their shards instead.
+            spec = PartitionSpec(usable_axis(mesh, batch_axis, q.shape[0]),
+                                 None,
+                                 usable_axis(mesh, head_axis, num_heads),
+                                 None)
+            # check_vma: see models.attention.a2a_self_attention.
+            attend = jax.shard_map(attend, mesh=mesh,
+                                   in_specs=(spec, spec, spec),
+                                   out_specs=spec, check_vma=not interpret)
+        return attend(q, k, v)
+    if backend == 'dense':
+        from petastorm_tpu.models.attention import dense_attention
+        return dense_attention(q, k, v, causal=causal)
+    raise ValueError('unknown attention {!r}'.format(attention))
+
+
 class MultiHeadAttention(nn.Module):
     num_heads: int
     attention: str = 'dense'            # dense | flash | ring | a2a
@@ -57,60 +115,11 @@ class MultiHeadAttention(nn.Module):
 
         q, k, v = proj('query'), proj('key'), proj('value')   # [B, T, H, Dh]
 
-        backend, _, mode = self.attention.partition(':')
-        interpret = mode == 'interpret'
-        if mode and not (interpret and backend in ('flash', 'a2a')):
-            raise ValueError('unknown attention {!r}'.format(self.attention))
-
-        def usable(axis, dim):
-            # A configured mesh axis carries a dim only when the mesh has
-            # it AND it evenly divides the (static) dim, so e.g. an init
-            # trace with batch 1 falls back to replication for that trace
-            # alone.
-            return (axis if axis in self.mesh.axis_names
-                    and dim % self.mesh.shape[axis] == 0 else None)
-
-        if backend in ('ring', 'a2a'):
-            if self.mesh is None or self.seq_axis is None:
-                raise ValueError("attention={!r} needs mesh= and seq_axis="
-                                 .format(self.attention))
-            from petastorm_tpu.models.attention import (a2a_self_attention,
-                                                        ring_self_attention)
-            # Keep batch/head shards local inside the shard_map.
-            batch_axis = usable(self.batch_axis, q.shape[0])
-            head_axis = usable(self.head_axis, self.num_heads)
-            if backend == 'ring':
-                sp_attention = ring_self_attention
-            else:
-                sp_attention = partial(a2a_self_attention,
-                                       interpret=interpret)
-            out = sp_attention(q, k, v, self.mesh, self.seq_axis,
-                               causal=self.causal, batch_axis=batch_axis,
-                               head_axis=head_axis)
-        elif backend == 'flash':
-            from petastorm_tpu.ops.flash_attention import flash_attention
-            attend = partial(flash_attention, causal=self.causal,
-                             interpret=interpret)
-            if self.mesh is not None:
-                # A Pallas call is opaque to the SPMD partitioner, which
-                # would gather the whole batch onto every device to run it.
-                # Attention is elementwise over batch and heads: map the
-                # kernel over their shards instead.
-                spec = PartitionSpec(usable(self.batch_axis, q.shape[0]),
-                                     None,
-                                     usable(self.head_axis, self.num_heads),
-                                     None)
-                # check_vma: see models.attention.a2a_self_attention.
-                attend = jax.shard_map(attend, mesh=self.mesh,
-                                       in_specs=(spec, spec, spec),
-                                       out_specs=spec,
-                                       check_vma=not interpret)
-            out = attend(q, k, v)
-        elif backend == 'dense':
-            from petastorm_tpu.models.attention import dense_attention
-            out = dense_attention(q, k, v, causal=self.causal)
-        else:
-            raise ValueError('unknown attention {!r}'.format(self.attention))
+        out = self_attention(q, k, v, attention=self.attention,
+                             causal=self.causal, mesh=self.mesh,
+                             seq_axis=self.seq_axis,
+                             batch_axis=self.batch_axis,
+                             head_axis=self.head_axis)
 
         out = out.astype(self.dtype)
         return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
