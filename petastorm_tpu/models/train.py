@@ -5,6 +5,12 @@ The input pipeline delivers batches already laid out on the mesh
 replicated over 'data' and (for the wide classifier head) sharded over
 'model'; XLA inserts the gradient all-reduce over ICI from the sharding
 annotations — no hand-rolled collectives (SURVEY.md §5.8).
+
+Loss and accuracy come from one op, ``ops.cross_entropy``: the models hand
+back float32 logits, and inside the jitted step that cast fuses into the
+op's reads, so what lies in HBM is the logits as the head's product left
+them (bf16 for the models' default dtype) and the backward pass starts from
+them and a float32 log-sum-exp a row.
 """
 
 from typing import Any
@@ -14,6 +20,8 @@ import jax.numpy as jnp
 import optax
 from flax.training import train_state
 from jax.sharding import NamedSharding, PartitionSpec
+
+from petastorm_tpu.ops.cross_entropy import softmax_cross_entropy
 
 
 class TrainState(train_state.TrainState):
@@ -151,7 +159,9 @@ def make_scan_train_step(mesh=None, batch_axis='data', microbatches=8,
 
 def make_train_step_fn(mesh=None, batch_axis='data'):
     """The un-jitted train step body (shared by ``make_train_step`` and
-    ``make_scan_train_step``)."""
+    ``make_scan_train_step``). ``metrics`` holds the batch's mean ``loss``
+    and its ``accuracy``, both out of the fused loss's one look at the
+    logits."""
 
     def train_step(state, images, labels):
         if mesh is not None:
@@ -170,17 +180,15 @@ def make_train_step_fn(mesh=None, batch_axis='data'):
             else:
                 logits = state.apply_fn(variables, images, train=True)
                 new_batch_stats = None
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                logits, labels).mean()
-            return loss, (logits, new_batch_stats)
+            loss, hit = softmax_cross_entropy(logits, labels)
+            return loss.mean(), (hit, new_batch_stats)
 
-        (loss, (logits, new_batch_stats)), grads = jax.value_and_grad(
+        (loss, (hit, new_batch_stats)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         state = state.apply_gradients(grads=grads)
         if new_batch_stats is not None:
             state = state.replace(batch_stats=new_batch_stats)
-        accuracy = jnp.mean(jnp.argmax(logits, -1) == labels)
-        return state, {'loss': loss, 'accuracy': accuracy}
+        return state, {'loss': loss, 'accuracy': jnp.mean(hit)}
 
     return train_step
 
@@ -191,7 +199,7 @@ def make_eval_step():
         if state.batch_stats is not None:
             variables['batch_stats'] = state.batch_stats
         logits = state.apply_fn(variables, images, train=False)
-        return {'loss': optax.softmax_cross_entropy_with_integer_labels(logits, labels).mean(),
-                'accuracy': jnp.mean(jnp.argmax(logits, -1) == labels)}
+        loss, hit = softmax_cross_entropy(logits, labels)
+        return {'loss': loss.mean(), 'accuracy': jnp.mean(hit)}
 
     return jax.jit(eval_step)

@@ -165,7 +165,12 @@ class Block(nn.Module):
 
 
 class TransformerLM(nn.Module):
-    """``[B, T] int32 tokens -> [B, T, vocab] float32 logits`` (causal)."""
+    """``[B, T] int32 tokens -> [B, T, vocab] float32 logits`` (causal).
+
+    The head's product is in ``dtype`` and the cast to float32 comes last:
+    under ``jax.jit`` with a consumer in the same program (the loss of
+    ``models.train``) XLA fuses the cast into that consumer's reads, and no
+    float32 ``[B, T, vocab]`` is written."""
 
     vocab_size: int
     d_model: int = 256
