@@ -176,7 +176,7 @@ THREAD_GUARDS = (
 DIR_GUARDS = (
     DirGuard(
         ('pst-chunk-store-*',), 'petastorm_tpu.chunk_store',
-        'Env-armed readers and bench sweeps create prefix-named stores '
+        'Env-armed readers create prefix-named stores '
         'under the shared tempdir; a test dying mid-write must not leave '
         'GBs of decoded chunks on the CI NVMe. Snapshot-diff: only dirs '
         'that appeared during the test are its leaks.',
@@ -199,13 +199,6 @@ DIR_GUARDS = (
         'at start; the guard deletes what a test leaked anyway so one '
         'SIGKILL drill cannot strand 64MB segments on the CI host.',
         marker='wire', base='/dev/shm'),
-    DirGuard(
-        ('pst-bench-probe-*',), 'bench',
-        'Opportunistic-prober flock files (bench._probe_lock_path) live '
-        'under the tempdir — previously next to the committed artifact, '
-        'where one got checked in. Zero-byte, but the sweep keeps the '
-        'shared tempdir from accreting one per checkout hash.',
-        marker=None),
 )
 
 

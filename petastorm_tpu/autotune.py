@@ -4,8 +4,8 @@ The pipeline's speed knobs — ``workers_count``, ``prefetch``,
 ``arena_depth``, ``inflight``, ventilation depth — are fixed at
 construction, yet the optimum moves at runtime: the first (decode-bound)
 epoch and the cache-warm (collate-bound) steady state want different
-settings, and shared-host load swings capacity severalfold between runs
-(PROFILE_r05). tf.data's autotuning (Murray et al., VLDB 2021) and DALI's
+settings, and shared-host load swings capacity severalfold between runs.
+tf.data's autotuning (Murray et al., VLDB 2021) and DALI's
 pipeline-depth tuning both show a feedback controller over stage latencies
 recovers near-hand-tuned throughput without per-workload sweeps. Every
 signal such a controller needs already exists here (PR-3 heartbeats, PR-2
@@ -517,7 +517,7 @@ class AutoTuner(object):
         deltas = {k: snap.get(k, 0) - prev.get(k, 0) for k in _CUMULATIVE_KEYS}
         if any(v < 0 for v in deltas.values()):
             # A cumulative counter went BACKWARD: someone reset the stats
-            # mid-run (bench reset_stats() after warmup). The tick's
+            # mid-run (a benchmark's reset_stats() after warmup). The tick's
             # deltas — and any pending action verdict judged on them —
             # are garbage; discard both and re-baseline from this sample.
             self._pending = None

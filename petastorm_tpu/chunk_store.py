@@ -1,7 +1,7 @@
 """Mmap-backed decoded-chunk store: the NVMe cache tier.
 
-PROFILE_r05 shows the pipeline is jpeg-decode-bound cold (~845 img/s) and
-memcpy-bound warm (~5.5k img/s), and the pre-existing tiers leave a hole:
+A jpeg pipeline is decode-bound cold and memcpy-bound warm (no chip record
+bears on the rates), and the other cache tiers leave a hole:
 ``DeviceDatasetCache`` needs the dataset in HBM, ``MemoryCache`` needs it
 in RAM *per process* (no sharing across a process pool), and
 ``LocalDiskCache`` historically stored **encoded** bytes behind pickle, so
@@ -85,8 +85,8 @@ logger = logging.getLogger(__name__)
 
 ENV_VAR = 'PETASTORM_TPU_CHUNK_STORE'
 
-#: Temp-dir prefix for stores created without an explicit directory (bench
-#: sweeps); the conftest ``chunkstore`` guard deletes leaked matches.
+#: Temp-dir prefix for stores created without an explicit directory; the
+#: conftest ``chunkstore`` guard deletes leaked matches.
 TEMP_DIR_PREFIX = 'pst-chunk-store-'
 
 _MAGIC = b'PSTC'
@@ -344,7 +344,7 @@ class DecodedChunkStore(CacheBase):
     :param throttle_delay_s: writer pause granularity while throttled.
     :param validate: ``'open'`` (default) checks every field's CRC32 once
         per process when an entry is first mmapped; ``'off'`` trusts the
-        bytes (bench experiments only).
+        bytes (experiments only).
     :param cleanup: remove the whole store directory on :meth:`cleanup`.
     """
 

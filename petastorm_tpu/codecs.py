@@ -442,11 +442,10 @@ if not _HAS_CV2 and not _HAS_PIL:  # pragma: no cover
 # --------------------------------------------------------------------------
 
 #: Decode-path override: ``scalar`` forces one native call per image (the
-#: pre-batched behavior — the bench sweep's baseline and the determinism
-#: acceptance gate's reference stream); ``batched``/``auto``/unset keep the
-#: default one-native-call-per-(row-group, field) fast path. Read per call
-#: (like PETASTORM_TPU_FAULTS) so tests and bench sweeps flip it between
-#: readers in one process.
+#: pre-batched behavior — the determinism acceptance gate's reference
+#: stream); ``batched``/``auto``/unset keep the default
+#: one-native-call-per-(row-group, field) fast path. Read per call (like
+#: PETASTORM_TPU_FAULTS) so tests flip it between readers in one process.
 DECODE_PATH_ENV = 'PETASTORM_TPU_DECODE_PATH'
 
 #: Deliberately unguessable stand-in blob for the ``decode-corrupt-batch``

@@ -14,9 +14,9 @@ SURVEY.md §7.6:
   * device staging onto a ``Mesh``-sharded layout (each pod host
     contributes its disjoint reader shard — ``make_pod_reader`` maps
     ``cur_shard`` to ``jax.process_index()``): per-device sharded
-    assembly by default — zero-copy batch-dim sub-slices dispatched on
-    one overlapped ``device_put`` stream per addressable device and
-    stitched with ``jax.make_array_from_single_device_arrays`` — with
+    assembly by default — zero-copy batch-dim sub-slices going out as
+    one batched per-device transfer a field, inline or from a per-device
+    stream by the shard's bytes — with
     ``jax.make_array_from_process_local_data`` as the one-shot fallback
     for shardings that split non-batch dims; plain ``device_put``
     single-chip,
@@ -48,10 +48,6 @@ def _never_ready():
     """Fallback readiness probe for array types without ``is_ready`` —
     the engine then waits via the blocking ``ready_fn`` instead."""
     return False
-
-# Fields smaller than this stage as one put even under stage_chunks>1:
-# chunking a 1KB label column costs k round trips for nothing.
-_STAGE_CHUNK_MIN_BYTES = 4 << 20
 
 
 # --------------------------------------------------------------------------
@@ -680,19 +676,18 @@ def _stack_column(values, name, shape_policies, x64, out=None):
 # --------------------------------------------------------------------------
 
 class _BatchedShardWave(object):
-    """One field's whole per-device wave, submitted as a SINGLE stream
-    item: the stream-side put issues one C++ batched transfer over every
-    shard view and returns the stitched global array, so DMA-scale fields
-    get the cheap dispatch of the inline tier AND land against the
+    """One field's whole per-device wave: what :meth:`JaxLoader.
+    _batched_assemble` puts as one C++ batched transfer over every shard
+    view, inline or as a SINGLE stream item. On a stream, DMA-scale
+    fields get the cheap dispatch of the inline tier AND land against the
     per-device in-flight windows (fence pipelining) instead of blocking
-    the dispatch thread. ``pst_self_accounting`` tells the stream loop
-    the put_fn records the true per-device byte/shard breakdown itself
-    (``record_inline_wave``) — the submitting stream must not claim the
-    whole wave's bytes as its own."""
+    the dispatch thread; the put records the true per-device byte/shard
+    breakdown itself (``record_inline_wave``), so the submitting stream
+    keeps the window's books only and claims none of the wave's bytes as
+    its own."""
 
     __slots__ = ('sharding', 'plan', 'streams', 'views', 'from_arena',
                  'nbytes')
-    pst_self_accounting = True
 
     def __init__(self, sharding, plan, streams, views, from_arena):
         self.sharding = sharding
@@ -705,6 +700,21 @@ class _BatchedShardWave(object):
 
 class JaxLoader(object):
     """Iterates mesh-sharded ``jax.Array`` batches off a Reader.
+
+    Each field of a batch is staged by one of six tiers, chosen from what
+    the loader can observe and counted in ``stats['stage_tiers']`` (the
+    ``dispatch.stage`` span's ``cause`` names the batch's):
+    ``device-decoded`` (the decode hook already returned a device array);
+    with a ``mesh``/``sharding`` whose devices this process addresses,
+    ``inline-batched`` or ``streamed-batched`` by the shard's bytes against
+    ``device_stream_min_bytes`` where the sharding partitions just the
+    leading batch dim (each device's shard is then a zero-copy contiguous
+    sub-slice of the host batch:
+    :func:`petastorm_tpu.parallel.mesh.device_shard_plan`, computed once
+    per schema), else ``one-shot``
+    (``jax.make_array_from_process_local_data``, e.g. a sequence-sharded
+    dim); without either, ``dlpack`` on the CPU backend and ``plain``
+    (``jax.device_put``) elsewhere.
 
     :param reader: a ``make_reader``/``make_batch_reader`` Reader (each pod
         host should construct it with ``cur_shard=jax.process_index()``).
@@ -736,12 +746,6 @@ class JaxLoader(object):
         repeats trade statistical efficiency for step throughput — the chip
         trains instead of idling. Epoch/checkpoint accounting counts source
         rows once; ``stats['batches']`` counts echoed deliveries.
-    :param stage_chunks: split each ``>=4MB`` field into this many
-        ``device_put`` events along the batch dim and concatenate on device.
-        Leave it at 1 on a directly attached chip (docs/tpu_guide.rst);
-        no chip record shows it winning there. Applies per target device:
-        single-device loaders chunk the whole batch, and the per-device
-        sharded path chunks each device's shard on its own dispatch stream.
     :param arena_depth: host-batch arenas in the staging engine's pool
         (``prefetch > 0`` only). Batches are collated into these recycled
         preallocated buffers instead of allocating every batch; an arena
@@ -755,43 +759,22 @@ class JaxLoader(object):
         before the dispatch stage blocks on the oldest — the window that
         lets collate of batch N+1 overlap the transfer of batch N
         (``stats['overlap_frac']``).
-    :param per_device_dispatch: the per-device sharded staging path
-        (mesh/sharding targets only). When the batch sharding partitions
-        just the leading batch dim, each field's per-device shards are
-        zero-copy contiguous sub-slices of the host batch
-        (:func:`petastorm_tpu.parallel.mesh.device_shard_plan`, computed
-        once per schema); dispatch runs one overlapped ``device_put``
-        stream per addressable device (``staging.DeviceStager``,
-        ``pst-device-put-*`` threads with per-device in-flight windows
-        and donated arena-backed shards) and stitches the global array
-        with ``jax.make_array_from_single_device_arrays`` — so collate
-        of shard k+1 hides under the transfer of shard k on *every*
-        device. ``None`` (default) auto-enables for eligible shardings,
-        falling back to the one-shot
-        ``jax.make_array_from_process_local_data`` per ineligible field
-        (e.g. a sequence-sharded dim); ``False`` forces the one-shot
-        path everywhere (the pre-ISSUE-14 behavior, kept for A/B
-        benching); ``True`` additionally raises when no addressable
-        device is found.
     :param device_inflight: per-device in-flight transfer window of the
-        per-device dispatch streams (each stream blocks on its own
+        per-device dispatch streams (``staging.DeviceStager``,
+        ``pst-device-put-*`` threads; each stream blocks on its own
         oldest transfer past this) — the autotuner's ``device_inflight``
         knob; dispatch-bound ticks widen it before the batch-level
         ``inflight`` window.
-    :param device_stream_min_bytes: per-shard size at which a field's
-        shards route through the per-device *stream threads* (issue-side
-        overlap pays when each transfer is DMA-scale). Smaller shards
-        are issued inline on the dispatch thread as ONE batched
-        per-device transfer (``pxla.batched_device_put`` over the
-        precomputed zero-copy shard views — faster than the one-shot
-        path because the shard layout is never recomputed per batch);
-        both tiers produce the identical per-device-sharded global
-        array. Default 8MB; ``0`` forces every shard through the
-        streams. DMA-scale fields above the threshold still go out as
-        one batched transfer when the API is available — issued FROM a
-        stream thread as a single wave item so the transfer lands
-        against the per-device in-flight window instead of blocking
-        dispatch (the streamed-batched tier).
+    :param device_stream_min_bytes: per-shard size at which a planned
+        field's wave is issued from a per-device *stream thread*
+        (``streamed-batched``: the transfer lands against the per-device
+        in-flight window instead of blocking dispatch, which pays when
+        each transfer is DMA-scale). Smaller shards are issued inline on
+        the dispatch thread (``inline-batched``). Both are ONE batched
+        per-device transfer a field (``pxla.batched_device_put`` over the
+        precomputed zero-copy shard views) and produce the identical
+        per-device-sharded global array. Default 8MB; ``0`` sends every
+        planned field through the streams.
     :param pinned_arenas: allocate the host staging arenas as
         DMA-friendly pinned slabs (page-aligned, pre-faulted,
         best-effort ``mlock`` — see ``native/pinned.py``); falls back
@@ -854,11 +837,11 @@ class JaxLoader(object):
                  batch_axis='data', prefetch=2, shape_policies=None,
                  shuffling_queue_capacity=0, min_after_dequeue=None, seed=None,
                  last_batch='drop', strict_fields=False, echo=1, tracer=None,
-                 stage_chunks=1, arena_depth=None, inflight=2,
+                 arena_depth=None, inflight=2,
                  watchdog=None, stall_timeout_s=None, autotune=None,
                  lineage=None, resume_state=None, on_device_augment=None,
-                 per_device_dispatch=None, device_inflight=2,
-                 device_stream_min_bytes=None, pinned_arenas=None):
+                 device_inflight=2, device_stream_min_bytes=None,
+                 pinned_arenas=None):
         import jax
 
         # Fail a typo'd memory budget before any staging thread starts or
@@ -998,8 +981,7 @@ class JaxLoader(object):
         self._queue = queue.Queue(maxsize=self._prefetch_target)
         # Consumer-local drain buffer: __next__ moves every already-staged
         # batch here under one queue-mutex acquisition instead of paying a
-        # lock round trip per batch (the warm-cache chunk rate is queue-pop
-        # bound — PROFILE_r05 §2). Consumer thread only.
+        # lock round trip per batch. Consumer thread only.
         self._ready = deque()
         self._stop = threading.Event()
         self._exhausted = False
@@ -1091,16 +1073,6 @@ class JaxLoader(object):
         # a later zeroing would blank the accounting at spin-up.
         self._last_batch_nbytes = 0
         self._dlpack_staging = jax.default_backend() == 'cpu'
-        # stage_chunks > 1 splits each field along the batch dim into that
-        # many device_puts and concatenates on device. Applies per target
-        # device: single-device loaders chunk the whole batch; the
-        # per-device sharded path chunks each device's shard on its own
-        # stream (_put_shard).
-        self._stage_chunks = max(1, int(stage_chunks))
-        self._stage_concat = None
-        if self._stage_chunks > 1:
-            import jax.numpy as jnp
-            self._stage_concat = jax.jit(lambda *xs: jnp.concatenate(xs))
 
         # Zero-copy backends (CPU) hand out device arrays that ALIAS host
         # memory; recycling/accounting decisions below key off this once.
@@ -1108,13 +1080,12 @@ class JaxLoader(object):
         self._staging_aliasing = (self._dlpack_staging
                                   or staging_aliases_host(jax))
 
-        # Per-device sharded staging (the ISSUE-14 tentpole): one
-        # overlapped device_put stream per addressable device; batch-dim
-        # shards are zero-copy contiguous sub-slices of the host batch
-        # and the global jax.Array is stitched with
-        # make_array_from_single_device_arrays. Shard layouts are planned
-        # once per (field, shape) in _device_shard_plan; ineligible
-        # fields keep the one-shot path per field.
+        # Per-device sharded staging: batch-dim shards are zero-copy
+        # contiguous sub-slices of the host batch, and each field goes out
+        # as one batched transfer, inline or from one of the per-device
+        # streams. Shard layouts are planned once per (field, shape) in
+        # _device_shard_plan; ineligible fields keep the one-shot path
+        # per field.
         self._stager = None
         self._stager_devices = ()
         self._shard_plans = {}
@@ -1131,8 +1102,7 @@ class JaxLoader(object):
         # installed jax: if it moves, this import fails and says so.
         from jax._src.interpreters import pxla
         self._batched_put = pxla.batched_device_put
-        if (mesh is not None or sharding is not None) \
-                and per_device_dispatch is not False:
+        if mesh is not None or sharding is not None:
             devices = self._collect_stager_devices()
             if devices:
                 from petastorm_tpu.staging import DeviceStager, OverlapMeter
@@ -1145,21 +1115,16 @@ class JaxLoader(object):
                 # The stager gets its OWN OverlapMeter: the loader tracks
                 # 'host' around _stage on it, the stager tracks one
                 # logical 'h2d' lane over its in-flight windows, and
-                # their co-activity IS the streamed-path h2d_overlap_frac
-                # (satellite: the bench probe used to report 0.0 here).
+                # their co-activity IS the streamed-path h2d_overlap_frac.
                 self._stager = DeviceStager(
                     stream_keys=[str(getattr(d, 'id', i))
                                  for i, d in enumerate(devices)],
-                    put_fn=self._put_shard,
+                    put_fn=self._batched_assemble,
                     inflight=device_inflight,
                     ready_fn=jax.block_until_ready,
                     stop_event=self._stop,
                     tracer=self._tracer,
                     meter=OverlapMeter())
-            elif per_device_dispatch:
-                raise ValueError(
-                    'per_device_dispatch=True but the mesh/sharding has no '
-                    'addressable device on this process')
 
         # Pipelined staging engine (prefetch > 0): an assemble stage that
         # collates batches into recycled host arenas and a dispatch stage
@@ -1479,22 +1444,6 @@ class JaxLoader(object):
         from petastorm_tpu.parallel.mesh import batch_sharding
         return batch_sharding(self._mesh, self._batch_axis)
 
-    def _chunked_put(self, array, sharding=None, device=None, donate=False):
-        """Split along the batch dim into ``stage_chunks`` pieces, put
-        each, concatenate on device — the ONE implementation of the
-        ``stage_chunks`` option. ``device`` is the per-device-stream form
-        (each shard chunks on its own stream, optionally donated);
-        ``sharding``/neither are the no-mesh and fallback forms."""
-        jax = self._jax
-        parts = np.array_split(array, min(self._stage_chunks, len(array)))
-        if device is not None:
-            staged = [self._device_put(p, device, donate) for p in parts]
-        elif sharding is not None:
-            staged = [jax.device_put(p, sharding) for p in parts]
-        else:
-            staged = [jax.device_put(p) for p in parts]
-        return self._stage_concat(*staged)
-
     # -- per-device sharded staging ---------------------------------------
 
     def _collect_stager_devices(self):
@@ -1516,15 +1465,12 @@ class JaxLoader(object):
         return tuple(sorted(devices, key=lambda d: getattr(d, 'id', 0)))
 
     def _device_shard_plan(self, name, sharding, shape):
-        """``(plan, stream_indices, donate_ok)`` for a batch-dim-sharded
+        """``(plan, stream_indices)`` for a batch-dim-sharded
         field, or ``None`` (ineligible: keep the one-shot path). Memoized
         per (field, host shape) — shard boundaries are computed from the
         ``NamedSharding`` exactly once per schema, and the arena pool
         learns the layout so arenas can hand out memoized per-device
-        sub-slice views (zero re-layout at dispatch time). ``donate_ok``
-        marks the shards whose bound no replica shares — only those may
-        be donated outright (donating one replica's buffer would
-        invalidate it under its sibling's transfer)."""
+        sub-slice views (zero re-layout at dispatch time)."""
         key = (name, tuple(shape))
         cached = self._shard_plans.get(key)
         if cached is not None:
@@ -1535,8 +1481,7 @@ class JaxLoader(object):
             self._shard_plans[key] = False
             return None
         index_of = {d: i for i, d in enumerate(self._stager_devices)}
-        entry = (plan, tuple(index_of[d] for d in plan.devices),
-                 tuple(plan.bounds.count(b) == 1 for b in plan.bounds))
+        entry = (plan, tuple(index_of[d] for d in plan.devices))
         self._shard_plans[key] = entry
         if self._arena_pool is not None:
             self._arena_pool.learn_shard_layout({name: plan.bounds})
@@ -1562,115 +1507,63 @@ class JaxLoader(object):
         return tuple(array[start:stop]
                      for start, stop in plan.bounds), False
 
-    def _device_put(self, array, device, donate):
-        """One shard onto one device. ``donate`` hands the (arena-backed)
-        host buffer to the backend without a defensive copy — safe because
-        arena recycling is already gated on transfer completion plus, on
-        aliasing backends, consumer GC holds."""
-        return self._jax.device_put(array, device, donate=bool(donate))
-
-    def _put_shard(self, array, stream_index, donate):
-        """DeviceStager ``put_fn``: issue one shard's transfer on its
-        device's stream — through :meth:`_chunked_put` when
-        ``stage_chunks`` asks (the transport optimization now applies
-        per device, so multi-device shardings ride it too). A
-        :class:`_BatchedShardWave` item carries a whole field's wave and
-        goes out as one batched transfer, stitched into the global array
-        before it enters the in-flight window (the streamed-batched tier:
-        :meth:`_batched_assemble`, run ON this stream thread)."""
-        if isinstance(array, _BatchedShardWave):
-            return self._batched_assemble(array.sharding, array.plan,
-                                          array.streams, array.views,
-                                          array.from_arena)
-        device = self._stager_devices[stream_index]
-        if (self._stage_chunks > 1
-                and array.nbytes >= _STAGE_CHUNK_MIN_BYTES
-                and len(array) >= self._stage_chunks):
-            return self._chunked_put(array, device=device, donate=donate)
-        return self._device_put(array, device, donate)
-
     def _stage_pending_shards(self, pending, out, arena):
-        """Dispatch every planned field's per-device shards, then stitch
-        each field's global ``jax.Array``. Three tiers, same result:
+        """Dispatch every planned field's per-device shards as the field's
+        global ``jax.Array``. Two tiers by the shard's bytes, same result:
 
-        * **inline** (small shards): ONE batched per-device transfer per
-          field on the dispatch thread — the precomputed zero-copy shard
-          views go straight into ``pxla.batched_device_put``, so dispatch
-          pays no per-batch layout work and no per-shard Python
-          round-trips (measurably faster than the one-shot
-          ``make_array_from_process_local_data``, which re-wrangles
-          indices every call);
+        * **inline-batched** (small shards): ONE batched per-device
+          transfer per field on the dispatch thread — the precomputed
+          zero-copy shard views go straight into
+          ``pxla.batched_device_put``, so dispatch pays no per-batch
+          layout work and no per-shard Python round-trips (the one-shot
+          ``make_array_from_process_local_data`` re-wrangles indices
+          every call);
         * **streamed-batched** (DMA-scale shards): the same single C++
           batched transfer, but issued FROM a stream thread as one
           :class:`_BatchedShardWave` item so it lands against the
           per-device in-flight windows (fence pipelining) instead of
-          blocking the dispatch thread for the whole transfer;
-        * **streams** (chunked puts only): the wave is submitted
-          shard-by-shard across the per-device stream threads
-          before gathering, so every device issues concurrently; the
-          field stitches with
-          ``jax.make_array_from_single_device_arrays``.
+          blocking the dispatch thread for the whole transfer.
         """
-        jax = self._jax
-        streamed = []
         waves = []
-        for name, sharding, plan, streams, donate_ok, array in pending:
+        for name, sharding, plan, streams, array in pending:
             views, from_arena = self._shard_arrays(name, array, arena, plan)
+            wave = _BatchedShardWave(sharding, plan, streams, views,
+                                     from_arena)
             shard_nbytes = views[0].nbytes if views else 0
-            chunked = (self._stage_chunks > 1
-                       and shard_nbytes >= _STAGE_CHUNK_MIN_BYTES)
-            if not chunked:
-                if shard_nbytes < self._device_stream_min_bytes:
-                    self._stage_tiers['inline-batched'] += 1
-                    out[name] = self._batched_assemble(
-                        sharding, plan, streams, views, from_arena)
-                else:
-                    self._stage_tiers['streamed-batched'] += 1
-                    waves.append((name, _BatchedShardWave(
-                        sharding, plan, streams, views, from_arena)))
-                continue
-            self._stage_tiers['per-shard-streams'] += 1
-            streamed.append((name, sharding, plan, streams, donate_ok,
-                             views, from_arena))
-        if not waves and not streamed:
+            if shard_nbytes < self._device_stream_min_bytes:
+                self._stage_tiers['inline-batched'] += 1
+                out[name] = self._batched_assemble(wave)
+            else:
+                self._stage_tiers['streamed-batched'] += 1
+                waves.append((name, wave))
+        if not waves:
             return
-        items = []
-        for i, (_name, wave) in enumerate(waves):
-            # Round-robin the submitting stream over the wave's own
-            # devices so concurrent fields issue from different threads
-            # (the batched put covers every device either way).
-            items.append((wave.streams[i % len(wave.streams)], wave, False))
-        for _name, _sh, _plan, streams, donate_ok, views, from_arena \
-                in streamed:
-            for stream, view, unique in zip(streams, views, donate_ok):
-                items.append((stream, view, from_arena and unique))
-        staged_flat = self._stager.put_shards(items)
-        for k, (name, _wave) in enumerate(waves):
-            out[name] = staged_flat[k]
-        pos = len(waves)
-        for name, sharding, plan, streams, _ok, views, _fa in streamed:
-            count = len(streams)
-            out[name] = jax.make_array_from_single_device_arrays(
-                plan.global_shape, sharding,
-                list(staged_flat[pos:pos + count]))
-            pos += count
+        # Round-robin the submitting stream over the wave's own devices so
+        # concurrent fields issue from different threads (the batched put
+        # covers every device either way).
+        staged = self._stager.put_shards(
+            [(wave.streams[i % len(wave.streams)], wave)
+             for i, (_name, wave) in enumerate(waves)])
+        for (name, _wave), array in zip(waves, staged):
+            out[name] = array
 
-    def _batched_assemble(self, sharding, plan, streams, views, from_arena):
+    def _batched_assemble(self, wave):
         """The global per-device-sharded array in one C++ batched transfer
         over the precomputed shard views — called on the dispatch thread
-        (inline tier) or from a stream thread for a whole-wave item
-        (streamed-batched tier, ``pst_self_accounting``: the stream loop
-        skips its own accounting and this records the wave's true
-        per-device breakdown). ``from_arena`` feeds the donation
-        accounting (arena sub-slices handed over with no loader-side copy;
-        the batched API itself never donates)."""
+        (inline tier) or, as the DeviceStager's ``put_fn``, from a stream
+        thread (streamed-batched tier: the stream loop keeps the window's
+        books and this records the wave's true per-device breakdown).
+        ``from_arena`` feeds the donation accounting (arena sub-slices
+        handed over with no loader-side copy; the batched API itself never
+        donates)."""
         t0 = time.perf_counter()
         staged = self._batched_put(
-            self._jax.core.ShapedArray(plan.global_shape, views[0].dtype),
-            sharding, list(views), list(plan.devices))
+            self._jax.core.ShapedArray(wave.plan.global_shape,
+                                       wave.views[0].dtype),
+            wave.sharding, list(wave.views), list(wave.plan.devices))
         self._stager.record_inline_wave(
-            streams, [v.nbytes for v in views],
-            time.perf_counter() - t0, from_arena)
+            wave.streams, [v.nbytes for v in wave.views],
+            time.perf_counter() - t0, wave.from_arena)
         return staged
 
     def _decode_raw_columns(self, host_batch):
@@ -1741,40 +1634,26 @@ class JaxLoader(object):
                 if hasattr(array, 'is_ready'):
                     # A device-decode hook already produced a committed
                     # jax array: any re-staging path (process-local-data
-                    # assembly, chunked puts, dlpack import) would at
-                    # best round-trip it through the host.
+                    # assembly, dlpack import) would at best round-trip it
+                    # through the host.
                     self._stage_tiers['device-decoded'] += 1
                     out[name] = array
                     continue
-                chunkable = (self._stage_chunks > 1
-                             and array.nbytes >= _STAGE_CHUNK_MIN_BYTES
-                             and len(array) >= self._stage_chunks)
                 if self._mesh is not None or self._sharding is not None:
                     sharding = self._field_sharding(name)
                     planned = (self._device_shard_plan(name, sharding,
                                                        array.shape)
                                if self._stager is not None else None)
                     if planned is not None:
-                        # Per-device sharded path: zero-copy shard views
-                        # dispatched on per-device streams (chunked puts
-                        # included — _put_shard splits per device), then
-                        # stitched into the global array below.
-                        plan, streams, donate_ok = planned
+                        # Per-device sharded path: zero-copy shard views,
+                        # dispatched as one wave below.
+                        plan, streams = planned
                         pending.append((name, sharding, plan, streams,
-                                        donate_ok, array))
-                    elif chunkable and sharding.num_devices == 1:
-                        # No stager (per_device_dispatch=False A/B mode,
-                        # or no addressable device): single-device
-                        # shardings still honor stage_chunks.
-                        self._stage_tiers['chunked'] += 1
-                        out[name] = self._chunked_put(array, sharding)
+                                        array))
                     else:
                         self._stage_tiers['one-shot'] += 1
                         out[name] = jax.make_array_from_process_local_data(
                             sharding, array)
-                elif chunkable and not self._dlpack_staging:
-                    self._stage_tiers['chunked'] += 1
-                    out[name] = self._chunked_put(array, None)
                 elif self._dlpack_staging:
                     self._stage_tiers['dlpack'] += 1
                     # CPU backend: import the host buffer zero-copy via
@@ -1972,8 +1851,7 @@ class JaxLoader(object):
                 item = self._queue.get()
                 # Batched pop: move every staged batch into the local
                 # buffer under ONE mutex acquisition (vs one Queue.get
-                # lock round trip per batch — the warm-cache rate is
-                # queue-pop bound, PROFILE_r05 §2). The queue's live
+                # lock round trip per batch). The queue's live
                 # maxsize shrinks by the same count (no notify): drained
                 # slots must NOT become capacity the dispatch thread
                 # refills, or staged-but-undelivered device batches would
@@ -2098,7 +1976,7 @@ class JaxLoader(object):
         if self._stager is not None:
             # Per-device dispatch health: stream count (n_devices — the
             # real data-parallel fan-out, not a dryrun), per-device put
-            # seconds/bytes (the bench's per-device h2d_GBps basis),
+            # seconds/bytes (what a per-device h2d GB/s is read from),
             # shards donated (zero-copy handoffs), and per-stream window
             # fences.
             stager_stats = self._stager.stats()
@@ -2149,7 +2027,7 @@ class JaxLoader(object):
         governor = membudget_mod.get_governor()
         if governor.armed:
             # Memory governor: budget, ladder position + peaks, per-pool
-            # bytes, degrade-action counts (the bench's `mem` block).
+            # bytes, degrade-action counts.
             out['mem'] = governor.stats()
         return out
 
@@ -2164,8 +2042,7 @@ class JaxLoader(object):
     @property
     def lineage_tracker(self):
         """The loader's :class:`~petastorm_tpu.lineage.LineageTracker`
-        (``None`` when unarmed) — ring access for tests and the bench's
-        replay self-check."""
+        (``None`` when unarmed) — ring access for a replay self-check."""
         return self._lineage
 
     def state_dict(self):

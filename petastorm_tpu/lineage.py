@@ -590,7 +590,7 @@ class LineageLedger(object):
                 self._queue.task_done()
 
     def flush(self, timeout_s=5.0):
-        """Best-effort drain wait (tests / bench self-checks): True when
+        """Best-effort drain wait (tests, self-checks): True when
         every accepted record reached the file within the timeout. Gates
         on the written count, not the queue depth — the writer pops a
         record (queue hits 0) before its bytes land."""

@@ -176,8 +176,7 @@ def process_rss_bytes(statm_path='/proc/self/statm'):
 
 def peak_rss_bytes():
     """Lifetime peak RSS (``ru_maxrss``) in bytes. Kernel units differ:
-    Linux reports kilobytes, macOS bytes (the same quirk ``bench.py``'s
-    ``_rss_mb`` handles)."""
+    Linux reports kilobytes, macOS bytes."""
     import resource
     maxrss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
     return int(maxrss if sys.platform == 'darwin' else maxrss * 1024)
@@ -788,7 +787,7 @@ class MemoryGovernor(object):
                 'pools': dict(self._last_pools)}
 
     def stats(self):
-        """The bench/``stats`` surface: budget provenance, ladder peaks,
+        """The ``stats`` surface: budget provenance, ladder peaks,
         per-action degrade counts, transition history."""
         with self._lock:
             actions = dict(self._degrade_actions)

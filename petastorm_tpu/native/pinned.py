@@ -1,4 +1,4 @@
-"""DMA-friendly host slabs (src/pinned.cc) + the memcpy ceiling probe.
+"""DMA-friendly host slabs (src/pinned.cc).
 
 The arena pool allocates its per-batch host buffers out of these slabs
 when pinned mode is on: page-aligned, pre-faulted, and best-effort
@@ -54,8 +54,6 @@ def _load():
     lib.pst_pinned_free.restype = None
     lib.pst_pinned_free.argtypes = [ctypes.c_void_p, ctypes.c_size_t,
                                     ctypes.c_int]
-    lib.pst_memcpy_GBps.restype = ctypes.c_double
-    lib.pst_memcpy_GBps.argtypes = [ctypes.c_size_t, ctypes.c_int]
     _lib = lib
     return _lib
 
@@ -138,31 +136,3 @@ def allocate(nbytes, lock=True):
     if slab is None:
         slab = _allocate_mmap(nbytes, lock)
     return slab
-
-
-def memcpy_ceiling_GBps(nbytes=64 << 20, reps=5):
-    """Measured sustained host-memcpy bandwidth in GB/s — the ceiling any
-    memcpy-based h2d path is chasing. Uses the GIL-free native probe when
-    available, a ``np.copyto`` timing loop otherwise; ``None`` when the
-    measurement failed outright."""
-    nbytes, reps = int(nbytes), int(reps)
-    if nbytes <= 0 or reps <= 0:
-        return None
-    lib = _load()
-    if lib is not None:
-        gbps = float(lib.pst_memcpy_GBps(nbytes, reps))
-        return gbps if gbps > 0 else None
-    import time
-    try:
-        a = np.ones(nbytes, np.uint8)
-        b = np.zeros(nbytes, np.uint8)
-    except MemoryError:
-        return None
-    np.copyto(b, a)  # warmup
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        np.copyto(b, a)
-    dt = time.perf_counter() - t0
-    if dt <= 0:
-        return None
-    return nbytes * reps / dt / 1e9

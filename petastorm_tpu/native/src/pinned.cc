@@ -1,5 +1,4 @@
-// DMA-friendly host memory for the arena pool, plus the host-memcpy
-// ceiling probe the bench children compare h2d_GBps against.
+// DMA-friendly host memory for the arena pool.
 //
 // pst_pinned_alloc maps page-aligned anonymous memory (MAP_POPULATE
 // pre-faults every page so first-touch faults never land inside the
@@ -8,9 +7,7 @@
 // is not an error: the mapping is still page-aligned and pre-faulted,
 // which is most of the win on hosts without CAP_IPC_LOCK.
 
-#include <cstring>
-#include <cstdlib>
-#include <ctime>
+#include <cstddef>
 
 #include <sys/mman.h>
 
@@ -36,35 +33,6 @@ void pst_pinned_free(void* p, size_t nbytes, int locked) {
     if (p == nullptr) return;
     if (locked) munlock(p, nbytes);
     munmap(p, nbytes);
-}
-
-// Sustained single-thread memcpy bandwidth in GB/s over `reps` copies of
-// an `nbytes` buffer (one untimed warmup). This is the host-side ceiling
-// any h2d path built on host memcpy cannot beat.
-double pst_memcpy_GBps(size_t nbytes, int reps) {
-    if (nbytes == 0 || reps <= 0) return -1.0;
-    char* a = static_cast<char*>(malloc(nbytes));
-    char* b = static_cast<char*>(malloc(nbytes));
-    if (a == nullptr || b == nullptr) {
-        free(a);
-        free(b);
-        return -1.0;
-    }
-    memset(a, 1, nbytes);
-    memset(b, 0, nbytes);
-    memcpy(b, a, nbytes);  // warmup: fault + warm caches outside the window
-    struct timespec t0, t1;
-    clock_gettime(CLOCK_MONOTONIC, &t0);
-    for (int i = 0; i < reps; ++i) {
-        memcpy(b, a, nbytes);
-        asm volatile("" : : "r"(b) : "memory");
-    }
-    clock_gettime(CLOCK_MONOTONIC, &t1);
-    double dt = (t1.tv_sec - t0.tv_sec) + (t1.tv_nsec - t0.tv_nsec) * 1e-9;
-    free(a);
-    free(b);
-    if (dt <= 0.0) return -1.0;
-    return static_cast<double>(nbytes) * reps / dt / 1e9;
 }
 
 }  // extern "C"

@@ -19,8 +19,8 @@ service and MinatoLoader (PAPERS.md) both treat transient input-tier failure
 as a first-class event; a single policy object makes the behavior uniform,
 testable (inject a fake ``sleep``/``rng``) and tunable in one place.
 
-Module-level counters record every retry so ``bench.py`` can surface
-retry-rate regressions in BENCH_*.json.
+Module-level counters record every retry (:func:`retry_counters`), so a
+run can show its retry rate.
 """
 
 import logging
@@ -44,7 +44,7 @@ def _count_retry(name):
 
 
 def retry_counters():
-    """Snapshot of ``{loop_name: retries_this_process}`` (bench telemetry)."""
+    """Snapshot of ``{loop_name: retries_this_process}``."""
     with _counters_lock:
         return dict(_retry_counters)
 

@@ -1,10 +1,10 @@
 """Pipelined staging engine: recycled host-batch arenas + overlapped
 assemble/dispatch.
 
-PROFILE_r05 shows the steady-state input pipeline is collate/memcpy-bound
-and that staging never overlaps anything (``h2d_overlap_frac`` 0.0,
-``stage_dispatch_s`` + ``consumer_wait_s`` dominating the pipeline wall).
-This module is the fix, in the tf.data (arXiv:2101.12127) / MinatoLoader
+A cache-warm input pipeline is collate/memcpy-bound, and staging done in
+one loop overlaps nothing (``h2d_overlap_frac`` 0.0, ``stage_dispatch_s`` +
+``consumer_wait_s`` making up the pipeline wall). This module is the fix,
+in the tf.data (arXiv:2101.12127) / MinatoLoader
 (arXiv:2509.10712) shape: software pipelining between batch assembly and
 device dispatch, plus buffer reuse so the collate path stops allocating a
 fresh host batch every step.
@@ -604,7 +604,7 @@ class ArenaPool(object):
 class OverlapMeter(object):
     """Wall-clock co-activity of named stages (assemble vs dispatch).
 
-    ``reset()`` starts a new measurement window (the bench resets after
+    ``reset()`` starts a new measurement window (a benchmark resets after
     warmup) but lifetime totals survive it — on zero-copy backends the
     cache-warm steady state has nearly nothing left to overlap (both
     stages are view handoffs), so the decode-bound phase where dispatch
@@ -767,28 +767,24 @@ class DeviceStagerStopped(RuntimeError):
 class DeviceStager(object):
     """One overlapped ``device_put`` stream per addressable device.
 
-    The one-shot ``jax.make_array_from_process_local_data`` path issues
-    every device's transfer from a single thread and fences the whole
-    batch at once, so the collate of batch N+1 can only hide under the
-    *aggregate* transfer of batch N. This runs one dispatch stream (a
-    ``pst-device-put-<k>`` thread) per device instead: shard puts issue
-    concurrently across devices, each stream keeps its own bounded
-    in-flight window (blocking on its *oldest* transfer when full), and
-    the caller stitches the staged shards into a global ``jax.Array``
-    with ``jax.make_array_from_single_device_arrays`` — so collate of
-    shard k+1 hides under the transfer of shard k on *every* device, not
-    just along the batch dim of one.
+    Issuing a DMA-scale transfer on the dispatch thread blocks it for
+    the whole transfer. This runs one dispatch stream (a
+    ``pst-device-put-<k>`` thread) per device instead: the owner submits
+    a field's whole per-device wave as one item to one of them, each
+    stream keeps its own bounded in-flight window (blocking on its
+    *oldest* transfer when full), and waves of concurrent fields issue
+    from different threads. The item's put accounts itself
+    (:meth:`record_inline_wave`); the stream keeps the window's books.
 
     jax-free by construction (``put_fn`` injected), so the stream
-    discipline — ordering, windows, donation accounting, stop semantics —
-    is unit-testable without a backend.
+    discipline — ordering, windows, stop semantics — is unit-testable
+    without a backend.
 
     :param stream_keys: one label per stream (device ids); sets the
         stream count and the ``device`` label on
         ``pst_device_put_seconds``.
-    :param put_fn: ``(array, stream_index, donate) -> staged array``;
-        called on the stream's own thread, must be thread-safe across
-        streams (``jax.device_put`` is).
+    :param put_fn: ``item -> staged array``; called on the submitting
+        stream's own thread, must be thread-safe across streams.
     :param inflight: per-stream in-flight transfer window (the autotune
         ``device_inflight`` knob; :meth:`set_inflight` retargets live).
     :param ready_fn: ``staged -> None`` blocking until the transfer
@@ -810,8 +806,7 @@ class DeviceStager(object):
         # unfenced transfer (all streams collapse into one logical h2d
         # lane — per-stream spans would measure stream-vs-stream
         # co-activity, not transfer-vs-host overlap). stats() then
-        # reports h2d_overlap_frac for the streamed path, which the
-        # bench's one-shot probe cannot see.
+        # reports h2d_overlap_frac for the streamed path.
         self.meter = meter
         self._h2d_tokens = 0
         self._h2d_span = None
@@ -825,8 +820,7 @@ class DeviceStager(object):
         self._m_donated = metrics_mod.counter(
             'pst_shards_donated_total',
             'Arena-backed shards handed to the device transfer with no '
-            'loader-side host copy (stream-tier puts additionally donate '
-            'the buffer to the backend)')
+            'loader-side host copy')
         self._stats_lock = threading.Lock()
         self._put_s = {k: 0.0 for k in self._keys}
         self._put_bytes = {k: 0 for k in self._keys}
@@ -868,31 +862,25 @@ class DeviceStager(object):
     # -- submission --------------------------------------------------------
 
     def put_shards(self, items):
-        """Dispatch one wave of shards: ``items`` is a list of
-        ``(stream_index, array, donate)``; returns the staged arrays in
+        """Dispatch one batch's waves: ``items`` is a list of
+        ``(stream_index, item)``, an item being whatever ``put_fn`` takes
+        with its host bytes as ``nbytes``; returns the staged arrays in
         the same order once every put has been *issued* (transfers
         complete in the background against the per-stream windows).
-        ``donate`` marks that shard's source buffer donated — an
-        arena-backed sub-slice whose recycling is already gated on
-        transfer completion (and consumer GC on aliasing backends), so
-        the backend may consume it without a defensive host copy; the
-        caller must not donate a buffer shared by another shard of the
-        wave (replicated bounds). Raises :class:`DeviceStagerStopped`
-        when the stager is stopping mid-wave; re-raises the first
-        ``put_fn`` failure otherwise."""
+        Raises :class:`DeviceStagerStopped` when the stager is stopping
+        mid-wave; re-raises the first ``put_fn`` failure otherwise."""
         if not self._started:
             self.start()
         results = [None] * len(items)
         state = {'remaining': len(items), 'error': None}
         done = threading.Event()
         lock = threading.Lock()
-        for slot, (stream, array, donate) in enumerate(items):
-            self._enqueue(stream, (array, bool(donate), slot, results,
-                                   state, lock, done))
+        for slot, (stream, array) in enumerate(items):
+            self._enqueue(stream, (array, slot, results, state, lock, done))
         while not done.is_set():
             if self._stop.is_set():
                 raise DeviceStagerStopped(
-                    'device stager stopping mid-wave ({} shard(s) '
+                    'device stager stopping mid-wave ({} item(s) '
                     'outstanding)'.format(state['remaining']))
             done.wait(0.1)
         if state['error'] is not None:
@@ -914,7 +902,6 @@ class DeviceStager(object):
     def _stream_loop(self, index):
         window = deque()    # (staged, nbytes) — owned by this thread only
         q = self._queues[index]
-        key = self._keys[index]
         try:
             while True:
                 try:
@@ -928,7 +915,7 @@ class DeviceStager(object):
                         if not self._retire_oldest(window, block=False):
                             break
                     continue
-                array, donate, slot, results, state, lock, done = item
+                array, slot, results, state, lock, done = item
                 try:
                     # Fence pipelining: make room at SUBMIT time, not
                     # after delivery. The window only gives up its oldest
@@ -940,27 +927,12 @@ class DeviceStager(object):
                     # this wave.
                     while len(window) >= self._inflight:
                         self._retire_oldest(window, block=True)
-                    # A wave item may account itself (the streamed
-                    # batched-put tier calls record_inline_wave with the
-                    # true per-device breakdown from inside put_fn); the
-                    # stream then only does window/byte bookkeeping.
-                    self_acct = bool(getattr(array, 'pst_self_accounting',
-                                             False))
-                    t0 = time.perf_counter()
-                    staged = self._put_fn(array, index, donate)
-                    dt = time.perf_counter() - t0
+                    # The item accounts itself (put_fn calls
+                    # record_inline_wave with the wave's true per-device
+                    # breakdown); the stream keeps the window's books.
+                    staged = self._put_fn(array)
                     nbytes = int(getattr(array, 'nbytes', 0))
-                    if not self_acct:
-                        self._m_put.labels(key).observe(dt)
-                        if donate:
-                            self._m_donated.inc()
                     with self._stats_lock:
-                        if not self_acct:
-                            self._put_s[key] += dt
-                            self._put_bytes[key] += nbytes
-                            self._shards_put += 1
-                            if donate:
-                                self._donated += 1
                         self._window_bytes += nbytes
                     window.append((staged, nbytes))
                     self._h2d_enter()
@@ -1111,9 +1083,9 @@ class DeviceStager(object):
                 'device_put_bytes': dict(self._put_bytes),
                 'leaked_threads': list(self._leaked_threads)}
         if overlap is not None:
-            # The streamed-path measurement the bench's one-shot probe
-            # cannot see: 'h2d' (any transfer unfenced in a window) vs
-            # 'host' (the owner's staging work) co-activity.
+            # The streamed path's overlap: 'h2d' (any transfer unfenced
+            # in a window) vs 'host' (the owner's staging work)
+            # co-activity.
             out['h2d_overlap'] = overlap
             out['h2d_overlap_frac'] = overlap['overlap_frac']
         return out

@@ -12,8 +12,7 @@ def drain_queue(bounded_queue, buffer, max_items):
     consumer-local ``buffer`` (deque) under ONE mutex acquisition — the
     batched-pop primitive behind the worker pool's result handoff
     (``ThreadPool._pop_result``; a per-item ``Queue.get`` costs a lock
-    round trip each, and the warm-cache chunk rate is queue-pop bound,
-    PROFILE_r05 §2). The cap matters: every drained slot is capacity the
+    round trip each). The cap matters: every drained slot is capacity the
     producers refill, so callers size it to bound how far undelivered
     items may overshoot the queue's nominal depth. Producers blocked on
     the bounded put are woken for the freed capacity. Returns the number
@@ -50,8 +49,8 @@ def cached_namedtuple(cache, type_name, names):
 
 def enable_compile_cache():
     """Turn on jax's persistent compilation cache for this process and
-    return its directory. Entry points (``chip_smoke.py``, the bench
-    children, the examples, ``__graft_entry__``) call this before their
+    return its directory. Entry points (``chip_smoke.py``, ``perfbench``'s
+    harness, the examples, ``__graft_entry__``) call this before their
     first jit; library modules never set global jax config.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and

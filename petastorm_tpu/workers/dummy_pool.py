@@ -16,8 +16,8 @@ from petastorm_tpu.workers import (EmptyResultError, RowGroupQuarantined,
 class DummyPool(object):
     #: Readers build the ventilator with ``inline=True`` for this pool: work
     #: happens on the consumer thread, so a feeder thread (and its GIL
-    #: ping-pong — ~50% of the 1-core per-row path, PROFILE_r04.md) would
-    #: be pure overhead. ``get_results`` pumps the ventilator itself.
+    #: ping-pong with the consumer) would be pure overhead. ``get_results``
+    #: pumps the ventilator itself.
     inline_ventilation = True
 
     def __init__(self, workers_count=None):

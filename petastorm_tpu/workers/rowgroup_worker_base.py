@@ -168,8 +168,7 @@ class RowGroupWorkerBase(WorkerBase):
         if urlparse(self._store.url).scheme == 'file':
             # Local store: hand pyarrow the OS path so reads run on its
             # native (memory-mapped) IO instead of round-tripping every
-            # buffer through a Python fsspec file object — measured ~6% of
-            # the per-row hot path (round-4 profile, PROFILE_r04.md).
+            # buffer through a Python fsspec file object.
             pf = pq.ParquetFile(path, memory_map=True)
         else:
             pf = pq.ParquetFile(self._store.open_file(path))

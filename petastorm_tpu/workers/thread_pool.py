@@ -136,9 +136,8 @@ class ThreadPool(object):
         self._worker_args = None
         # Consumer-local drain buffer: get_results() moves every already-
         # ready result here under ONE queue-mutex acquisition instead of
-        # paying a lock round trip per pop (the warm-cache chunk rate is
-        # queue-pop bound — PROFILE_r05 §2). Touched only by the consumer
-        # thread.
+        # paying a lock round trip per pop (warm from a cache, a chunk
+        # costs little but its pop). Touched only by the consumer thread.
         self._pending_results = deque()
         #: Ventilator backpressure watermark: when set, the ventilator
         #: stops feeding new row-groups while the results queue holds this

@@ -91,10 +91,10 @@ class ConcurrentVentilator(Ventilator):
             chunks for the consumer-side resequencer.
         :param inline: no ventilation thread — the consumer drives
             ventilation by calling :meth:`pump` (synchronous pools). A
-            ventilator thread next to an inline pool is pure overhead: on a
-            single-core host the GIL ping-pong between the feeder thread
-            and the consumer measured ~50% of the whole per-row read path
-            (round-4 profile, PROFILE_r04.md).
+            ventilator thread next to an inline pool is pure overhead: the
+            feeder thread and the consumer only hand the GIL back and
+            forth (no chip record bears on it; the pool does its work on
+            the consumer's thread either way).
         """
         if iterations is not None and iterations <= 0:
             raise ValueError('iterations must be positive or None, got {}'.format(iterations))

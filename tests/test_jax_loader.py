@@ -132,38 +132,43 @@ def test_jax_loader_mesh_sharded(synthetic_dataset):
     assert batch.matrix.addressable_shards[0].data.shape == (2, 4, 5)
 
 
-def test_jax_loader_stage_chunks_parity(synthetic_dataset, monkeypatch):
-    """stage_chunks splits large fields into several puts + an on-device
-    concat: delivered batches must be
-    bitwise identical to one-shot staging, small fields stay one-shot, and
-    multi-device shardings chunk per device through the per-device
-    sharded path (the old fall-back-to-one-shot restriction is gone —
-    tests/test_multichip_staging.py covers its parity)."""
+@pytest.mark.parametrize('n_devices', [1, 8], ids=['mesh1', 'mesh8'])
+def test_large_field_parity(synthetic_dataset, n_devices):
+    """A field above the stream threshold (``device_stream_min_bytes=100``
+    on this tiny fixture: 'matrix' shards are 160 B on eight devices) and
+    one below it ('id', 64 B on one) arrive bitwise identical to the
+    host's batches, on one device and sharded 2 rows a device over eight."""
     import jax
     from jax.sharding import Mesh
 
-    import petastorm_tpu.jax_loader as jl
-    monkeypatch.setattr(jl, '_STAGE_CHUNK_MIN_BYTES', 64)  # tiny fixture data
-    mesh1 = Mesh(np.array(jax.devices()[:1]), ('data',))
-    runs = []
-    for k in (1, 4):
-        with _row_reader(synthetic_dataset.url,
-                         schema_fields=['id', 'matrix']) as reader:
-            with JaxLoader(reader, 16, mesh=mesh1, stage_chunks=k) as loader:
-                runs.append([(np.asarray(b.id), np.asarray(b.matrix))
-                             for b in loader])
-    assert len(runs[0]) == len(runs[1]) > 0
-    for (id1, m1), (idk, mk) in zip(*runs):
-        np.testing.assert_array_equal(id1, idk)
-        np.testing.assert_array_equal(m1, mk)
-    # Multi-device mesh: each device's shard chunks on its own stream;
-    # shards stay correct.
-    mesh8 = make_mesh({'data': 8})
-    with _row_reader(synthetic_dataset.url, schema_fields=['matrix']) as reader:
-        with JaxLoader(reader, 16, mesh=mesh8, stage_chunks=4) as loader:
-            batch = next(loader)
-            assert loader.stats['n_devices'] == 8
-    assert batch.matrix.addressable_shards[0].data.shape == (2, 4, 5)
+    mesh = Mesh(np.array(jax.devices()[:n_devices]), ('data',))
+    fields = ['id', 'matrix']
+    with _row_reader(synthetic_dataset.url, schema_fields=fields) as reader:
+        ref = list(iter_numpy_batches(reader, 16))
+    with _row_reader(synthetic_dataset.url, schema_fields=fields) as reader:
+        with JaxLoader(reader, 16, mesh=mesh,
+                       device_stream_min_bytes=100) as loader:
+            got = list(loader)
+            stats = loader.stats
+    assert len(got) == len(ref) > 0
+    for batch, host in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(batch.id), host['id'])
+        np.testing.assert_array_equal(np.asarray(batch.matrix),
+                                      host['matrix'])
+        assert batch.matrix.addressable_shards[0].data.shape \
+            == (16 // n_devices, 4, 5)
+    assert stats['n_devices'] == n_devices
+    assert stats['stage_tiers'] == {'inline-batched': len(got),
+                                    'streamed-batched': len(got)}
+
+
+@pytest.mark.parametrize('option', ['stage_chunks', 'per_device_dispatch'])
+def test_removed_staging_options_are_refused(synthetic_dataset, option):
+    """The two options that chose a staging tier by hand are gone: a
+    caller that still passes one is told so, not silently ignored."""
+    with _row_reader(synthetic_dataset.url, schema_fields=['id']) as reader:
+        with pytest.raises(TypeError, match=option):
+            JaxLoader(reader, 8, **{option: 1})
 
 
 def test_jax_loader_full_epoch_on_mesh(synthetic_dataset):

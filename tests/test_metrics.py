@@ -381,7 +381,7 @@ def test_flight_recorder_dump_on_injected_stall(synthetic_dataset, tmp_path,
 # Non-metric pst_* literals the source scanner must ignore: native module
 # names and the deterministic-mode item/chunk tag key (workers/ventilator).
 _NON_METRIC_PST_LITERALS = {'pst_image', 'pst_parquet', 'pst_shm_ring',
-                            'pst_det', 'pst_pinned', 'pst_self_accounting',
+                            'pst_det', 'pst_pinned',
                             # prefix filter in tools/fleet.py --status, not
                             # an instrument name
                             'pst_fleet_tenant_',
@@ -402,7 +402,6 @@ def _source_metric_names():
 
     root = os.path.dirname(petastorm_tpu.__file__)
     paths = glob.glob(os.path.join(root, '**', '*.py'), recursive=True)
-    paths.append(os.path.join(root, os.pardir, 'bench.py'))
     names = set()
     for path in paths:
         with open(path) as f:

@@ -21,7 +21,7 @@ import pytest
 from petastorm_tpu import make_pod_reader, make_tensor_reader
 from petastorm_tpu.codecs import NdarrayCodec, ScalarCodec
 from petastorm_tpu.etl.writer import write_dataset
-from petastorm_tpu.jax_loader import JaxLoader
+from petastorm_tpu.jax_loader import JaxLoader, iter_numpy_batches
 from petastorm_tpu.parallel import make_mesh
 from petastorm_tpu.parallel.mesh import device_shard_plan
 from petastorm_tpu.unischema import Unischema, UnischemaField
@@ -107,14 +107,27 @@ def test_shard_plan_replicated_sharding():
 # per-device dispatch: engagement, parity, fallbacks
 # ---------------------------------------------------------------------------
 
-def _collect(url, per_device=None, mesh=None, batch=16, **loader_kw):
-    mesh = mesh if mesh is not None else make_mesh({'data': 8})
+def _collect(url, batch=16, **loader_kw):
     with _reader(url) as reader:
-        with JaxLoader(reader, batch, mesh=mesh,
-                       per_device_dispatch=per_device, **loader_kw) as loader:
+        with JaxLoader(reader, batch, mesh=make_mesh({'data': 8}),
+                       **loader_kw) as loader:
             batches = [(np.asarray(b.id), np.asarray(b.vec)) for b in loader]
             stats = loader.stats
     return batches, stats
+
+
+def _host_batches(url, batch=16):
+    """The reference: the same rows collated on the host by a second
+    reader, touching no device path."""
+    with _reader(url) as reader:
+        return [(b['id'], b['vec']) for b in iter_numpy_batches(reader, batch)]
+
+
+def _assert_same_batches(got, ref):
+    assert len(got) == len(ref) > 0
+    for (gid, gvec), (rid, rvec) in zip(got, ref):
+        np.testing.assert_array_equal(gid, rid)
+        np.testing.assert_array_equal(gvec, rvec)
 
 
 def test_per_device_path_dispatches_global_arrays(mc_dataset):
@@ -133,15 +146,36 @@ def test_per_device_path_dispatches_global_arrays(mc_dataset):
     assert stats['shards_put'] >= 8
 
 
-def test_per_device_matches_one_shot_bit_identical(mc_dataset):
-    fast, fast_stats = _collect(mc_dataset.url, per_device=None)
-    ref, ref_stats = _collect(mc_dataset.url, per_device=False)
+def test_per_device_matches_host_batches_bit_identical(mc_dataset):
+    fast, fast_stats = _collect(mc_dataset.url)
     assert fast_stats['n_devices'] == 8
-    assert 'n_devices' not in ref_stats
-    assert len(fast) == len(ref) == ROWS // 16
-    for (fid, fvec), (rid, rvec) in zip(fast, ref):
-        np.testing.assert_array_equal(fid, rid)
-        np.testing.assert_array_equal(fvec, rvec)
+    assert len(fast) == ROWS // 16
+    _assert_same_batches(fast, _host_batches(mc_dataset.url))
+
+
+# One 'vec' shard of a 16-row batch on eight devices: 2 rows x 6 float32.
+_VEC_SHARD_BYTES = 2 * 6 * 4
+
+
+@pytest.mark.parametrize('min_bytes, fields_a_batch', [
+    (_VEC_SHARD_BYTES + 1, {'inline-batched': 2}),
+    (_VEC_SHARD_BYTES, {'inline-batched': 1, 'streamed-batched': 1}),
+    (_VEC_SHARD_BYTES - 1, {'inline-batched': 1, 'streamed-batched': 1}),
+], ids=['below', 'at', 'above'])
+def test_tier_follows_shard_bytes(mc_dataset, min_bytes, fields_a_batch):
+    """The one staging decision of a planned field: its shard's bytes
+    against ``device_stream_min_bytes``, below / at / above. 'id' (8-byte
+    shards) stays inline throughout, so it is 'vec' that moves."""
+    from petastorm_tpu.trace import Tracer
+    tracer = Tracer()
+    got, stats = _collect(mc_dataset.url, device_stream_min_bytes=min_bytes,
+                          tracer=tracer)
+    assert stats['stage_tiers'] == {tier: k * len(got)
+                                    for tier, k in fields_a_batch.items()}
+    causes = [r[7] for r in tracer.records()
+              if r[0] == 'dispatch.stage' and len(r) == 8]
+    assert causes == [sorted(fields_a_batch)] * len(got)
+    _assert_same_batches(got, _host_batches(mc_dataset.url))
 
 
 def test_stream_tier_forced_and_threads_join(mc_dataset):
@@ -158,10 +192,7 @@ def test_stream_tier_forced_and_threads_join(mc_dataset):
             names = {t.name for t in threading.enumerate()}
             assert any(n.startswith('pst-device-put-') for n in names)
             stats = loader.stats
-    ref, _ = _collect(mc_dataset.url, per_device=False)
-    for (fid, fvec), (rid, rvec) in zip(batches, ref):
-        np.testing.assert_array_equal(fid, rid)
-        np.testing.assert_array_equal(fvec, rvec)
+    _assert_same_batches(batches, _host_batches(mc_dataset.url))
     assert stats['shards_put'] >= 8
     assert stats['device_inflight'] == 1
     assert not any(t.name.startswith('pst-device-put-')
@@ -170,8 +201,7 @@ def test_stream_tier_forced_and_threads_join(mc_dataset):
 
 def test_streamed_overlap_reported_in_stats(mc_dataset):
     """The stager's OverlapMeter surfaces the streamed-path h2d/host
-    co-activity in ``loader.stats`` — the bench's one-shot probe
-    structurally reported 0.0 here (ISSUE 17 satellite)."""
+    co-activity in ``loader.stats``."""
     mesh = make_mesh({'data': 8})
     with _reader(mc_dataset.url) as reader:
         with JaxLoader(reader, 16, mesh=mesh,
@@ -224,21 +254,6 @@ def test_sequence_sharded_field_falls_back_per_field(mc_dataset):
     # Only 'id' is per-device-planned (4 distinct shards x 2 replicas);
     # 'vec' shards a non-batch dim and must not be counted.
     assert stats['shards_put'] == len(batches) * 8
-
-
-def test_chunked_multi_device_parity(mc_dataset, monkeypatch):
-    """stage_chunks > 1 now rides the per-device path (each device's
-    shard splits on its own stream) instead of falling back to one-shot —
-    the old single-device-sharding restriction is gone."""
-    import petastorm_tpu.jax_loader as jl
-    monkeypatch.setattr(jl, '_STAGE_CHUNK_MIN_BYTES', 64)
-    fast, stats = _collect(mc_dataset.url, per_device=None, stage_chunks=2,
-                           device_stream_min_bytes=0)
-    ref, _ = _collect(mc_dataset.url, per_device=False)
-    for (fid, fvec), (rid, rvec) in zip(fast, ref):
-        np.testing.assert_array_equal(fid, rid)
-        np.testing.assert_array_equal(fvec, rvec)
-    assert stats['n_devices'] == 8
 
 
 # ---------------------------------------------------------------------------

@@ -539,7 +539,7 @@ class _FakeStaged(object):
 def _fence_stager(inflight, fences, staged_out, put_hook=None):
     from petastorm_tpu.staging import DeviceStager
 
-    def put_fn(array, stream, donate):
+    def put_fn(array):
         if put_hook is not None:
             put_hook()
         staged = _FakeStaged(array.tag)
@@ -559,7 +559,7 @@ def test_fence_pipelining_window_never_drains():
     st = _fence_stager(2, fences, staged)
     try:
         for i in range(5):
-            st.put_shards([(0, _FakeShard('s{}'.format(i)), False)])
+            st.put_shards([(0, _FakeShard('s{}'.format(i)))])
             if i >= 1:
                 # Not a drained stream: both slots in flight between waves.
                 assert st.window_nbytes == 2 * _FakeShard.nbytes
@@ -592,13 +592,13 @@ def test_four_streams_fencing_at_once_lose_no_wait_seconds():
     from petastorm_tpu.trace import Tracer
     tracer = Tracer()
     st = DeviceStager(['d0', 'd1', 'd2', 'd3'],
-                      lambda array, stream, donate: _FakeStaged(array.tag),
+                      lambda array: _FakeStaged(array.tag),
                       inflight=1, ready_fn=lambda staged: None, tracer=tracer)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-6)
     try:
         for i in range(300):
-            st.put_shards([(d, _FakeShard('s{}.{}'.format(i, d)), False)
+            st.put_shards([(d, _FakeShard('s{}.{}'.format(i, d)))
                            for d in range(4)])
         waited = st.stats()['device_ready_wait_s']
         assert st.ready_wait_seconds == pytest.approx(waited, abs=1e-4)
@@ -624,7 +624,7 @@ def test_fence_pipelining_under_device_put_delay(monkeypatch):
                            'device-put-delay'))
     try:
         for i in range(4):
-            st.put_shards([(0, _FakeShard('s{}'.format(i)), False)])
+            st.put_shards([(0, _FakeShard('s{}'.format(i)))])
             assert st.window_nbytes == _FakeShard.nbytes
         assert fences == ['s0', 's1', 's2']
     finally:
@@ -639,7 +639,7 @@ def test_stager_stop_reclaims_inflight_window_without_fencing():
     fences, staged = [], []
     st = _fence_stager(4, fences, staged)
     for i in range(3):
-        st.put_shards([(0, _FakeShard('s{}'.format(i)), False)])
+        st.put_shards([(0, _FakeShard('s{}'.format(i)))])
     assert st.window_nbytes == 3 * _FakeShard.nbytes
     assert st.stop() == []                     # joined; nothing leaked
     assert st.window_nbytes == 0

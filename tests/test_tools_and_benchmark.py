@@ -140,106 +140,3 @@ def test_benchmark_tf_read_path(synthetic_dataset):
         warmup_cycles_count=5, measure_cycles_count=20,
         pool_type='dummy', read_method='tf')
     assert result.samples_per_second > 0
-
-
-def _import_bench(monkeypatch):
-    """bench.py lives at the repo root, not in the package."""
-    import importlib
-    import os
-
-    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    return importlib.import_module('bench')
-
-
-def test_bench_headline_metric_name_tracks_basis(monkeypatch):
-    """Headline hygiene: the HBM-resident basis must carry a DISTINCT
-    metric name (``..._sustained``) plus a ``headline_config`` key, so
-    cross-round diffs can never silently mix bases."""
-    bench = _import_bench(monkeypatch)
-
-    streamed = {'imagenet_img_per_sec_per_chip': 400.0, 'mfu': 0.02,
-                'input_stall_frac': 0.3, 'platform': 'tpu'}
-    result = {}
-    bench._set_headline(result, streamed)
-    assert result['metric'] == 'imagenet_resnet50_img_per_sec_per_chip'
-    assert result['headline_config'] == 'streamed_from_host'
-
-    hbm = dict(streamed, imagenet_hbm_cached_img_per_sec_per_chip=2615.6,
-               hbm_cached_mfu=0.163, h2d_chunked_GBps=0.044)
-    result = {}
-    bench._set_headline(result, hbm)
-    assert result['metric'] == \
-        'imagenet_resnet50_img_per_sec_per_chip_sustained'
-    assert result['headline_config'] == 'hbm_resident'
-    assert result['value'] == 2615.6
-
-
-def test_bench_headline_picks_best_sustained_config(monkeypatch, capsys):
-    """When the imagenet child measured an HBM-resident steady state faster
-    than the streamed rate, the headline uses it — with basis, zero stall
-    (no input pipeline during measured epochs), and the HBM config's own
-    MFU — while the streamed numbers stay in the JSON, and the summary
-    line (the LAST stdout line) carries the same run's device, mfu, stall
-    and basis."""
-    import json
-
-    bench = _import_bench(monkeypatch)
-    inet_hbm = {'imagenet_img_per_sec_per_chip': 170.0, 'mfu': 0.01,
-                'input_stall_frac': 0.46, 'platform': 'tpu',
-                'h2d_chunked_GBps': 0.044,
-                'imagenet_hbm_cached_img_per_sec_per_chip': 2615.6,
-                'hbm_cached_mfu': 0.163}
-    rate, basis, mfu, stall = bench._sustained_best(inet_hbm)
-    assert rate == 2615.6 and mfu == 0.163 and stall == 0.0
-    assert basis.startswith('hbm_resident_steady_state')
-
-    device = {'platform': 'tpu', 'kind': 'TPU v5 lite', 'count': 1}
-    result = {'device': device, 'metric': 'hello_world_samples_per_sec',
-              'value': 2900.0, 'unit': 'samples/s', 'vs_baseline': 4.1}
-    result.update(inet_hbm)
-    bench._set_headline(result, inet_hbm)
-    bench._print_result(result)
-    out = capsys.readouterr().out.strip().splitlines()
-    full = json.loads(out[0])
-    assert full['value'] == 2615.6
-    assert full['vs_baseline'] == round(2615.6 / 2000.0, 3)
-    assert full['headline_basis'].startswith('hbm_resident_steady_state')
-    assert full['imagenet_img_per_sec_per_chip'] == 170.0
-    assert full['headline_streamed_img_per_sec_per_chip'] == 170.0
-    assert full['headline_streamed_vs_baseline'] == round(170.0 / 2000.0, 3)
-    assert out[-1].startswith('BENCH_SUMMARY ')
-    summary = json.loads(out[-1][len('BENCH_SUMMARY '):])
-    assert summary['value'] == 2615.6
-    assert summary['device'] == device
-    assert summary['mfu'] == 0.163
-    assert summary['input_stall_frac'] == 0.0
-    assert summary['platform'] == 'tpu'
-    assert summary['basis'] == 'hbm_resident_steady_state'
-
-
-@pytest.mark.slow
-def test_bench_lm_child_smoke(tmp_path):
-    """The lm bench child runs end to end (toy config, CPU): token Parquet
-    store -> tensor reader -> JaxLoader -> scanned TransformerLM steps."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env.update({'JAX_PLATFORMS': 'cpu', 'BENCH_LM_VOCAB': '256',
-                'BENCH_LM_DMODEL': '32', 'BENCH_LM_LAYERS': '1',
-                'BENCH_LM_HEADS': '2', 'BENCH_LM_BATCH': '1',
-                'BENCH_LM_SCAN_K': '2', 'BENCH_LM_STEPS': '2'})
-    proc = subprocess.run(
-        [sys.executable, os.path.join(repo, 'bench.py'), '--_child', 'lm', '2'],
-        capture_output=True, text=True, timeout=600, env=env)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert out['lm_tokens_per_sec_per_chip'] > 0
-    assert out['platform'] == 'cpu'
-    # the same Pallas kernel as on the chip, named as the interpreter here
-    assert out['lm_config']['attention'] == 'flash:interpret'
-    assert out['lm_final_loss'] > 0
