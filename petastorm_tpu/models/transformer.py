@@ -3,9 +3,11 @@
 The long-context flagship: the same flax module runs with
 
 * ``attention='dense'`` — reference XLA attention (small inputs, tests),
-* ``attention='flash'`` — the Pallas blocked kernel
+* ``attention='flash'`` — the Pallas blocked kernels
   (:mod:`petastorm_tpu.ops.flash_attention`), no ``[T, T]`` materialization;
-  compiled, so it needs a TPU,
+  they read and write the projections' own ``[B, T, H*D]`` arrays, heads by
+  128-lane blocks (:class:`FlatDenseGeneral` writes them so); compiled, so
+  it needs a TPU,
 * ``attention='ring'`` — sequence parallelism: q/k/v sharded over a mesh
   axis, kv blocks rotating over ICI
   (:mod:`petastorm_tpu.models.attention`), for contexts longer than one
@@ -24,6 +26,7 @@ only thing that changes between single-chip and pod runs — the module code
 is identical (mesh + shardings, XLA inserts the collectives).
 """
 
+import math
 from functools import partial
 from typing import Any, Optional
 
@@ -39,6 +42,44 @@ def usable_axis(mesh, axis, dim):
     falls back to replication for that trace alone."""
     return (axis if axis in mesh.axis_names and dim % mesh.shape[axis] == 0
             else None)
+
+
+class FlatDenseGeneral(nn.Module):
+    """``nn.DenseGeneral(features, axis=<the last dimensions the kernel
+    covers>)`` with the same parameters (names, shapes, initial values) and
+    the same values out, computed as one product of 2-D shape with the bias
+    added before the result takes its head dimensions: ``[..., K] x [K, N]
+    + [N]``, then reshaped. What differs is the program. XLA gives a dot or
+    a bias sum whose result is ``[B, T, H, 64]`` a layout with T minor and
+    copies every such array on its way to and from a kernel that reads
+    ``[B, T, H * 64]`` rows, eight 25 MB copies an attention layer at
+    GPT-2's shape; a ``[B, T, H * 64]`` result is written as the flash
+    kernels read it, and their reshape is a bitcast."""
+    features: Any                       # int or tuple: the output's last dims
+    contract: int = 1                   # how many last dims of x are contracted
+    use_bias: bool = True
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        features = (tuple(self.features) if isinstance(self.features, tuple)
+                    else (self.features,))
+        free, contracted = (x.shape[:x.ndim - self.contract],
+                            x.shape[x.ndim - self.contract:])
+        flat = (math.prod(contracted), math.prod(features))
+
+        def kernel_init(rng, shape, dtype=jnp.float32):
+            return nn.linear.default_kernel_init(rng, flat, dtype).reshape(shape)
+
+        kernel = self.param('kernel', kernel_init, contracted + features)
+        bias = (self.param('bias', nn.initializers.zeros_init(), features)
+                if self.use_bias else None)
+        x, kernel, bias = nn.dtypes.promote_dtype(x, kernel, bias,
+                                                  dtype=self.dtype)
+        out = x.reshape(free + flat[:1]) @ kernel.reshape(flat)
+        if bias is not None:
+            out += bias.reshape(flat[1:])
+        return out.reshape(free + features)
 
 
 def self_attention(q, k, v, attention='dense', causal=True, mesh=None,
@@ -110,8 +151,8 @@ class MultiHeadAttention(nn.Module):
         head_dim = d_model // self.num_heads
 
         def proj(name):
-            return nn.DenseGeneral((self.num_heads, head_dim), axis=-1,
-                                   dtype=self.dtype, name=name)(x)
+            return FlatDenseGeneral((self.num_heads, head_dim),
+                                    dtype=self.dtype, name=name)(x)
 
         q, k, v = proj('query'), proj('key'), proj('value')   # [B, T, H, Dh]
 
@@ -122,8 +163,8 @@ class MultiHeadAttention(nn.Module):
                              head_axis=self.head_axis)
 
         out = out.astype(self.dtype)
-        return nn.DenseGeneral(d_model, axis=(-2, -1), dtype=self.dtype,
-                               name='out')(out)
+        return FlatDenseGeneral(d_model, contract=2, dtype=self.dtype,
+                                name='out')(out)
 
 
 class Block(nn.Module):

@@ -1,17 +1,36 @@
 """Blocked (flash) attention as Pallas TPU kernels (forward + backward).
 
 Single-device exact attention without materializing the ``[T, T]`` score
-matrix: a 3-D grid ``(batch*heads, q_blocks, kv_blocks)`` streams one
-``[block_q, d]`` query block and one ``[block_k, d]`` kv block into VMEM per
-step — VMEM use is O(block) regardless of sequence length, so context is
-bounded by HBM, not VMEM. The online softmax (running max / normalizer)
-lives in VMEM scratch that persists across the kv-block axis (TPU grids
-execute sequentially, innermost axis fastest), and every matmul runs on the
-MXU. The backward is two more Pallas passes (dq over kv blocks; dk+dv over
-q blocks) that reconstruct ``P = exp(S - lse)`` tile by tile from the
+matrix: a 4-D grid ``(batch, lane blocks, q_blocks, kv_blocks)`` streams one
+``[block_q, lanes]`` query block and one ``[block_k, lanes]`` kv block into
+VMEM per step — VMEM use is O(block) regardless of sequence length, so
+context is bounded by HBM, not VMEM. The online softmax (running max /
+normalizer) lives in VMEM scratch that persists across the kv-block axis (TPU
+grids execute sequentially, innermost axis fastest), and every matmul runs on
+the MXU. The backward is two more Pallas passes (dq over kv blocks; dk+dv
+over q blocks) that reconstruct ``P = exp(S - lse)`` tile by tile from the
 logsumexp rows the training forward saves — O(block) memory in both
 directions. Role parity: the attention compute the reference's training
 stacks get from fused CUDA kernels — rebuilt the TPU way.
+
+**The arrays are the model's own.** q, k, v, the output, ``dO`` and the three
+gradients are ``[B, T, H*D]``, which a ``[B, T, H, D]`` array is by a bitcast:
+what a projection wrote is what a kernel reads, and no transpose, row sum or
+broadcast stands beside the three calls (a ``[B*H, T, D]`` kernel costs eight
+layout copies of a q-sized array a layer). A *lane block* (:func:`lane_plan`)
+is 128 lanes of ``H*D``: two 64-wide heads, or one of 128 (a wider head has
+a block of its width). The
+heads of a block are computed one after another over the same bands; a
+head's lanes are chosen by a lane mask, not a transpose (:func:`_head_of`:
+q and dO with the other heads' lanes zeroed contract over all 128 lanes in
+the passes a 64-deep contraction takes on a 128 x 128 MXU; of a ``[rows,
+128]`` result the head's lanes are kept by a select, :func:`_by_head`), and
+with one head a block no mask is traced at all. ``lse`` is float32 ``[B, T,
+H*D]``, a head's value in each of its lanes, and ``D = rowsum(dO * O)`` is
+taken inside both backward kernels from the ``dO`` and output blocks. What
+does not fill a lane block (an odd head, ``H*D < 128``, a width like 96) is
+padded with zero heads or lanes by the wrapper and stripped: one kernel
+family, nothing to choose.
 
 **Two tile sizes** (:func:`tile_plan`, the one place they are decided). The
 *DMA block* ``(block_q, block_k)`` is what one grid step holds in VMEM; it
@@ -57,7 +76,7 @@ from petastorm_tpu.trace import get_global_tracer
 
 NEG_INF = -1e30  # large-finite: -inf breaks the running-max rescale at init
 
-_LANES = 128     # VPU lane width: in-kernel scratch vectors are lane-broadcast
+_LANES = 128     # VPU lane width: the lane block of heads no wider than it
 
 #: Compute sub-tile ``(rows, columns)`` of each pass, clamped to the DMA
 #: block. Chosen on a v5e at ``[192, 1024, 64]`` bf16 causal and confirmed at
@@ -72,15 +91,15 @@ _SUB_TILES = {'fwd': (128, 128), 'dq': (128, 128), 'dkv': (128, 256)}
 def _mosaic_params(interpret):
     """Compiler hints for the compiled path: all three kernels carry their
     online-softmax / accumulator state only along the LAST grid axis, so the
-    first two axes (batch*heads, outer block) are declared parallel —
-    Mosaic may then reorder/pipeline them freely. Interpret mode takes no
+    first three axes (batch, lane block, outer block) are declared parallel
+    — Mosaic may then reorder/pipeline them freely. Interpret mode takes no
     TPU compiler params."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
 
     return {'compiler_params': pltpu.CompilerParams(
-        dimension_semantics=('parallel', 'parallel', 'arbitrary'))}
+        dimension_semantics=('parallel', 'parallel', 'parallel', 'arbitrary'))}
 
 
 def _out_struct(shape, dtype, like):
@@ -91,13 +110,49 @@ def _out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _to_bhtd(x, t_pad):
-    """[B, T, H, D] -> padded [B*H, T_pad, D]."""
+# --------------------------------------------------------------------------
+# the lane plan: how heads lie in the 128-lane blocks of a [B, T, H*D] array
+# --------------------------------------------------------------------------
+
+def lane_plan(h, d):
+    """How ``h`` heads of width ``d`` fill the lane blocks the kernels read,
+    from what the call can see. A head no wider than a vreg is padded to the
+    next power of two (which divides 128) and ``128 // width`` heads share a
+    128-lane block; a wider one is padded to the next multiple of 128 and
+    has a block of its own. Heads are padded up to whole blocks. At GPT-2's
+    ``(12, 64)`` two heads a block and no padding; at ``(15, 128)`` one.
+
+    JSON-safe: ``lane_block`` (the block's lanes), ``heads_per_block``,
+    ``pad_heads`` (zero heads appended) and ``pad_lanes`` (zero lanes
+    appended to every head)."""
+    if d <= _LANES:
+        width = 1 << (d - 1).bit_length()
+        lane_block = _LANES
+    else:
+        width = lane_block = -(-d // _LANES) * _LANES
+    per_block = lane_block // width
+    return {'lane_block': lane_block, 'heads_per_block': per_block,
+            'pad_heads': -h % per_block, 'pad_lanes': width - d}
+
+
+def _to_lanes(x, plan):
+    """``[B, T, H, D]`` -> ``[B, T_pad, lanes]``: a bitcast where nothing is
+    padded (the array a projection wrote is the array a kernel reads); else
+    zero rows, heads and lanes up to whole blocks."""
     b, t, h, d = x.shape
-    x = jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
-    if t_pad != t:
-        x = jnp.pad(x, ((0, 0), (0, t_pad - t), (0, 0)))
-    return x
+    pad = (plan['t_pad'] - t, plan['pad_heads'], plan['pad_lanes'])
+    if any(pad):
+        x = jnp.pad(x, ((0, 0),) + tuple((0, p) for p in pad))
+    return x.reshape(b, plan['t_pad'], -1)
+
+
+def _from_lanes(x, shape, plan):
+    """The inverse of :func:`_to_lanes`: ``[B, T_pad, lanes]`` -> ``shape``,
+    the padding stripped."""
+    b, t, h, d = shape
+    x = x.reshape(b, plan['t_pad'], h + plan['pad_heads'],
+                  d + plan['pad_lanes'])
+    return x[:, :t, :h, :d]
 
 
 def _pad_plan(t, block_q, block_k):
@@ -261,15 +316,15 @@ _plans_reported = set()
 
 
 def _plan_for(q, causal, block_q, block_k):
-    """The plan of a call on ``[B, T, H, D]`` operands; the first time a
-    process traces a kernel with it, one ``kernel.flash_plan`` instant on
-    the global tracer carries it (a model's layers share one plan, so one
-    record, not one a layer)."""
-    _, t, _, hd = q.shape
+    """The plan of a call on ``[B, T, H, D]`` operands: :func:`tile_plan`
+    with :func:`lane_plan`. The first time a process traces a kernel with
+    it, one ``kernel.flash_plan`` instant on the global tracer carries it (a
+    model's layers share one plan, so one record, not one a layer)."""
+    _, t, h, hd = q.shape
     key = (t, bool(causal), jnp.dtype(q.dtype).name, hd, block_q, block_k)
-    plan = tile_plan(*key)
-    if key not in _plans_reported:
-        _plans_reported.add(key)
+    plan = dict(tile_plan(*key), heads=h, dd='in-kernel', **lane_plan(h, hd))
+    if key + (h,) not in _plans_reported:
+        _plans_reported.add(key + (h,))
         get_global_tracer().instant('kernel.flash_plan', cat='kernel',
                                     args=plan)
     return plan
@@ -347,44 +402,87 @@ def _recompute_p(q, k_sub, lse, mask, scale):
 
 
 def _index_maps(block_q, block_k, seq_len, causal):
-    """``(q_map, kv_map)`` over grid ``(b, qi, ki)``: the q side follows
-    ``qi``; the kv side follows ``ki`` as far as the last block this q block
-    needs and stays there, so the steps that compute nothing fetch nothing
-    new."""
+    """``(q_map, kv_map)`` over grid ``(b, lane block, qi, ki)``: the q side
+    follows ``qi``; the kv side follows ``ki`` as far as the last block this
+    q block needs and stays there, so the steps that compute nothing fetch
+    nothing new."""
     last_real = (seq_len - 1) // block_k
 
-    def q_map(b, qi, ki):
-        return (b, qi, 0)
+    def q_map(b, j, qi, ki):
+        return (b, qi, j)
 
-    def kv_map(b, qi, ki):
+    def kv_map(b, j, qi, ki):
         last = last_real
         if causal:
             last = jnp.minimum(last, (qi * block_q + block_q - 1) // block_k)
-        return (b, jnp.minimum(ki, last), 0)
+        return (b, jnp.minimum(ki, last), j)
 
     return q_map, kv_map
 
 
 def _index_maps_dkv(block_q, block_k, causal):
-    """``(q_map, kv_map)`` over the dk/dv grid ``(b, ki, qi)``: the q side
-    starts at the first q block this kv block needs."""
-    def q_map(b, ki, qi):
+    """``(q_map, kv_map)`` over the dk/dv grid ``(b, lane block, ki, qi)``:
+    the q side starts at the first q block this kv block needs."""
+    def q_map(b, j, ki, qi):
         if causal:
             qi = jnp.maximum(qi, (ki * block_k) // block_q)
-        return (b, qi, 0)
+        return (b, qi, j)
 
-    def kv_map(b, ki, qi):
-        return (b, ki, 0)
+    def kv_map(b, j, ki, qi):
+        return (b, ki, j)
 
     return q_map, kv_map
 
 
-def _kernel_args(plan, name, d):
-    """The static arguments of pass ``name``'s kernel."""
+def _kernel_args(plan, name):
+    """The static arguments of pass ``name``'s kernel. ``heads`` is how the
+    heads of a lane block lie in it: how many, and how many lanes each."""
     tiling = (plan['block_q'], plan['block_k'],
               plan['passes'][name]['sub_q'], plan['passes'][name]['sub_k'])
+    per_block = plan['heads_per_block']
     return dict(tiling=tiling, seq_len=plan['t'], causal=plan['causal'],
-                cases=tuple(plan['cases']), scale=1.0 / math.sqrt(d))
+                cases=tuple(plan['cases']), scale=1.0 / math.sqrt(plan['hd']),
+                heads=(per_block, plan['lane_block'] // per_block))
+
+
+# --------------------------------------------------------------------------
+# the heads of a lane block
+# --------------------------------------------------------------------------
+
+def _head_of(x, head, heads):
+    """``[rows, lanes]`` ``x`` with the lanes of every head but ``head``
+    zeroed: as an operand it contracts over the whole lane block and gives
+    what the head's own lanes give (on a 128 x 128 MXU in the passes its
+    narrower slice would take); as the operand that is not contracted it
+    leaves the other heads' lanes of the product zero. With one head a
+    block it is ``x``: no mask is traced."""
+    count, width = heads
+    if count == 1:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    mine = (lane >= head * width) & (lane < (head + 1) * width)
+    return jnp.where(mine, x, jnp.zeros_like(x))
+
+
+def _by_head(parts, heads):
+    """``[rows, lanes]`` holding ``parts[h]`` (``[rows, 1]``, or ``[rows,
+    lanes]``) in the lanes of head ``h``: one select a head after the
+    first."""
+    count, width = heads
+    shape = (parts[0].shape[0], count * width)
+    out = jnp.broadcast_to(parts[0], shape)
+    if count > 1:
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        for head in range(1, count):
+            out = jnp.where(lane >= head * width, parts[head], out)
+    return out
+
+
+def _column(ref, at, head, heads):
+    """``[rows, 1]``: the value ``ref`` holds for ``head`` in rows ``at`` (a
+    head's statistic stands in each of its lanes)."""
+    lane = head * heads[1]
+    return ref[at, lane:lane + 1]
 
 
 # --------------------------------------------------------------------------
@@ -392,12 +490,14 @@ def _kernel_args(plan, name, d):
 # --------------------------------------------------------------------------
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
-    """One grid step: one (block_q, d) query block x one (block_k, d) kv
-    block, computed in the bands of :func:`_bands`.
+    """One grid step: one ``(block_q, lanes)`` query block x one ``(block_k,
+    lanes)`` kv block of one lane block, computed in the bands of
+    :func:`_bands`, the block's heads one after another in each band.
 
-    acc/m/l scratch persists across the kv axis (axis 2, innermost): init at
-    ki == 0, accumulate every step, normalize + store at the last ki. m/l
-    are lane-broadcast ``[block_q, _LANES]`` to respect TPU vector tiling.
+    acc/m/l scratch persists across the kv axis (axis 3, innermost): init at
+    ki == 0, accumulate every step, normalize + store at the last ki. All
+    three are ``[block_q, lanes]``; m and l hold a head's value in each of
+    that head's lanes, so the last step is elementwise.
     """
     import jax.experimental.pallas as pl
 
@@ -405,8 +505,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
         lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         lse_ref, (acc_ref, m_ref, l_ref) = None, rest
-    scale = args['scale']
-    ki = pl.program_id(2)
+    scale, heads = args['scale'], args['heads']
+    ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
@@ -418,28 +518,37 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
         for r0, rows, c_full, c_run in _bands(case, *args['tiling']):
             at, to = pl.ds(r0, rows), pl.ds(0, c_run)
             mask = _band_mask(case, r0, rows, c_full, c_run - c_full)
-            # Native-dtype operands, f32 accumulation.
-            s = _masked(_scores(q_ref[at, :], k_ref[to, :], scale), mask,
-                        NEG_INF)
-            m_prev = m_ref[at, 0:1]
-            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-            correction = jnp.exp(m_prev - m_new)
-            # A row with nothing unmasked so far has m_new == NEG_INF and
-            # p == 1 where it is masked: the second select zeroes it.
-            p = _masked(jnp.exp(s - m_new), mask, 0.0)
-            l_new = l_ref[at, 0:1] * correction + p.sum(axis=-1, keepdims=True)
-            v_sub = v_ref[to, :]
-            acc_ref[at, :] = acc_ref[at, :] * correction + jax.lax.dot_general(
-                p.astype(v_sub.dtype), v_sub, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[at, :] = jnp.broadcast_to(m_new, (rows, _LANES))
-            l_ref[at, :] = jnp.broadcast_to(l_new, (rows, _LANES))
+            q = q_ref[at, :]
+            corrections, m_news, l_news, pvs = [], [], [], []
+            for head in range(heads[0]):
+                # Native-dtype operands, f32 accumulation.
+                s = _masked(_scores(_head_of(q, head, heads), k_ref[to, :],
+                                    scale), mask, NEG_INF)
+                m_prev = _column(m_ref, at, head, heads)
+                m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+                correction = jnp.exp(m_prev - m_new)
+                # A row with nothing unmasked so far has m_new == NEG_INF and
+                # p == 1 where it is masked: the second select zeroes it.
+                p = _masked(jnp.exp(s - m_new), mask, 0.0)
+                l_news.append(_column(l_ref, at, head, heads) * correction
+                              + p.sum(axis=-1, keepdims=True))
+                # [rows, lanes]: of it the head's own lanes are kept.
+                v_sub = v_ref[to, :]
+                pvs.append(jax.lax.dot_general(
+                    p.astype(v_sub.dtype), v_sub, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                corrections.append(correction)
+                m_news.append(m_new)
+            acc_ref[at, :] = (acc_ref[at, :] * _by_head(corrections, heads)
+                              + _by_head(pvs, heads))
+            m_ref[at, :] = _by_head(m_news, heads)
+            l_ref[at, :] = _by_head(l_news, heads)
 
-    _for_each_case(args, 1, 2, step)
+    _for_each_case(args, 2, 3, step)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
-        l = l_ref[:, 0:1]
+        l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)                   # fully masked rows
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
         if emit_lse:
@@ -448,44 +557,48 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
             lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
-def _flash_bhtd(q, k, v, plan, interpret, emit_lse):
-    """Padded ``[BH, T_pad, D]`` -> ``out`` (+ ``lse [BH, T_pad, _LANES]`` when
-    ``emit_lse`` — the training forward; inference skips the write)."""
+def _block_specs(plan, q_map, kv_map):
+    """``(q_spec, kv_spec)``: the blocks of the q side (q, o, dO, dq, lse)
+    and of the kv side (k, v, dk, dv)."""
+    import jax.experimental.pallas as pl
+
+    return (pl.BlockSpec((None, plan['block_q'], plan['lane_block']), q_map),
+            pl.BlockSpec((None, plan['block_k'], plan['lane_block']), kv_map))
+
+
+def _flash_fwd(q, k, v, plan, interpret, emit_lse):
+    """Padded ``[B, T_pad, lanes]`` -> ``out`` (+ ``lse``, float32 of the
+    same shape, a head's value in each of its lanes, when ``emit_lse`` — the
+    training forward; inference skips the write)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, t_pad, d = q.shape
-    block_q, block_k = plan['block_q'], plan['block_k']
+    b, t_pad, lanes = q.shape
+    block_q, block_k, lane_block = (
+        plan['block_q'], plan['block_k'], plan['lane_block'])
     kernel = functools.partial(_flash_kernel, emit_lse=emit_lse,
-                               **_kernel_args(plan, 'fwd', d))
-    q_map, kv_map = _index_maps(block_q, block_k, plan['t'], plan['causal'])
+                               **_kernel_args(plan, 'fwd'))
+    q_spec, kv_spec = _block_specs(plan, *_index_maps(
+        block_q, block_k, plan['t'], plan['causal']))
     # o/lse blocks ignore ki: revisited across the kv axis, written at the
-    # last ki only.
-    out_specs = [pl.BlockSpec((None, block_q, d), q_map)]
-    out_shape = [_out_struct((bh, t_pad, d), q.dtype, q)]
+    # last ki only. A (block_q,) rank-1 or (1, block_q) lse block violates
+    # Mosaic's (8,128)-or-full rule on real chips (found on first hardware
+    # contact), so lse has o's shape: every lane of a head carries its value.
+    out_specs = [q_spec]
+    out_shape = [_out_struct(q.shape, q.dtype, q)]
     if emit_lse:
-        # Lane-broadcast [BH, T_pad, _LANES] (all lanes carry the same
-        # value) — the layout the official TPU flash kernels use for l/m
-        # residuals. A (block_q,) rank-1 or (1, block_q) block violates
-        # Mosaic's (8,128)-or-full rule on real chips (found on first
-        # hardware contact); the 128x HBM redundancy is the price of a
-        # layout every Mosaic version tiles natively.
-        out_specs.append(pl.BlockSpec((None, block_q, _LANES), q_map))
-        out_shape.append(_out_struct((bh, t_pad, _LANES), jnp.float32, q))
+        out_specs.append(q_spec)
+        out_shape.append(_out_struct(q.shape, jnp.float32, q))
     out = pl.pallas_call(
         kernel,
-        grid=(bh, t_pad // block_q, t_pad // block_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), q_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-        ],
+        grid=(b, lanes // lane_block, t_pad // block_q, t_pad // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),       # acc
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
+            pltpu.VMEM((block_q, lane_block), jnp.float32),  # acc
+            pltpu.VMEM((block_q, lane_block), jnp.float32),  # running max
+            pltpu.VMEM((block_q, lane_block), jnp.float32),  # running denom
         ],
         interpret=interpret,
         **_mosaic_params(interpret),
@@ -497,54 +610,74 @@ def _flash_bhtd(q, k, v, plan, interpret, emit_lse):
 # backward
 # --------------------------------------------------------------------------
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref,
-                     acc_ref, **args):
-    """dQ pass: grid (bh, q_blocks, kv_blocks); dq accumulates across ki,
-    over the same bands as the forward.
+def _row_dot(do, o32):
+    """``D = rowsum(dO * O)`` of one head, ``[rows, 1]`` float32, from the
+    head's ``dO`` (the other heads' lanes zeroed) and the block's ``O``."""
+    return jnp.sum(do.astype(jnp.float32) * o32, axis=-1, keepdims=True)
 
-    dS = P * (dO V^T - D);  dQ = scale * dS K, with D = rowsum(dO * O).
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref,
+                     acc_ref, dd_ref, **args):
+    """dQ pass: grid (b, lane block, q_blocks, kv_blocks); dq accumulates
+    across ki, over the same bands as the forward.
+
+    dS = P * (dO V^T - D);  dQ = scale * dS K, with D = rowsum(dO * O) taken
+    here from the dO and O blocks, once a q block (at its first kv block)
+    into scratch that holds a head's D in each of the head's lanes.
     """
     import jax.experimental.pallas as pl
 
-    scale = args['scale']
-    ki = pl.program_id(2)
+    scale, heads = args['scale'], args['heads']
+    ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
+        do, o32 = do_ref[...], o_ref[...].astype(jnp.float32)
+        dd_ref[...] = _by_head([_row_dot(_head_of(do, head, heads), o32)
+                                for head in range(heads[0])], heads)
 
     def step(case):
         for r0, rows, c_full, c_run in _bands(case, *args['tiling']):
             at, to = pl.ds(r0, rows), pl.ds(0, c_run)
-            k_sub, do = k_ref[to, :], do_ref[at, :]
-            p = _recompute_p(q_ref[at, :], k_sub, lse_ref[at, 0:1],
-                             _band_mask(case, r0, rows, c_full, c_run - c_full),
-                             scale)
-            dp = jax.lax.dot_general(do, v_ref[to, :], (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - dd_ref[at, 0:1])
-            acc_ref[at, :] += scale * jax.lax.dot_general(
-                ds.astype(k_sub.dtype), k_sub, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            mask = _band_mask(case, r0, rows, c_full, c_run - c_full)
+            q, do = q_ref[at, :], do_ref[at, :]
+            dqs = []
+            for head in range(heads[0]):
+                k_sub = k_ref[to, :]
+                p = _recompute_p(_head_of(q, head, heads), k_sub,
+                                 _column(lse_ref, at, head, heads), mask,
+                                 scale)
+                dp = jax.lax.dot_general(
+                    _head_of(do, head, heads), v_ref[to, :],
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                ds = p * (dp - _column(dd_ref, at, head, heads))
+                dqs.append(jax.lax.dot_general(
+                    ds.astype(k_sub.dtype), k_sub, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+            acc_ref[at, :] += scale * _by_head(dqs, heads)
 
-    _for_each_case(args, 1, 2, step)
+    _for_each_case(args, 2, 3, step)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
         dq_ref[...] = acc_ref[...].astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
+def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                       dk_ref, dv_ref, dk_acc_ref, dv_acc_ref, **args):
-    """dK/dV pass: grid (bh, kv_blocks, q_blocks); accumulates across qi,
-    over the column bands of :func:`_col_bands`.
+    """dK/dV pass: grid (b, lane block, kv_blocks, q_blocks); accumulates
+    across qi, over the column bands of :func:`_col_bands`.
 
-    dV = P^T dO;  dK = dS^T (scale * Q).
+    dV = P^T dO;  dK = dS^T (scale * Q). A head's q and dO (the other heads'
+    lanes zeroed, so that its products leave their lanes of the accumulators
+    alone) and its D are formed once a grid step, not once a band.
     """
     import jax.experimental.pallas as pl
 
-    scale = args['scale']
-    qi = pl.program_id(2)
+    scale, heads = args['scale'], args['heads']
+    qi = pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -553,85 +686,82 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref,
 
     def step(case):
         block_q = args['tiling'][0]
+        q, do, o32 = q_ref[...], do_ref[...], o_ref[...].astype(jnp.float32)
+        of_head = []
+        for head in range(heads[0]):
+            do_h = _head_of(do, head, heads)
+            of_head.append((_head_of(q, head, heads), do_h,
+                            _row_dot(do_h, o32)))
         for c0, cols, r_lo, r_full in _col_bands(case, *args['tiling']):
             at, to = pl.ds(r_lo, block_q - r_lo), pl.ds(c0, cols)
-            q, do = q_ref[at, :], do_ref[at, :]
-            p = _recompute_p(q, k_ref[to, :], lse_ref[at, 0:1],
-                             _band_mask(case, r_lo, r_full - r_lo, c0, cols),
-                             scale)
-            dv_acc_ref[to, :] += jax.lax.dot_general(
-                p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v_ref[to, :], (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - dd_ref[at, 0:1])
-            # dK = dS^T (scale*Q): scale folds onto the f32 accumulator so Q
-            # stays a native-dtype operand.
-            dk_acc_ref[to, :] += scale * jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            mask = _band_mask(case, r_lo, r_full - r_lo, c0, cols)
+            dv = dk = 0.0
+            for head, (q_h, do_h, dd) in enumerate(of_head):
+                q_h, do_h, dd = q_h[r_lo:], do_h[r_lo:], dd[r_lo:]
+                p = _recompute_p(q_h, k_ref[to, :],
+                                 _column(lse_ref, at, head, heads), mask,
+                                 scale)
+                dv += jax.lax.dot_general(
+                    p.astype(do_h.dtype), do_h, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(do_h, v_ref[to, :],
+                                         (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                ds = p * (dp - dd)
+                # dK = dS^T (scale*Q): scale folds onto the f32 accumulator
+                # so Q stays a native-dtype operand.
+                dk += jax.lax.dot_general(
+                    ds.astype(q_h.dtype), q_h, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            dv_acc_ref[to, :] += dv
+            dk_acc_ref[to, :] += scale * dk
 
-    _for_each_case(args, 2, 1, step)
+    _for_each_case(args, 3, 2, step)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(qi == pl.num_programs(3) - 1)
     def _finish():
         dk_ref[...] = dk_acc_ref[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc_ref[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd_bhtd(q, k, v, do, lse, dd, plan, interpret):
-    """Backward over padded ``[BH, T_pad, D]`` tensors -> (dq, dk, dv)."""
+def _flash_bwd(q, k, v, do, lse, o, plan, interpret):
+    """Backward over padded ``[B, T_pad, lanes]`` arrays -> (dq, dk, dv)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, t_pad, d = q.shape
-    block_q, block_k = plan['block_q'], plan['block_k']
-    q_map, kv_map = _index_maps(block_q, block_k, plan['t'], plan['causal'])
+    b, t_pad, lanes = q.shape
+    block_q, block_k, lane_block = (
+        plan['block_q'], plan['block_k'], plan['lane_block'])
+    q_spec, kv_spec = _block_specs(plan, *_index_maps(
+        block_q, block_k, plan['t'], plan['causal']))
     dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, **_kernel_args(plan, 'dq', d)),
-        grid=(bh, t_pad // block_q, t_pad // block_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), q_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_q, d), q_map),
-            pl.BlockSpec((None, block_q, _LANES), q_map),
-            pl.BlockSpec((None, block_q, _LANES), q_map),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, d), q_map),
-        out_shape=_out_struct((bh, t_pad, d), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        functools.partial(_flash_dq_kernel, **_kernel_args(plan, 'dq')),
+        grid=(b, lanes // lane_block, t_pad // block_q, t_pad // block_k),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, q_spec],
+        out_specs=q_spec,
+        out_shape=_out_struct(q.shape, q.dtype, q),
+        scratch_shapes=[pltpu.VMEM((block_q, lane_block), jnp.float32),
+                        pltpu.VMEM((block_q, lane_block), jnp.float32)],
         interpret=interpret,
         **_mosaic_params(interpret),
-    )(q, k, v, do, lse, dd)
+    )(q, k, v, do, lse, o)
 
-    q_map, kv_map = _index_maps_dkv(block_q, block_k, plan['causal'])
+    q_spec, kv_spec = _block_specs(plan, *_index_maps_dkv(
+        block_q, block_k, plan['causal']))
     dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **_kernel_args(plan, 'dkv', d)),
-        grid=(bh, t_pad // block_k, t_pad // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), q_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_q, d), q_map),
-            pl.BlockSpec((None, block_q, _LANES), q_map),
-            pl.BlockSpec((None, block_q, _LANES), q_map),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, d), kv_map),
-            pl.BlockSpec((None, block_k, d), kv_map),
-        ],
-        out_shape=[
-            _out_struct((bh, t_pad, d), k.dtype, k),
-            _out_struct((bh, t_pad, d), v.dtype, v),
-        ],
+        functools.partial(_flash_dkv_kernel, **_kernel_args(plan, 'dkv')),
+        grid=(b, lanes // lane_block, t_pad // block_k, t_pad // block_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, q_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[_out_struct(k.shape, k.dtype, k),
+                   _out_struct(v.shape, v.dtype, v)],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, lane_block), jnp.float32),
+            pltpu.VMEM((block_k, lane_block), jnp.float32),
         ],
         interpret=interpret,
         **_mosaic_params(interpret),
-    )(q, k, v, do, lse, dd)
+    )(q, k, v, do, lse, o)
     return dq, dk, dv
 
 
@@ -646,6 +776,14 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     Runs the Pallas blocked kernels compiled for the TPU; ``interpret=True``
     runs them in the Pallas interpreter instead (any backend — the CPU
     tests). The compiled kernel on a backend that is not a TPU raises.
+
+    The kernels read q, k, v (and ``dO``) and write the output (and dq, dk,
+    dv) as ``[B, T, H*D]``: the reshape is a bitcast, so what a projection
+    wrote is what a kernel reads and no layout copy stands between them.
+    Heads go to the kernels by 128-lane blocks (:func:`lane_plan`: two
+    64-wide heads a block, one of 128), a grid ``(B, H*D / 128, q blocks, kv
+    blocks)``; what does not fill a block (an odd head, ``H*D < 128``, a
+    width like 96) is padded with zero heads or lanes here and stripped.
 
     ``block_q``/``block_k`` are the *DMA block*: what one grid step holds in
     VMEM. They default per dtype on TPU — ``(512, 1024)`` for bf16, ``(256,
@@ -665,14 +803,17 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
     squares is computed at T = 1024, 0.54 at T = 2048; the limit is a half),
     and ``causal=False`` computes everything that is not padding. :func:`tile_plan` is the
     account, and a ``kernel.flash_plan`` instant on the global tracer
-    reports it once a plan (PERF.md has the times measured on a v5e).
+    reports it with the lane plan once a plan (PERF.md has the times
+    measured on a v5e).
 
     Differentiable end to end in O(block) memory: the training forward saves
-    the logsumexp rows and the backward runs two more Pallas passes (a dq
-    pass over kv blocks and a dk/dv pass over q blocks) that reconstruct
-    ``P = exp(S - lse)`` tile by tile — no ``[T, T]`` materialization in
-    either direction. The inference (non-differentiated) path skips the lse
-    write entirely.
+    the logsumexp rows (float32 ``[B, T, H*D]``, a head's value in each of
+    its lanes) and the backward runs two more Pallas passes (a dq pass over
+    kv blocks and a dk/dv pass over q blocks) that reconstruct ``P = exp(S -
+    lse)`` tile by tile and take ``D = rowsum(dO * O)`` from the ``dO`` and
+    output blocks they are handed — no ``[T, T]`` materialization in either
+    direction and no XLA operation beside the three calls. The inference
+    (non-differentiated) path skips the lse write entirely.
     """
     if not interpret and jax.devices()[0].platform != 'tpu':
         raise RuntimeError(
@@ -717,27 +858,11 @@ _once_a_shape = functools.partial(jax.jit, inline=True)
 @functools.partial(_once_a_shape, static_argnums=(0, 1, 2, 3))
 def _flash_diff_bwd(causal, block_q, block_k, interpret, residuals, g):
     q, k, v, out, lse = residuals
-    b, t, h, d = q.shape
     plan = _plan_for(q, causal, block_q, block_k)
-    t_pad = plan['t_pad']
-
-    # D = rowsum(dO * O): cheap elementwise+reduce, left to XLA.
-    dd = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    dd = jnp.moveaxis(dd, 2, 1).reshape(b * h, t)   # [BH, T]
-    if t_pad != t:
-        # lse is already padded (saved at the forward's padded length).
-        dd = jnp.pad(dd, ((0, 0), (0, t_pad - t)))
-    # Lane-broadcast like lse: [BH, T_pad, _LANES] (see _flash_bhtd).
-    dd = jnp.broadcast_to(dd[:, :, None], (b * h, t_pad, _LANES))
-
-    dq, dk, dv = _flash_bwd_bhtd(
-        _to_bhtd(q, t_pad), _to_bhtd(k, t_pad), _to_bhtd(v, t_pad),
-        _to_bhtd(g, t_pad), lse, dd, plan, interpret)
-
-    def from_bhtd(x):
-        return jnp.moveaxis(x[:, :t].reshape(b, h, t, d), 1, 2)
-
-    return from_bhtd(dq), from_bhtd(dk), from_bhtd(dv)
+    # lse is in the kernels' layout already (saved as the forward wrote it).
+    grads = _flash_bwd(*(_to_lanes(x, plan) for x in (q, k, v, g)), lse,
+                       _to_lanes(out, plan), plan, interpret)
+    return tuple(_from_lanes(x, q.shape, plan) for x in grads)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -745,11 +870,8 @@ _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 @functools.partial(_once_a_shape, static_argnums=(3, 4, 5, 6, 7))
 def _flash_pallas(q, k, v, causal, block_q, block_k, interpret, emit_lse):
-    """Returns ``(out [B,T,H,D], lse [BH, T_pad, _LANES] | None)``."""
-    b, t, h, d = q.shape
+    """Returns ``(out [B, T, H, D], lse [B, T_pad, lanes] | None)``."""
     plan = _plan_for(q, causal, block_q, block_k)
-    t_pad = plan['t_pad']
-    out, lse = _flash_bhtd(_to_bhtd(q, t_pad), _to_bhtd(k, t_pad),
-                           _to_bhtd(v, t_pad), plan, interpret, emit_lse)
-    out = out[:, :t]
-    return jnp.moveaxis(out.reshape(b, h, t, d), 1, 2), lse
+    out, lse = _flash_fwd(_to_lanes(q, plan), _to_lanes(k, plan),
+                          _to_lanes(v, plan), plan, interpret, emit_lse)
+    return _from_lanes(out, q.shape, plan), lse

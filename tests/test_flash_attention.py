@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 
 from petastorm_tpu.models.attention import dense_attention
-from petastorm_tpu.ops.flash_attention import (_bands, _flash_bhtd,
-                                               flash_attention, tile_plan)
+from petastorm_tpu.ops.flash_attention import (_bands, _flash_fwd,
+                                               _from_lanes, _plan_for,
+                                               _to_lanes, flash_attention)
 
 
 # Heavyweight (jit compiles of full models / interpret-mode Pallas):
@@ -121,24 +122,28 @@ def test_rows_fully_masked_in_a_computed_tile_keep_their_statistics():
     logsumexp rows say whether they did; the padding rows stay finite."""
     rng = np.random.default_rng(5)
     t, d = 520, 16
-    plan = tile_plan(t, True, 'float32', d, 512, 64)
+    q, k, v = (jnp.asarray(rng.standard_normal((1, t, 2, d)), jnp.float32)
+               for _ in range(3))
+    plan = _plan_for(q, True, 512, 64)
     fwd = plan['passes']['fwd']
     assert (plan['t_pad'], fwd['sub_q'], fwd['sub_k']) == (1024, 128, 64)
+    assert (plan['heads_per_block'], plan['pad_heads']) == (8, 6)
     # q block 0 against kv block 1: rows 0..127 in one masked 128 x 64 tile.
     assert (0, 128, 0, 64) in _bands((-64, 64), 512, 64, 128, 64)
-    q, k, v = (jnp.pad(jnp.asarray(rng.standard_normal((2, t, d)),
-                                   jnp.float32), ((0, 0), (0, 504), (0, 0)))
-               for _ in range(3))
-    out, lse = _flash_bhtd(q, k, v, plan, True, True)
+    out, lse = _flash_fwd(*(_to_lanes(x, plan) for x in (q, k, v)), plan,
+                          True, True)
+    assert out.shape == lse.shape == (1, 1024, 128)
     assert bool(jnp.isfinite(out).all()) and bool(jnp.isfinite(lse).all())
-    scores = jnp.einsum('bqd,bkd->bqk', q[:, :t], k[:, :t]) / np.sqrt(d)
+    scores = jnp.einsum('bqhd,bkhd->bhqk', q, k) / np.sqrt(d)
     scores = jnp.where(jnp.tril(jnp.ones((t, t), bool)), scores, -jnp.inf)
-    np.testing.assert_allclose(np.asarray(lse[:, :t, 0]),
-                               np.asarray(jax.nn.logsumexp(scores, axis=-1)),
-                               atol=1e-4, rtol=1e-4)
-    np.testing.assert_array_equal(np.asarray(lse[:, :, 0]),
-                                  np.asarray(lse[:, :, -1]))  # lane-broadcast
-    want = jnp.einsum('bqk,bkd->bqd', jax.nn.softmax(scores, axis=-1),
-                      v[:, :t])
-    np.testing.assert_allclose(np.asarray(out[:, :t]), np.asarray(want),
-                               atol=1e-4, rtol=1e-4)
+    # A head's logsumexp stands in each of its d lanes.
+    np.testing.assert_allclose(
+        np.asarray(lse[0, :t, :2 * d:d].T),
+        np.asarray(jax.nn.logsumexp(scores, axis=-1)[0]),
+        atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(lse[:, :, 0:2 * d:d]),
+                                  np.asarray(lse[:, :, d - 1:2 * d:d]))
+    want = jnp.einsum('bhqk,bkhd->bqhd', jax.nn.softmax(scores, axis=-1), v)
+    np.testing.assert_allclose(
+        np.asarray(_from_lanes(out, q.shape, plan)), np.asarray(want),
+        atol=1e-4, rtol=1e-4)

@@ -186,13 +186,23 @@ def test_flash_plan_instant_once_per_distinct_plan(monkeypatch):
         jax.eval_shape(lambda q: layers(q, True), q)
         jax.eval_shape(lambda q: layers(q, True), q)
         jax.eval_shape(lambda q: layers(q, False), q)
+        # The heads of a call are part of its plan: three heads pad a fourth.
+        jax.eval_shape(lambda q: layers(q, False),
+                       jax.ShapeDtypeStruct((1, 1024, 3, 64), jnp.bfloat16))
     finally:
         trace.set_global_tracer(previous)
     plans = [r for r in tracer.records() if r[0] == 'kernel.flash_plan']
-    assert len(plans) == 2
+    assert len(plans) == 3
     assert all(r[1] == 'kernel' and r[3] is None for r in plans)  # instants
-    causal, full = (r[7] for r in plans)
+    causal, full, odd = (r[7] for r in plans)
+    assert (odd['heads'], odd['pad_heads']) == (3, 1)
     assert causal['causal'] and causal['share'] == pytest.approx(7 / 12)
+    # How the call's heads lie in the lane blocks the kernels read, and
+    # where D = rowsum(dO * O) is taken.
+    for plan in (causal, full):
+        assert (plan['heads'], plan['lane_block'], plan['heads_per_block'],
+                plan['pad_heads'], plan['pad_lanes'], plan['dd']) == (
+                    2, 128, 2, 0, 0, 'in-kernel')
     assert causal['passes']['fwd'] == {
         'sub_q': 128, 'sub_k': 128, 'tiles_run': 36, 'tiles_masked': 8,
         'tiles_total': 64}
