@@ -64,6 +64,9 @@ FULL = {
               ('bf16-T8192', 'bfloat16', 1, 8192, 8, 64),
               ('f32-ragged-T1000', 'float32', 2, 1000, 4, 64)],
     'normalize': [(128, 224, 224, 3), (100, 300, 300, 3)],
+    # xing4.tokens4k's first product: pairs of 4,096 tokens x top 4 against
+    # eight experts, tiles of 128 rows (tokens, top_k, experts, k, n, tile)
+    'grouped': (4096, 4, 8, 3584, 2048, 128),
 }
 TINY = {
     'image_size': 32, 'per_chip': 4, 'classes': 10, 'resnet': 'ResNetTiny',
@@ -76,6 +79,7 @@ TINY = {
               ('bf16-multiblock', 'bfloat16', 1, 256, 2, 16),
               ('f32-ragged-T50', 'float32', 2, 50, 2, 16)],
     'normalize': [(8, 16, 16, 3), (5, 10, 10, 3)],
+    'grouped': (24, 2, 3, 32, 128, 8),
 }
 
 # Max |kernel - reference| over max |reference|, forward and input gradients,
@@ -88,6 +92,9 @@ TINY = {
 # of a value) on the way out, and once more on the way into the backward.
 FLASH_FWD_TOL = 2e-3 + 2 ** -8
 FLASH_GRAD_TOL = 5e-3 + 2 ** -7
+# Both routes accumulate a product in float32 and round it once to bf16: they
+# differ by the order of the sum, an ulp of bf16 at the largest value.
+GROUPED_TOL = 2 ** -7
 
 
 class Run(object):
@@ -675,6 +682,56 @@ def _dense_reference(q, k, v):
     return jnp.moveaxis(out[:, :, :, 0, :], 0, 2)
 
 
+def _grouped_product_check(run, assert_mosaic):
+    """The Pallas grouped product of ``ops.grouped_matmul`` against
+    ``jax.lax.ragged_dot`` at the shape a dropless expert layer runs it:
+    uneven groups laid out in whole tiles (one group empty, one of a single
+    pair), forward and both gradients, bf16."""
+    import jax
+    import jax.numpy as jnp
+
+    from petastorm_tpu.ops.grouped_matmul import (aligned_layout,
+                                                  grouped_matmul)
+
+    tokens, top_k, experts, k, n, tile = run.cfg['grouped']
+    pairs = tokens * top_k
+    # the cell's expectation is pairs / 8 an expert; here the first expert
+    # gets nothing, the second one pair, the rest uneven shares of a
+    # sixteenth of the pairs each (what 8 of 64 experts are sent)
+    rng = np.random.default_rng(0)
+    counts = rng.multinomial(pairs // 8, np.ones(experts - 2) / (experts - 2))
+    counts = jnp.asarray([0, 1] + counts.tolist(), jnp.int32)
+    sizes, _ = aligned_layout(counts, tile)
+    rows = pairs + experts * tile
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    x = jax.random.normal(keys[0], (rows, k), jnp.bfloat16)
+    w = (jax.random.normal(keys[1], (experts, k, n), jnp.float32)
+         / np.sqrt(k)).astype(jnp.bfloat16)
+    c = jax.random.normal(keys[2], (rows, n), jnp.bfloat16)
+    impl = 'pallas:interpret' if run.interpret else 'pallas'
+
+    def product(impl):
+        def loss(x, w):
+            y = grouped_matmul(x, w, sizes, tile_m=tile, impl=impl)
+            return jnp.sum((y * c).astype(jnp.float32)), y
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True))
+
+    kernel, plain = product(impl), product('ragged_dot')
+    assert_mosaic('grouped', kernel.lower(x, w))
+    (_, y), grads = run.compile('grouped.fwd+bwd', kernel, x, w)(x, w)
+    (_, want), want_grads = plain(x, w)
+    fwd_err = _rel_err(y, want)
+    grad_err = max(_rel_err(g, t) for g, t in zip(grads, want_grads))
+    run.say('grouped product [{} rows x {} -> {} by {} experts, groups {} in '
+            'tiles of {}, bf16]: fwd err {:.4%}, grad err {:.4%} (tol {:.2%}) '
+            'against ragged_dot'.format(rows, k, n, experts, counts.tolist(),
+                                        tile, fwd_err, grad_err,
+                                        GROUPED_TOL))
+    ok = fwd_err <= GROUPED_TOL and grad_err <= GROUPED_TOL and not bool(
+        jnp.any(y[int(sizes.sum()):]))
+    return [] if ok else ['grouped']
+
+
 def phase_kernels(run):
     import jax
     import jax.numpy as jnp
@@ -723,6 +780,8 @@ def phase_kernels(run):
         if not (finite and fwd_err <= FLASH_FWD_TOL
                 and grad_err <= FLASH_GRAD_TOL):
             failures.append(name)
+
+    failures += _grouped_product_check(run, assert_mosaic)
 
     for shape in run.cfg['normalize']:
         images = jax.random.randint(jax.random.PRNGKey(shape[0]), shape, 0,

@@ -6,9 +6,10 @@ equivalents: flax models consumed through ``jax_loader`` with mesh sharding.
 """
 
 from petastorm_tpu.models.hybrid import HybridLM  # noqa: F401
+from petastorm_tpu.models.latent_moe import LatentMoELM  # noqa: F401
 from petastorm_tpu.models.mlp import MLP  # noqa: F401
 from petastorm_tpu.models.resnet import ResNet, ResNet18, ResNet50  # noqa: F401
-from petastorm_tpu.models.moe import SwitchMoE  # noqa: F401
+from petastorm_tpu.models.moe import RoutedMoE, SwitchMoE  # noqa: F401
 from petastorm_tpu.models.pipeline import pipeline_apply  # noqa: F401
 from petastorm_tpu.models.transformer import TransformerLM  # noqa: F401
 from petastorm_tpu.models.vit import ViT, ViTTiny  # noqa: F401

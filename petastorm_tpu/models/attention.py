@@ -170,9 +170,10 @@ def a2a_self_attention(q, k, v, mesh, seq_axis, causal=False,
     return fn(q, k, v)
 
 
-def dense_attention(q, k, v, causal=False):
+def dense_attention(q, k, v, causal=False, scale=None):
     """Reference dense attention (for tests/small inputs): [B, T, H, D]."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum('bqhd,bkhd->bhqk', q, k) * scale
     if causal:
         t = q.shape[1]

@@ -1,4 +1,9 @@
-"""Switch-style Mixture-of-Experts MLP with expert parallelism.
+"""Mixture-of-experts feed-forward layers: :class:`SwitchMoE` (top-1 with a
+capacity, dense dispatch, the expert axis sharded over a mesh) and
+:class:`RoutedMoE` (dropless top-k over the experts *held here* of a wider
+published router, with a shared expert; below).
+
+**Switch-style Mixture-of-Experts MLP with expert parallelism.**
 
 The GShard/Switch formulation — the original TPU MoE design: top-1 routing
 becomes dense one-hot dispatch/combine einsums (no gather/scatter, every op
@@ -23,12 +28,19 @@ stand-ins; ``expert_param_spec`` composes with
 ``models.train.create_train_state``.
 """
 
-from typing import Any, Optional
+import collections
+import functools
+from typing import Any, Optional, Sequence
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec
+
+from petastorm_tpu.ops.grouped_matmul import (TILE_M, aligned_layout,
+                                              grouped_matmul, tile_groups)
+from petastorm_tpu.trace import get_global_tracer
 
 
 class SwitchMoE(nn.Module):
@@ -126,3 +138,259 @@ def expert_param_spec(path, value, mesh):
             and value.shape[0] % mesh.shape['expert'] == 0:
         return PartitionSpec('expert', None, None)
     return transformer_param_spec(path, value, mesh)
+
+
+# --------------------------------------------------------------------------
+# dropless top-k routing over the experts held here
+# --------------------------------------------------------------------------
+
+def top_k_routing(scores, top_k, scale=1.0, normalise=True):
+    """``scores [..., E]`` float32 (one a published expert) -> ``(experts
+    [..., k] int32, weights [..., k] float32)``: the ``top_k`` scores, each
+    weighted by its own score over the picked scores' sum (``normalise``)
+    times ``scale``."""
+    _, experts = jax.lax.top_k(scores, top_k)
+    picked = jnp.take_along_axis(scores, experts, axis=-1)
+    if normalise:
+        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return experts, picked * scale
+
+
+Dispatch = collections.namedtuple('Dispatch', [
+    'group_sizes',  # [G] rows of each held expert's group, whole tiles
+    'counts',       # [G] pairs routed to each held expert
+    'dest',         # [N, k] the row of each pair (0 where its expert is absent)
+    'is_held',      # [N, k] whether the pair's expert is held here
+    'row_token',    # [rows] the token each row holds (0 for a padding row)
+    'row_pair',     # [rows] the pair each row holds, as n * k + slot
+    'row_valid'])   # [rows] whether the row holds a pair at all
+
+
+def dispatch_plan(experts, held, experts_published, tile_m):
+    """Where every (token, expert) pair goes: pairs sorted by the expert held
+    (in ``held``'s order, a token's pairs in its own order), each group
+    starting on a tile of ``tile_m`` rows and at least one tile long
+    (:func:`petastorm_tpu.ops.grouped_matmul.aligned_layout`); the pairs of
+    absent experts have no row. ``experts [N, k]`` int32 ids over the
+    published experts. Static shapes: ``rows = N * k + len(held) * tile_m``
+    holds every pair there could be, so nothing is dropped; the group sizes
+    are data."""
+    n, k = experts.shape
+    g = len(held)
+    local = np.full((experts_published,), g, np.int32)
+    local[np.asarray(held)] = np.arange(g, dtype=np.int32)
+    key = jnp.asarray(local)[experts].reshape(-1)                   # [P]
+    pairs = n * k
+    onehot = key[:, None] == jnp.arange(g, dtype=jnp.int32)[None]   # [P, G]
+    counts = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    rank = jnp.sum(jnp.where(onehot, jnp.cumsum(onehot, axis=0,
+                                                dtype=jnp.int32) - 1, 0),
+                   axis=-1)
+    sizes, starts = aligned_layout(counts, tile_m)
+    is_held = key < g
+    dest = jnp.where(is_held, starts[jnp.minimum(key, g - 1)] + rank, 0)
+    # The other way: which pair a row holds. Sorted by group, a group's
+    # pairs lie from ``first[g]`` on in the order ``rank`` counts them.
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    first = jnp.cumsum(counts) - counts
+    rows = pairs + g * tile_m
+    row = jnp.arange(rows, dtype=jnp.int32)
+    group, used = tile_groups(sizes, tile_m, rows // tile_m)
+    mine = group[row // tile_m]
+    within = row - starts[mine]
+    valid = (within < counts[mine]) & (row // tile_m < used[0])
+    pair = order[jnp.clip(first[mine] + within, 0, pairs - 1)]
+    return Dispatch(sizes, counts, dest.reshape(n, k), is_held.reshape(n, k),
+                    jnp.where(valid, pair // k, 0), pair, valid)
+
+
+# Both directions of dispatch and combine are gathers: a token's pairs know
+# their rows and a row knows its token, so neither transpose is a scatter.
+
+@jax.custom_vjp
+def _to_rows(x, row_token, dest, is_held):
+    """``x [N, d]`` -> ``[rows, d]``: each row its token's vector."""
+    return x[row_token]
+
+
+def _to_rows_fwd(x, row_token, dest, is_held):
+    return x[row_token], (dest, is_held)
+
+
+def _to_rows_bwd(residuals, g):
+    dest, is_held = residuals
+    picked = jnp.where(is_held[..., None], g[dest].astype(jnp.float32), 0.0)
+    return jnp.sum(picked, axis=1).astype(g.dtype), None, None, None
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+def _weighted(y, weights, dest, is_held):
+    picked = y[dest].astype(jnp.float32)                            # [N, k, d]
+    return picked, jnp.where(is_held, weights, 0.0)
+
+
+@jax.custom_vjp
+def _from_rows(y, weights, dest, is_held, row_token, row_weight):
+    """``y [rows, d]`` -> ``[N, d]``: each token the weighted sum of its
+    held pairs' rows."""
+    picked, w = _weighted(y, weights, dest, is_held)
+    return jnp.sum(picked * w[..., None], axis=1).astype(y.dtype)
+
+
+def _from_rows_fwd(y, weights, dest, is_held, row_token, row_weight):
+    return (_from_rows(y, weights, dest, is_held, row_token, row_weight),
+            (y, weights, dest, is_held, row_token, row_weight))
+
+
+def _from_rows_bwd(residuals, g):
+    y, weights, dest, is_held, row_token, row_weight = residuals
+    dy = (g[row_token].astype(jnp.float32)
+          * row_weight[:, None]).astype(y.dtype)
+    picked, _ = _weighted(y, weights, dest, is_held)
+    dw = jnp.sum(picked * g.astype(jnp.float32)[:, None, :], axis=-1)
+    return (dy, jnp.where(is_held, dw, 0.0).astype(weights.dtype), None,
+            None, None, None)
+
+
+_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+
+
+def routed_experts(x, experts, weights, w_gate_up, w_down, held,
+                   experts_published, tile_m=TILE_M, impl='pallas'):
+    """The held experts' part of a routed layer. ``x [N, d]``, ``experts,
+    weights [N, k]`` (:func:`top_k_routing`), ``w_gate_up [G, d, 2 f]`` (an
+    expert's gate columns, then its up columns), ``w_down [G, f, d]``.
+    Returns ``([N, d], counts [G])``: ``sum over a token's held pairs of
+    weight * expert(x)``, every expert ``down(silu(gate x) * up x)``, and the
+    pairs each held expert was sent. Nothing is dropped whatever the routing
+    (:func:`dispatch_plan`)."""
+    plan = dispatch_plan(experts, held, experts_published, tile_m)
+    f = w_down.shape[1]
+    rows = _to_rows(x, plan.row_token, plan.dest, plan.is_held)
+    hidden = grouped_matmul(rows, w_gate_up, plan.group_sizes, tile_m, impl)
+    hidden = nn.silu(hidden[:, :f]) * hidden[:, f:]
+    y = grouped_matmul(hidden, w_down, plan.group_sizes, tile_m, impl)
+    row_weight = jnp.where(plan.row_valid,
+                           weights.reshape(-1)[plan.row_pair], 0.0)
+    out = _from_rows(y, weights, plan.dest, plan.is_held, plan.row_token,
+                     row_weight)
+    return out, plan.counts
+
+
+class RoutedMoE(nn.Module):
+    """Dropless top-k routed experts with a shared expert, told which
+    experts it holds: ``[B, T, d] -> ([B, T, d], expert_load [G])``.
+
+    The router is ``experts_published`` wide whatever is held: ``s =
+    sigmoid(W_r x)`` in float32, the ``top_k`` experts of each token, their
+    scores normalised over the picked and times ``scale``. ``held`` lists the
+    published experts that live here (a chip of an expert-parallel group);
+    the layer computes ``shared(x) + sum over a token's picked experts that
+    are held of weight * expert(x)`` and nothing for the absent ones: the
+    partial result of the chip before the group's exchange, with the shared
+    expert, which every chip computes alike, counted here. **No capacity and
+    no drop**: the held experts' products run over row groups of
+    data-dependent size (:mod:`petastorm_tpu.ops.grouped_matmul`; ``impl``
+    ``'pallas'``, ``'pallas:interpret'`` or ``'ragged_dot'``), in an array
+    that holds every pair there could be. ``expert_load`` is how many pairs
+    each held expert was sent.
+
+    A device trace names the layer's Pallas calls by this module's name, so
+    name it ``moe``. With ``mesh`` the routed part is mapped over the
+    batch's shards (a Pallas call is opaque to the SPMD partitioner); every
+    shard routes its own rows.
+    """
+
+    experts_published: int
+    held: Sequence[int]
+    top_k: int = 4
+    scale: float = 1.0
+    d_ff: int = 1024                    # an expert's width
+    shared_d_ff: int = 0                # the shared expert's; 0: none
+    normalise: bool = True
+    impl: str = 'pallas'
+    tile_m: int = TILE_M
+    mesh: Any = None
+    batch_axis: Optional[str] = 'data'
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from petastorm_tpu.models.hybrid import SwiGLU
+        from petastorm_tpu.models.transformer import usable_axis
+
+        b, t, d = x.shape
+        g = len(self.held)
+        x = x.astype(self.dtype)
+        scores = nn.sigmoid(nn.Dense(
+            self.experts_published, use_bias=False, dtype=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST, name='router')(
+                x.astype(jnp.float32)))
+        experts, weights = top_k_routing(scores, self.top_k, self.scale,
+                                         self.normalise)
+        init = nn.initializers.normal(0.02)
+        w_gate_up = self.param('experts_gate_up', init,
+                               (g, d, 2 * self.d_ff)).astype(self.dtype)
+        w_down = self.param('experts_down', init,
+                            (g, self.d_ff, d)).astype(self.dtype)
+
+        def routed(x, experts, weights, w_gate_up, w_down, axis=None):
+            if axis is not None and self.impl == 'pallas':
+                # Where shard_map checks how values vary (not under the
+                # interpreter), the kernels' custom_vjp hands back a
+                # gradient as varying as the rows it was made from: weights
+                # that vary like them get it summed over the shards by the
+                # cast's transpose.
+                w_gate_up, w_down = (jax.lax.pcast(w, (axis,), to='varying')
+                                     for w in (w_gate_up, w_down))
+            rows = x.shape[0] * x.shape[1]
+            y, counts = routed_experts(
+                x.reshape(rows, d), experts.reshape(rows, self.top_k),
+                weights.reshape(rows, self.top_k), w_gate_up, w_down,
+                tuple(self.held), self.experts_published, self.tile_m,
+                self.impl)
+            return y.reshape(x.shape), counts[None]
+
+        if self.mesh is not None and self.impl.startswith('pallas'):
+            axis = usable_axis(self.mesh, self.batch_axis, b)
+            rows, whole = PartitionSpec(axis, None, None), PartitionSpec()
+            routed = jax.shard_map(
+                functools.partial(routed, axis=axis), mesh=self.mesh,
+                in_specs=(rows, rows, rows, whole, whole),
+                out_specs=(rows, PartitionSpec(axis, None)),
+                check_vma=self.impl == 'pallas')
+        y, counts = routed(x, experts, weights, w_gate_up, w_down)
+        if self.shared_d_ff:
+            y = y + SwiGLU(self.shared_d_ff, dtype=self.dtype,
+                           name='shared')(x)
+        return y, jnp.sum(counts, axis=0)
+
+
+# A loop that dispatches step k and then awaits step k - 1 (one step kept in
+# flight, as the loader keeps ``inflight`` 2 transfers) has step k - 2 behind
+# it when step k's metrics are handed over.
+LOAD_LAG = 2
+
+
+class ExpertLoadCounter(object):
+    """Running totals of a step's ``expert_load`` on the global tracer's
+    ring, as counters ``moe.expert_load.e<slot>`` (one a held expert): what a
+    reader takes the difference of at a window's two ends. ``add`` is handed
+    every step's ``metrics`` and reads the load of the step ``LOAD_LAG`` calls
+    back, so it never waits for the device."""
+
+    def __init__(self):
+        self._pending, self._total = collections.deque(), None
+
+    def add(self, metrics):
+        self._pending.append(metrics['expert_load'])
+        if len(self._pending) <= LOAD_LAG:
+            return
+        load = np.asarray(self._pending.popleft()).astype(np.int64)
+        self._total = load if self._total is None else self._total + load
+        tracer = get_global_tracer()
+        for slot, value in enumerate(self._total.tolist()):
+            tracer.counter('moe.expert_load.e{}'.format(slot), value,
+                           cat='step')

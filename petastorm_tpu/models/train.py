@@ -11,6 +11,16 @@ back float32 logits, and inside the jitted step that cast fuses into the
 op's reads, so what lies in HBM is the logits as the head's product left
 them (bf16 for the models' default dtype) and the backward pass starts from
 them and a float32 log-sum-exp a row.
+
+**A model with several heads.** Where a model hands back a dict, ``'logits'``
+is one array or a tuple of them (``models.LatentMoELM``: the next token and
+the second next) and ``'metrics'`` arrays of its own that the step passes on
+in its ``metrics`` (``expert_load``). The labels of a tuple of heads are a
+tuple of ``(labels, weights)`` pairs, one a head; the step's loss is the sum
+over the heads of ``sum(weights * loss)``, so a head's mean, its mask and its
+coefficient are all in its weights. Each head goes through the same op (one
+``step.loss_plan`` instant a distinct plan). A model that hands back an array
+runs the program it always ran.
 """
 
 from typing import Any
@@ -107,6 +117,31 @@ def transformer_param_spec(path, value, mesh):
     return PartitionSpec()
 
 
+def _model_outputs(out):
+    """``(logits, metrics of the model's own)`` of what a model handed back."""
+    if isinstance(out, dict):
+        return out['logits'], dict(out.get('metrics', {}))
+    return out, {}
+
+
+def summed_loss(logits, labels):
+    """``(loss, hit)``: the batch's mean loss and each row's hit for one
+    array of logits; for a tuple of heads the sum of ``sum(weights * loss)``
+    over ``labels``' ``(labels, weights)`` pairs, and the first head's hit."""
+    if not isinstance(logits, (tuple, list)):
+        loss, hit = softmax_cross_entropy(logits, labels)
+        return loss.mean(), hit
+    if len(logits) != len(labels):
+        raise ValueError('{} heads against {} (labels, weights) pairs'.format(
+            len(logits), len(labels)))
+    total, first_hit = 0.0, None
+    for head, (target, weights) in zip(logits, labels):
+        loss, hit = softmax_cross_entropy(head, target)
+        total = total + jnp.sum(loss * weights)
+        first_hit = hit if first_hit is None else first_hit
+    return total, first_hit
+
+
 def make_train_step(mesh=None, batch_axis='data'):
     """Build a jitted train step ``(state, images, labels) -> (state, metrics)``."""
     return jax.jit(make_train_step_fn(mesh=mesh, batch_axis=batch_axis),
@@ -180,15 +215,16 @@ def make_train_step_fn(mesh=None, batch_axis='data'):
             else:
                 logits = state.apply_fn(variables, images, train=True)
                 new_batch_stats = None
-            loss, hit = softmax_cross_entropy(logits, labels)
-            return loss.mean(), (hit, new_batch_stats)
+            logits, extra = _model_outputs(logits)
+            loss, hit = summed_loss(logits, labels)
+            return loss, (hit, new_batch_stats, extra)
 
-        (loss, (hit, new_batch_stats)), grads = jax.value_and_grad(
+        (loss, (hit, new_batch_stats, extra)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
         state = state.apply_gradients(grads=grads)
         if new_batch_stats is not None:
             state = state.replace(batch_stats=new_batch_stats)
-        return state, {'loss': loss, 'accuracy': jnp.mean(hit)}
+        return state, dict(extra, loss=loss, accuracy=jnp.mean(hit))
 
     return train_step
 
@@ -198,8 +234,9 @@ def make_eval_step():
         variables = {'params': state.params}
         if state.batch_stats is not None:
             variables['batch_stats'] = state.batch_stats
-        logits = state.apply_fn(variables, images, train=False)
-        loss, hit = softmax_cross_entropy(logits, labels)
-        return {'loss': loss.mean(), 'accuracy': jnp.mean(hit)}
+        logits, extra = _model_outputs(
+            state.apply_fn(variables, images, train=False))
+        loss, hit = summed_loss(logits, labels)
+        return dict(extra, loss=loss, accuracy=jnp.mean(hit))
 
     return jax.jit(eval_step)

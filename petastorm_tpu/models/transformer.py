@@ -83,11 +83,15 @@ class FlatDenseGeneral(nn.Module):
 
 
 def self_attention(q, k, v, attention='dense', causal=True, mesh=None,
-                   seq_axis=None, batch_axis='data', head_axis='model'):
+                   seq_axis=None, batch_axis='data', head_axis='model',
+                   scale=None):
     """``[B, T, H, Dh]`` projections -> ``[B, T, H, Dh]`` through the backend
     ``attention`` names (see the module docstring): what lies between a
     layer's projections and its output projection, shared by
-    :class:`MultiHeadAttention` and ``models.hybrid``'s full-attention layer."""
+    :class:`MultiHeadAttention`, ``models.hybrid``'s full-attention layer and
+    ``models.latent_moe``'s latent attention (whose values are narrower than
+    its keys and whose scores take a ``scale`` of their own; ``dense`` and
+    ``flash`` only)."""
     num_heads = q.shape[2]
     backend, _, mode = attention.partition(':')
     interpret = mode == 'interpret'
@@ -95,6 +99,8 @@ def self_attention(q, k, v, attention='dense', causal=True, mesh=None,
         raise ValueError('unknown attention {!r}'.format(attention))
 
     if backend in ('ring', 'a2a'):
+        if scale is not None:
+            raise ValueError('attention={!r} takes no scale'.format(attention))
         if mesh is None or seq_axis is None:
             raise ValueError("attention={!r} needs mesh= and seq_axis="
                              .format(attention))
@@ -111,7 +117,8 @@ def self_attention(q, k, v, attention='dense', causal=True, mesh=None,
                             batch_axis=batch_axis, head_axis=head_axis)
     if backend == 'flash':
         from petastorm_tpu.ops.flash_attention import flash_attention
-        attend = partial(flash_attention, causal=causal, interpret=interpret)
+        attend = partial(flash_attention, causal=causal, interpret=interpret,
+                         scale=scale)
         if mesh is not None:
             # A Pallas call is opaque to the SPMD partitioner, which
             # would gather the whole batch onto every device to run it.
@@ -128,7 +135,7 @@ def self_attention(q, k, v, attention='dense', causal=True, mesh=None,
         return attend(q, k, v)
     if backend == 'dense':
         from petastorm_tpu.models.attention import dense_attention
-        return dense_attention(q, k, v, causal=causal)
+        return dense_attention(q, k, v, causal=causal, scale=scale)
     raise ValueError('unknown attention {!r}'.format(attention))
 
 

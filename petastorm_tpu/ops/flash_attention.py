@@ -32,6 +32,15 @@ does not fill a lane block (an odd head, ``H*D < 128``, a width like 96) is
 padded with zero heads or lanes by the wrapper and stripped: one kernel
 family, nothing to choose.
 
+**Keys and values of two widths.** Where ``v`` is narrower or wider a head
+than ``q`` and ``k`` (latent attention: 128 content + 64 rotary lanes of key
+against 128 of value) the two sides have lane blocks of their own, one head a
+block: q, k, dq and dk by blocks of the key width padded to whole vregs (192
+to 256: ``pad_lanes`` 64), v, the output, ``dO``, ``lse`` and dv by blocks
+of the value width. The score is one product over the padded key block;
+bands, cases and the executed-work account are the same plan. ``scale`` is
+the call's (default ``D ** -0.5`` of the key width as given).
+
 **Two tile sizes** (:func:`tile_plan`, the one place they are decided). The
 *DMA block* ``(block_q, block_k)`` is what one grid step holds in VMEM; it
 is large (``(512, 1024)`` for bf16) because a grid step costs about 0.35 µs
@@ -114,7 +123,7 @@ def _out_struct(shape, dtype, like):
 # the lane plan: how heads lie in the 128-lane blocks of a [B, T, H*D] array
 # --------------------------------------------------------------------------
 
-def lane_plan(h, d):
+def lane_plan(h, d, dv=None):
     """How ``h`` heads of width ``d`` fill the lane blocks the kernels read,
     from what the call can see. A head no wider than a vreg is padded to the
     next power of two (which divides 128) and ``128 // width`` heads share a
@@ -124,7 +133,15 @@ def lane_plan(h, d):
 
     JSON-safe: ``lane_block`` (the block's lanes), ``heads_per_block``,
     ``pad_heads`` (zero heads appended) and ``pad_lanes`` (zero lanes
-    appended to every head)."""
+    appended to every head); ``v_lane_block`` and ``v_pad_lanes`` are the
+    same of the value side, which differ where ``dv`` is another width than
+    ``d``: then both sides have one head a block, each width padded to whole
+    vregs (``(4, 192, 128)``: 256 and 128 lanes, 64 lanes of padding a key)."""
+    if dv not in (None, d):
+        wide, v_wide = (-(-w // _LANES) * _LANES for w in (d, dv))
+        return {'lane_block': wide, 'heads_per_block': 1, 'pad_heads': 0,
+                'pad_lanes': wide - d, 'v_lane_block': v_wide,
+                'v_pad_lanes': v_wide - dv}
     if d <= _LANES:
         width = 1 << (d - 1).bit_length()
         lane_block = _LANES
@@ -132,26 +149,28 @@ def lane_plan(h, d):
         width = lane_block = -(-d // _LANES) * _LANES
     per_block = lane_block // width
     return {'lane_block': lane_block, 'heads_per_block': per_block,
-            'pad_heads': -h % per_block, 'pad_lanes': width - d}
+            'pad_heads': -h % per_block, 'pad_lanes': width - d,
+            'v_lane_block': lane_block, 'v_pad_lanes': width - d}
 
 
-def _to_lanes(x, plan):
+def _to_lanes(x, plan, side=''):
     """``[B, T, H, D]`` -> ``[B, T_pad, lanes]``: a bitcast where nothing is
     padded (the array a projection wrote is the array a kernel reads); else
-    zero rows, heads and lanes up to whole blocks."""
+    zero rows, heads and lanes up to whole blocks. ``side='v_'``: an array
+    of the value side (v, the output, their gradients)."""
     b, t, h, d = x.shape
-    pad = (plan['t_pad'] - t, plan['pad_heads'], plan['pad_lanes'])
+    pad = (plan['t_pad'] - t, plan['pad_heads'], plan[side + 'pad_lanes'])
     if any(pad):
         x = jnp.pad(x, ((0, 0),) + tuple((0, p) for p in pad))
     return x.reshape(b, plan['t_pad'], -1)
 
 
-def _from_lanes(x, shape, plan):
+def _from_lanes(x, shape, plan, side=''):
     """The inverse of :func:`_to_lanes`: ``[B, T_pad, lanes]`` -> ``shape``,
     the padding stripped."""
     b, t, h, d = shape
     x = x.reshape(b, plan['t_pad'], h + plan['pad_heads'],
-                  d + plan['pad_lanes'])
+                  d + plan[side + 'pad_lanes'])
     return x[:, :t, :h, :d]
 
 
@@ -315,16 +334,22 @@ def tile_plan(t, causal, dtype, hd, block_q, block_k):
 _plans_reported = set()
 
 
-def _plan_for(q, causal, block_q, block_k):
+def _plan_for(q, causal, block_q, block_k, v=None, scale=None):
     """The plan of a call on ``[B, T, H, D]`` operands: :func:`tile_plan`
-    with :func:`lane_plan`. The first time a process traces a kernel with
-    it, one ``kernel.flash_plan`` instant on the global tracer carries it (a
-    model's layers share one plan, so one record, not one a layer)."""
+    with :func:`lane_plan`, the widths of the two sides (``qk_width``,
+    ``v_width``) and the scale of the scores. The first time a process
+    traces a kernel with it, one ``kernel.flash_plan`` instant on the global
+    tracer carries it (a model's layers share one plan, so one record, not
+    one a layer)."""
     _, t, h, hd = q.shape
+    dv = hd if v is None else v.shape[-1]
+    scale = 1.0 / math.sqrt(hd) if scale is None else float(scale)
     key = (t, bool(causal), jnp.dtype(q.dtype).name, hd, block_q, block_k)
-    plan = dict(tile_plan(*key), heads=h, dd='in-kernel', **lane_plan(h, hd))
-    if key + (h,) not in _plans_reported:
-        _plans_reported.add(key + (h,))
+    plan = dict(tile_plan(*key), heads=h, dd='in-kernel', qk_width=hd,
+                v_width=dv, scale=scale, **lane_plan(h, hd, dv))
+    key += (h, dv, scale)
+    if key not in _plans_reported:
+        _plans_reported.add(key)
         get_global_tracer().instant('kernel.flash_plan', cat='kernel',
                                     args=plan)
     return plan
@@ -436,13 +461,16 @@ def _index_maps_dkv(block_q, block_k, causal):
 
 def _kernel_args(plan, name):
     """The static arguments of pass ``name``'s kernel. ``heads`` is how the
-    heads of a lane block lie in it: how many, and how many lanes each."""
+    heads of a lane block lie in it: how many, and how many lanes each, on
+    the key side (q, k and their gradients); ``v_heads`` on the value side
+    (v, the output, ``dO``, ``lse``, ``D``)."""
     tiling = (plan['block_q'], plan['block_k'],
               plan['passes'][name]['sub_q'], plan['passes'][name]['sub_k'])
     per_block = plan['heads_per_block']
     return dict(tiling=tiling, seq_len=plan['t'], causal=plan['causal'],
-                cases=tuple(plan['cases']), scale=1.0 / math.sqrt(plan['hd']),
-                heads=(per_block, plan['lane_block'] // per_block))
+                cases=tuple(plan['cases']), scale=plan['scale'],
+                heads=(per_block, plan['lane_block'] // per_block),
+                v_heads=(per_block, plan['v_lane_block'] // per_block))
 
 
 # --------------------------------------------------------------------------
@@ -505,7 +533,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
         lse_ref, acc_ref, m_ref, l_ref = rest
     else:
         lse_ref, (acc_ref, m_ref, l_ref) = None, rest
-    scale, heads = args['scale'], args['heads']
+    scale, heads, v_heads = args['scale'], args['heads'], args['v_heads']
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
@@ -524,13 +552,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
                 # Native-dtype operands, f32 accumulation.
                 s = _masked(_scores(_head_of(q, head, heads), k_ref[to, :],
                                     scale), mask, NEG_INF)
-                m_prev = _column(m_ref, at, head, heads)
+                m_prev = _column(m_ref, at, head, v_heads)
                 m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
                 correction = jnp.exp(m_prev - m_new)
                 # A row with nothing unmasked so far has m_new == NEG_INF and
                 # p == 1 where it is masked: the second select zeroes it.
                 p = _masked(jnp.exp(s - m_new), mask, 0.0)
-                l_news.append(_column(l_ref, at, head, heads) * correction
+                l_news.append(_column(l_ref, at, head, v_heads) * correction
                               + p.sum(axis=-1, keepdims=True))
                 # [rows, lanes]: of it the head's own lanes are kept.
                 v_sub = v_ref[to, :]
@@ -539,10 +567,10 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
                     preferred_element_type=jnp.float32))
                 corrections.append(correction)
                 m_news.append(m_new)
-            acc_ref[at, :] = (acc_ref[at, :] * _by_head(corrections, heads)
-                              + _by_head(pvs, heads))
-            m_ref[at, :] = _by_head(m_news, heads)
-            l_ref[at, :] = _by_head(l_news, heads)
+            acc_ref[at, :] = (acc_ref[at, :] * _by_head(corrections, v_heads)
+                              + _by_head(pvs, v_heads))
+            m_ref[at, :] = _by_head(m_news, v_heads)
+            l_ref[at, :] = _by_head(l_news, v_heads)
 
     _for_each_case(args, 2, 3, step)
 
@@ -558,12 +586,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, emit_lse, **args):
 
 
 def _block_specs(plan, q_map, kv_map):
-    """``(q_spec, kv_spec)``: the blocks of the q side (q, o, dO, dq, lse)
-    and of the kv side (k, v, dk, dv)."""
+    """``(q_spec, k_spec, o_spec, v_spec)``: the blocks of q and dq, of k
+    and dk (key-side lanes), of o, dO and lse, and of v and dv (value-side
+    lanes, the same where the widths are)."""
     import jax.experimental.pallas as pl
 
-    return (pl.BlockSpec((None, plan['block_q'], plan['lane_block']), q_map),
-            pl.BlockSpec((None, plan['block_k'], plan['lane_block']), kv_map))
+    return tuple(pl.BlockSpec((None, plan[rows], plan[lanes]), index)
+                 for lanes in ('lane_block', 'v_lane_block')
+                 for rows, index in (('block_q', q_map), ('block_k', kv_map)))
 
 
 def _flash_fwd(q, k, v, plan, interpret, emit_lse):
@@ -574,31 +604,32 @@ def _flash_fwd(q, k, v, plan, interpret, emit_lse):
     from jax.experimental.pallas import tpu as pltpu
 
     b, t_pad, lanes = q.shape
-    block_q, block_k, lane_block = (
-        plan['block_q'], plan['block_k'], plan['lane_block'])
+    block_q, block_k, lane_block, v_block = (
+        plan['block_q'], plan['block_k'], plan['lane_block'],
+        plan['v_lane_block'])
     kernel = functools.partial(_flash_kernel, emit_lse=emit_lse,
                                **_kernel_args(plan, 'fwd'))
-    q_spec, kv_spec = _block_specs(plan, *_index_maps(
+    q_spec, k_spec, o_spec, v_spec = _block_specs(plan, *_index_maps(
         block_q, block_k, plan['t'], plan['causal']))
     # o/lse blocks ignore ki: revisited across the kv axis, written at the
     # last ki only. A (block_q,) rank-1 or (1, block_q) lse block violates
     # Mosaic's (8,128)-or-full rule on real chips (found on first hardware
     # contact), so lse has o's shape: every lane of a head carries its value.
-    out_specs = [q_spec]
-    out_shape = [_out_struct(q.shape, q.dtype, q)]
+    out_specs = [o_spec]
+    out_shape = [_out_struct(v.shape, q.dtype, q)]
     if emit_lse:
-        out_specs.append(q_spec)
-        out_shape.append(_out_struct(q.shape, jnp.float32, q))
+        out_specs.append(o_spec)
+        out_shape.append(_out_struct(v.shape, jnp.float32, q))
     out = pl.pallas_call(
         kernel,
         grid=(b, lanes // lane_block, t_pad // block_q, t_pad // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q, lane_block), jnp.float32),  # acc
-            pltpu.VMEM((block_q, lane_block), jnp.float32),  # running max
-            pltpu.VMEM((block_q, lane_block), jnp.float32),  # running denom
+            pltpu.VMEM((block_q, v_block), jnp.float32),  # acc
+            pltpu.VMEM((block_q, v_block), jnp.float32),  # running max
+            pltpu.VMEM((block_q, v_block), jnp.float32),  # running denom
         ],
         interpret=interpret,
         **_mosaic_params(interpret),
@@ -627,15 +658,15 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref,
     """
     import jax.experimental.pallas as pl
 
-    scale, heads = args['scale'], args['heads']
+    scale, heads, v_heads = args['scale'], args['heads'], args['v_heads']
     ki = pl.program_id(3)
 
     @pl.when(ki == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         do, o32 = do_ref[...], o_ref[...].astype(jnp.float32)
-        dd_ref[...] = _by_head([_row_dot(_head_of(do, head, heads), o32)
-                                for head in range(heads[0])], heads)
+        dd_ref[...] = _by_head([_row_dot(_head_of(do, head, v_heads), o32)
+                                for head in range(heads[0])], v_heads)
 
     def step(case):
         for r0, rows, c_full, c_run in _bands(case, *args['tiling']):
@@ -646,13 +677,13 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref, dq_ref,
             for head in range(heads[0]):
                 k_sub = k_ref[to, :]
                 p = _recompute_p(_head_of(q, head, heads), k_sub,
-                                 _column(lse_ref, at, head, heads), mask,
+                                 _column(lse_ref, at, head, v_heads), mask,
                                  scale)
                 dp = jax.lax.dot_general(
-                    _head_of(do, head, heads), v_ref[to, :],
+                    _head_of(do, head, v_heads), v_ref[to, :],
                     (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32)
-                ds = p * (dp - _column(dd_ref, at, head, heads))
+                ds = p * (dp - _column(dd_ref, at, head, v_heads))
                 dqs.append(jax.lax.dot_general(
                     ds.astype(k_sub.dtype), k_sub, (((1,), (0,)), ((), ())),
                     preferred_element_type=jnp.float32))
@@ -676,7 +707,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
     """
     import jax.experimental.pallas as pl
 
-    scale, heads = args['scale'], args['heads']
+    scale, heads, v_heads = args['scale'], args['heads'], args['v_heads']
     qi = pl.program_id(3)
 
     @pl.when(qi == 0)
@@ -689,7 +720,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
         q, do, o32 = q_ref[...], do_ref[...], o_ref[...].astype(jnp.float32)
         of_head = []
         for head in range(heads[0]):
-            do_h = _head_of(do, head, heads)
+            do_h = _head_of(do, head, v_heads)
             of_head.append((_head_of(q, head, heads), do_h,
                             _row_dot(do_h, o32)))
         for c0, cols, r_lo, r_full in _col_bands(case, *args['tiling']):
@@ -699,7 +730,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
             for head, (q_h, do_h, dd) in enumerate(of_head):
                 q_h, do_h, dd = q_h[r_lo:], do_h[r_lo:], dd[r_lo:]
                 p = _recompute_p(q_h, k_ref[to, :],
-                                 _column(lse_ref, at, head, heads), mask,
+                                 _column(lse_ref, at, head, v_heads), mask,
                                  scale)
                 dv += jax.lax.dot_general(
                     p.astype(do_h.dtype), do_h, (((0,), (0,)), ((), ())),
@@ -730,34 +761,35 @@ def _flash_bwd(q, k, v, do, lse, o, plan, interpret):
     from jax.experimental.pallas import tpu as pltpu
 
     b, t_pad, lanes = q.shape
-    block_q, block_k, lane_block = (
-        plan['block_q'], plan['block_k'], plan['lane_block'])
-    q_spec, kv_spec = _block_specs(plan, *_index_maps(
+    block_q, block_k, lane_block, v_block = (
+        plan['block_q'], plan['block_k'], plan['lane_block'],
+        plan['v_lane_block'])
+    q_spec, k_spec, o_spec, v_spec = _block_specs(plan, *_index_maps(
         block_q, block_k, plan['t'], plan['causal']))
     dq = pl.pallas_call(
         functools.partial(_flash_dq_kernel, **_kernel_args(plan, 'dq')),
         grid=(b, lanes // lane_block, t_pad // block_q, t_pad // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, q_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, o_spec],
         out_specs=q_spec,
         out_shape=_out_struct(q.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, lane_block), jnp.float32),
-                        pltpu.VMEM((block_q, lane_block), jnp.float32)],
+                        pltpu.VMEM((block_q, v_block), jnp.float32)],
         interpret=interpret,
         **_mosaic_params(interpret),
     )(q, k, v, do, lse, o)
 
-    q_spec, kv_spec = _block_specs(plan, *_index_maps_dkv(
+    q_spec, k_spec, o_spec, v_spec = _block_specs(plan, *_index_maps_dkv(
         block_q, block_k, plan['causal']))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_dkv_kernel, **_kernel_args(plan, 'dkv')),
         grid=(b, lanes // lane_block, t_pad // block_k, t_pad // block_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, q_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, o_spec, o_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[_out_struct(k.shape, k.dtype, k),
                    _out_struct(v.shape, v.dtype, v)],
         scratch_shapes=[
             pltpu.VMEM((block_k, lane_block), jnp.float32),
-            pltpu.VMEM((block_k, lane_block), jnp.float32),
+            pltpu.VMEM((block_k, v_block), jnp.float32),
         ],
         interpret=interpret,
         **_mosaic_params(interpret),
@@ -770,8 +802,10 @@ def _flash_bwd(q, k, v, do, lse, o, plan, interpret):
 # --------------------------------------------------------------------------
 
 def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
-                    interpret=False):
-    """Exact multi-head attention, ``[B, T, H, D]`` -> ``[B, T, H, D]``.
+                    interpret=False, scale=None):
+    """Exact multi-head attention, ``[B, T, H, D]`` -> ``[B, T, H, D]``
+    (``v`` and the output ``[B, T, H, Dv]`` where a value has another width
+    than a key); ``scale`` multiplies the scores, ``D ** -0.5`` by default.
 
     Runs the Pallas blocked kernels compiled for the TPU; ``interpret=True``
     runs them in the Pallas interpreter instead (any backend — the CPU
@@ -830,19 +864,20 @@ def flash_attention(q, k, v, causal=False, block_q=None, block_k=None,
             dq, dk = 256, 512
         block_q = dq if block_q is None else block_q
         block_k = dk if block_k is None else block_k
-    return _flash_diff(q, k, v, causal, block_q, block_k, interpret)
+    scale = None if scale is None else float(scale)
+    return _flash_diff(q, k, v, causal, block_q, block_k, interpret, scale)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_diff(q, k, v, causal, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_diff(q, k, v, causal, block_q, block_k, interpret, scale=None):
     out, _ = _flash_pallas(q, k, v, causal, block_q, block_k, interpret,
-                           emit_lse=False)
+                           False, scale)
     return out
 
 
-def _flash_diff_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _flash_diff_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
     out, lse = _flash_pallas(q, k, v, causal, block_q, block_k, interpret,
-                             emit_lse=True)
+                             True, scale)
     return out, (q, k, v, out, lse)
 
 
@@ -855,23 +890,27 @@ def _flash_diff_fwd(q, k, v, causal, block_q, block_k, interpret):
 _once_a_shape = functools.partial(jax.jit, inline=True)
 
 
-@functools.partial(_once_a_shape, static_argnums=(0, 1, 2, 3))
-def _flash_diff_bwd(causal, block_q, block_k, interpret, residuals, g):
+@functools.partial(_once_a_shape, static_argnums=(0, 1, 2, 3, 4))
+def _flash_diff_bwd(causal, block_q, block_k, interpret, scale, residuals, g):
     q, k, v, out, lse = residuals
-    plan = _plan_for(q, causal, block_q, block_k)
+    plan = _plan_for(q, causal, block_q, block_k, v, scale)
     # lse is in the kernels' layout already (saved as the forward wrote it).
-    grads = _flash_bwd(*(_to_lanes(x, plan) for x in (q, k, v, g)), lse,
-                       _to_lanes(out, plan), plan, interpret)
-    return tuple(_from_lanes(x, q.shape, plan) for x in grads)
+    dq, dk, dv = _flash_bwd(
+        _to_lanes(q, plan), _to_lanes(k, plan), _to_lanes(v, plan, 'v_'),
+        _to_lanes(g, plan, 'v_'), lse, _to_lanes(out, plan, 'v_'), plan,
+        interpret)
+    return (_from_lanes(dq, q.shape, plan), _from_lanes(dk, q.shape, plan),
+            _from_lanes(dv, v.shape, plan, 'v_'))
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
-@functools.partial(_once_a_shape, static_argnums=(3, 4, 5, 6, 7))
-def _flash_pallas(q, k, v, causal, block_q, block_k, interpret, emit_lse):
-    """Returns ``(out [B, T, H, D], lse [B, T_pad, lanes] | None)``."""
-    plan = _plan_for(q, causal, block_q, block_k)
+@functools.partial(_once_a_shape, static_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_pallas(q, k, v, causal, block_q, block_k, interpret, emit_lse,
+                  scale=None):
+    """Returns ``(out [B, T, H, Dv], lse [B, T_pad, value lanes] | None)``."""
+    plan = _plan_for(q, causal, block_q, block_k, v, scale)
     out, lse = _flash_fwd(_to_lanes(q, plan), _to_lanes(k, plan),
-                          _to_lanes(v, plan), plan, interpret, emit_lse)
-    return _from_lanes(out, q.shape, plan), lse
+                          _to_lanes(v, plan, 'v_'), plan, interpret, emit_lse)
+    return _from_lanes(out, v.shape, plan, 'v_'), lse

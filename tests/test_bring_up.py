@@ -35,6 +35,10 @@ def _run(args, env_extra=None, env_drop=(), timeout=600):
 
 # -- chip_smoke.py ----------------------------------------------------------
 
+# The rehearsal runs 85 s alone since it checks the grouped product too (PR 33)
+# and three times that beside five other workers: the subprocess's own limit,
+# not the hang guard's 120 s, is what bounds it.
+@pytest.mark.timeout(600)
 def test_chip_smoke_cpu_tiny_rehearsal_passes(tmp_path):
     """The sandbox rehearsal: every phase at toy size, kernels in interpret
     mode, ``platform=cpu`` on every line, and no bare result line a reader

@@ -1,0 +1,207 @@
+"""``models.moe.RoutedMoE``: dropless top-k routing over the experts held of
+a wider router. The shares of an expert-parallel group add up to the uncut
+layer of the plain reference, nothing is dropped whatever the routing, and
+the dispatch's tables say where every pair lies."""
+
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from petastorm_tpu import trace
+from petastorm_tpu.models import moe
+from petastorm_tpu.models.moe import RoutedMoE
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CONFIGS = os.path.join(ROOT, 'perfbench', 'configs')
+NAME = 'xing4-29b-a4b-ctx4096'
+
+
+@pytest.fixture(scope='module')
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        'xing4_reference', os.path.join(CONFIGS, NAME + '.reference.py'))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope='module')
+def cfg():
+    """The configuration's own file with every one of 16 experts held: the
+    uncut layer the shares are held against."""
+    cfg = json.load(open(os.path.join(CONFIGS, NAME + '.json')))
+    cfg.update(hidden_size=32, moe_intermediate_size=16, n_routed_experts=16)
+    cfg['published'] = dict(cfg['published'], n_routed_experts=16)
+    cfg['assumed'] = dict(cfg['assumed'], experts_held=list(range(16)))
+    return cfg
+
+
+def _layer_params(seed=0, d=32, f=16, experts=16):
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        return jnp.asarray(0.3 * rng.standard_normal(shape), jnp.float32)
+
+    return {'router': {'kernel': normal(d, experts)},
+            'shared': {'gate': {'kernel': normal(d, f)},
+                       'up': {'kernel': normal(d, f)},
+                       'down': {'kernel': normal(f, d)}},
+            'experts_gate_up': normal(experts, d, 2 * f),
+            'experts_down': normal(experts, f, d)}
+
+
+def _share(params, held, with_shared):
+    held = list(held)
+    p = {'router': params['router'],
+         'experts_gate_up': params['experts_gate_up'][jnp.asarray(held)],
+         'experts_down': params['experts_down'][jnp.asarray(held)]}
+    if with_shared:
+        p['shared'] = params['shared']
+    return p
+
+
+def _module(cfg, held, impl, shared=True, tile_m=8):
+    return RoutedMoE(
+        experts_published=cfg['published']['n_routed_experts'],
+        held=tuple(held), top_k=cfg['num_experts_per_tok'],
+        scale=cfg['routed_scaling_factor'], d_ff=cfg['moe_intermediate_size'],
+        shared_d_ff=cfg['moe_intermediate_size'] if shared else 0,
+        impl=impl, tile_m=tile_m, dtype=jnp.float32)
+
+
+@pytest.mark.parametrize('impl', ['pallas:interpret', 'ragged_dot'])
+def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
+        cfg, ref, impl):
+    """Eight chips hold two experts each of sixteen; every chip routes over
+    all sixteen and computes its own experts' part; the shared expert, which
+    every chip computes alike, is counted once. float32 on both sides, so
+    what differs is the order of sums: 2e-5 of the largest output."""
+    params = _layer_params()
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((2, 24, 32)),
+                    jnp.float32)
+    whole = ref._experts(params, x, cfg, None)
+    total, loads = 0.0, []
+    for chip in range(8):
+        held = [2 * chip, 2 * chip + 1]
+        y, load = _module(cfg, held, impl, shared=chip == 0).apply(
+            {'params': _share(params, held, chip == 0)}, x)
+        total = total + y
+        loads.append(np.asarray(load))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=2e-5 * float(jnp.abs(whole).max()), rtol=0)
+    # every pair of every token landed on exactly one chip
+    assert int(np.sum(loads)) == 2 * 24 * cfg['num_experts_per_tok']
+
+
+@pytest.mark.parametrize('impl', ['pallas:interpret', 'ragged_dot'])
+def test_a_share_s_gradients_equal_the_reference_s_for_the_same_share(
+        cfg, ref, impl):
+    """The cut as the benchmark's cell has it: some experts held, the
+    reference given the same share; loss and every leaf's gradient, the
+    router's through the weights included."""
+    held = [1, 2, 5, 11, 12]
+    params = _share(_layer_params(seed=2), held, True)
+    share = dict(cfg, n_routed_experts=len(held),
+                 assumed=dict(cfg['assumed'], experts_held=held))
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((1, 40, 32)),
+                    jnp.float32)
+    c = jnp.asarray(np.random.default_rng(4).standard_normal((1, 40, 32)),
+                    jnp.float32)
+    module = _module(cfg, held, impl)
+    got = jax.value_and_grad(lambda p, x: jnp.sum(
+        module.apply({'params': p}, x)[0] * c), argnums=(0, 1))(params, x)
+    want = jax.value_and_grad(lambda p, x: jnp.sum(
+        ref._experts(p, x, share, None) * c), argnums=(0, 1))(params, x)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(got[1]),
+                    jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=3e-5 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize('expert', [0, 3])
+@pytest.mark.parametrize('impl', ['pallas:interpret', 'ragged_dot'])
+def test_dropless_every_token_to_one_held_expert(impl, expert):
+    """All 96 tokens pick the same held expert (and three absent ones): a
+    capacity of 1.25 x 96 / 4 would drop 66 of them; here every token's
+    output is that expert's, and the other held experts see nothing."""
+    rng = np.random.default_rng(5)
+    d, f, held = 16, 8, (2, 4, 6, 9)
+    x = jnp.asarray(rng.standard_normal((96, d)), jnp.float32)
+    w1 = jnp.asarray(rng.standard_normal((4, d, 2 * f)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((4, f, d)), jnp.float32)
+    experts = jnp.tile(jnp.asarray([[held[expert], 0, 1, 3]], jnp.int32),
+                       (96, 1))
+    weights = jnp.tile(jnp.asarray([[0.7, 0.1, 0.1, 0.1]], jnp.float32),
+                       (96, 1))
+    y, counts = moe.routed_experts(x, experts, weights, w1, w2, held, 12,
+                                   tile_m=8, impl=impl)
+    hidden = x @ w1[expert]
+    want = 0.7 * ((jax.nn.silu(hidden[:, :f]) * hidden[:, f:]) @ w2[expert])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)
+    assert counts.tolist() == [96 if i == expert else 0 for i in range(4)]
+
+
+def test_the_dispatch_plan_places_every_held_pair_once():
+    rng = np.random.default_rng(6)
+    n, k, held, tile_m = 50, 4, (3, 7, 8, 15), 8
+    experts = jnp.asarray(np.stack([rng.permutation(16)[:k] for _ in range(n)]),
+                          jnp.int32)
+    plan = moe.dispatch_plan(experts, held, 16, tile_m)
+    is_held = np.isin(np.asarray(experts), held)
+    assert np.array_equal(np.asarray(plan.is_held), is_held)
+    counts = [int((np.asarray(experts) == e).sum()) for e in held]
+    assert plan.counts.tolist() == counts
+    assert plan.group_sizes.tolist() == [max(1, -(-c // tile_m)) * tile_m
+                                         for c in counts]
+    rows = n * k + len(held) * tile_m
+    assert plan.row_token.shape == plan.row_valid.shape == (rows,)
+    dest = np.asarray(plan.dest)[is_held]
+    assert len(set(dest.tolist())) == is_held.sum() == int(plan.row_valid.sum())
+    # a row knows its pair, and the pair knows its row
+    valid = np.asarray(plan.row_valid)
+    pair = np.asarray(plan.row_pair)[valid]
+    assert np.array_equal(np.asarray(plan.dest).reshape(-1)[pair],
+                          np.flatnonzero(valid))
+    assert np.array_equal(np.asarray(plan.row_token)[valid], pair // k)
+    # groups lie in the order of ``held``, each from a tile's first row
+    starts = np.cumsum([0] + plan.group_sizes.tolist()[:-1])
+    for slot, e in enumerate(held):
+        mine = np.asarray(plan.dest)[np.asarray(experts) == e]
+        assert sorted(mine.tolist()) == list(range(
+            starts[slot], starts[slot] + counts[slot]))
+
+
+def test_top_k_routing_normalises_over_the_picked_and_scales():
+    scores = jnp.asarray([[0.1, 0.8, 0.3, 0.6, 0.2]], jnp.float32)
+    experts, weights = moe.top_k_routing(scores, 2, scale=2.0)
+    assert experts.tolist() == [[1, 3]]
+    np.testing.assert_allclose(np.asarray(weights), [[2 * 0.8 / 1.4,
+                                                      2 * 0.6 / 1.4]], rtol=1e-6)
+    experts, weights = moe.top_k_routing(scores, 3, normalise=False)
+    assert experts.tolist() == [[1, 3, 2]]
+    np.testing.assert_allclose(np.asarray(weights), [[0.8, 0.6, 0.3]],
+                               rtol=1e-6)
+
+
+def test_the_expert_load_counter_writes_running_totals_a_step_late():
+    tracer = trace.Tracer(spill_dir=False)
+    previous = trace.set_global_tracer(tracer)
+    try:
+        counter = moe.ExpertLoadCounter()
+        for step in range(5):
+            counter.add({'expert_load': jnp.asarray([step, 10], jnp.int32)})
+    finally:
+        trace.set_global_tracer(previous)
+    records = [r for r in tracer.records() if r[0].startswith('moe.expert_load')]
+    # five steps handed in, three read (the last LOAD_LAG may be in flight)
+    assert moe.LOAD_LAG == 2
+    assert [r[3] for r in records if r[0].endswith('.e0')] == [0, 1, 3]
+    assert [r[3] for r in records if r[0].endswith('.e1')] == [10, 20, 30]
+    assert all(r[1] == 'step' and len(r) == 4 for r in records)
