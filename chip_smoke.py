@@ -15,7 +15,8 @@
   ``create_train_state`` / ``make_train_step`` / ``make_scan_train_step``,
 * runs an epoch out of ``DeviceDatasetCache`` and compares it with the
   streamed one,
-* compiles the Pallas kernels (flash attention forward and backward,
+* compiles the Pallas kernels (flash attention forward and backward, the
+  grouped product, a sub-layer between its hyper-connection maps,
   ``normalize_images``) and checks them against their XLA references.
 
 Nothing in it catches a failure to go on: the first phase that fails ends
@@ -67,6 +68,8 @@ FULL = {
     # xing4.tokens4k's first product: pairs of 4,096 tokens x top 4 against
     # eight experts, tiles of 128 rows (tokens, top_k, experts, k, n, tile)
     'grouped': (4096, 4, 8, 3584, 2048, 128),
+    # xing4.tokens4k's residual: 4,096 tokens of four 3584-wide streams
+    'streams': (4096, 4, 3584),
 }
 TINY = {
     'image_size': 32, 'per_chip': 4, 'classes': 10, 'resnet': 'ResNetTiny',
@@ -80,6 +83,7 @@ TINY = {
               ('f32-ragged-T50', 'float32', 2, 50, 2, 16)],
     'normalize': [(8, 16, 16, 3), (5, 10, 10, 3)],
     'grouped': (24, 2, 3, 32, 128, 8),
+    'streams': (48, 4, 128),
 }
 
 # Max |kernel - reference| over max |reference|, forward and input gradients,
@@ -95,6 +99,9 @@ FLASH_GRAD_TOL = 5e-3 + 2 ** -7
 # Both routes accumulate a product in float32 and round it once to bf16: they
 # differ by the order of the sum, an ulp of bf16 at the largest value.
 GROUPED_TOL = 2 ** -7
+# The kernels round the streams' gradient once where the jax.numpy
+# formulation rounds it in three places (and dPhi once more): two bf16 ulps.
+STREAMS_TOL = 2 ** -6
 
 
 class Run(object):
@@ -732,6 +739,64 @@ def _grouped_product_check(run, assert_mosaic):
     return [] if ok else ['grouped']
 
 
+def _stream_sub_layer_check(run, assert_mosaic):
+    """A sub-layer between its hyper-connection maps through the kernels of
+    ``ops.hyper_connections`` against the ``jax.numpy`` formulation at the
+    shape ``xing4.tokens4k`` runs it, the maps as the configuration starts
+    them: forward, and the gradients of the streams, of the sub-layer's own
+    weights and of every leaf of the maps, bf16."""
+    import jax
+    import jax.numpy as jnp
+
+    from petastorm_tpu.models.latent_moe import (StreamMaps, StreamSubLayer,
+                                                 mix_streams)
+
+    tokens, n, d = run.cfg['streams']
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    x = jax.random.normal(keys[0], (1, tokens, n * d), jnp.bfloat16)
+    w = (jax.random.normal(keys[1], (d, d), jnp.float32)
+         / np.sqrt(d)).astype(jnp.bfloat16)
+    c = jax.random.normal(keys[2], (1, tokens, n * d), jnp.bfloat16)
+    start = dict(alpha_init=0.1, res_diagonal_init=4.0)
+    sub = StreamSubLayer(streams=n, **start)
+    params = sub.init(keys[3], x, lambda inner: inner)['params']
+
+    def fn(w):
+        return lambda inner: jnp.tanh(inner @ w)
+
+    def kernels(params, x, w):
+        return sub.apply({'params': params}, x, fn(w))[0]
+
+    def plain(params, x, w):
+        x4 = x.reshape(1, tokens, n, d)
+        out, _ = mix_streams(x4, *StreamMaps(**start).apply(
+            {'params': params}, x4), fn(w))
+        return out.reshape(x.shape)
+
+    def both(route):
+        def loss(params, x, w):
+            out = route(params, x, w)
+            return jnp.sum((out * c).astype(jnp.float32)), out
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                          has_aux=True))
+
+    kernel = both(kernels)
+    assert_mosaic('streams', kernel.lower(params, x, w))
+    (_, out), grads = run.compile('streams.fwd+bwd', kernel, params, x, w)(
+        params, x, w)
+    (_, want), want_grads = both(plain)(params, x, w)
+    fwd_err = _rel_err(out, want)
+    errs = jax.tree_util.tree_map(_rel_err, grads, want_grads)
+    grad_err = max(jax.tree_util.tree_leaves(errs))
+    run.say('stream sub-layer [{} tokens x {} streams of {}, bf16]: fwd err '
+            '{:.4%}, grad err {:.4%} over {} leaves (tol {:.2%}) against '
+            'jax.numpy'.format(tokens, n, d, fwd_err, grad_err,
+                               len(jax.tree_util.tree_leaves(errs)),
+                               STREAMS_TOL))
+    ok = fwd_err <= STREAMS_TOL and grad_err <= STREAMS_TOL
+    return [] if ok else ['streams']
+
+
 def phase_kernels(run):
     import jax
     import jax.numpy as jnp
@@ -782,6 +847,7 @@ def phase_kernels(run):
             failures.append(name)
 
     failures += _grouped_product_check(run, assert_mosaic)
+    failures += _stream_sub_layer_check(run, assert_mosaic)
 
     for shape in run.cfg['normalize']:
         images = jax.random.randint(jax.random.PRNGKey(shape[0]), shape, 0,

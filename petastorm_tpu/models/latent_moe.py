@@ -6,18 +6,26 @@ Xing4.0's layout), told which share of each layer it holds.
 'metrics': {'expert_load': [experts held]}}``, every logits ``[B, T, vocab
 rows held]`` float32. RMSNorm throughout, SwiGLU feed-forward, no bias.
 
-**Streams** (:class:`StreamMaps`; arXiv:2512.24880 after arXiv:2409.19606).
-The residual is ``n`` streams ``X [B, T, n, d]``, the embedding copied ``n``
-times at the start and the streams summed before the final norm. Each
-sub-layer ``F`` has three maps of its own, made per token from ``x~ =
-rmsnorm(vec(X_t))``: ``H_pre = sigmoid(a x~ Phi_pre + b)`` weighs the streams
-into the sub-layer's one input, ``H_post = 2 sigmoid(...)`` spreads its
-output over them, and ``H_res = sinkhorn(exp(clip(a mat(x~ Phi_res) + b)))``,
-a doubly stochastic ``n x n`` matrix, mixes the streams that pass it by:
-``X <- H_res X + H_post^T F(rmsnorm(H_pre X))``. The product ``x~ Phi`` takes
-``dtype`` operands and accumulates in float32; the maps' sigmoids, Sinkhorn's
-iterations and the mixings are float32 ``jax.numpy`` (XLA fusions); the
-streams, and so their gradients, are kept in ``dtype`` between sub-layers.
+**Streams** (:class:`StreamSubLayer`; arXiv:2512.24880 after
+arXiv:2409.19606). The residual is ``n`` streams side by side in the lanes of
+``X [B, T, n d]`` (stream ``j`` the lanes ``j d .. (j + 1) d``), the
+embedding copied ``n`` times at the start and the streams summed before the
+final norm. Each sub-layer ``F`` has three maps of its own, made per token
+from ``x~ = rmsnorm(X_t)`` over all ``n d`` values: ``H_pre = sigmoid(a x~
+Phi_pre + b)`` weighs the streams into the sub-layer's one input, ``H_post =
+2 sigmoid(...)`` spreads its output over them, and ``H_res =
+sinkhorn(exp(clip(a mat(x~ Phi_res) + b)))``, a doubly stochastic ``n x n``
+matrix, mixes the streams that pass it by: ``X <- H_res X + H_post^T
+F(rmsnorm(H_pre X))``. The product ``x~ Phi`` takes ``dtype`` operands and
+accumulates in float32; the maps' sigmoids, Sinkhorn's iterations and the
+mixings are float32; the streams, and so their gradients, are kept in
+``dtype`` between sub-layers. Where a stream is whole vregs wide (``d % 128
+== 0``) a sub-layer's maps, Sinkhorn and mixings run as the Pallas kernels of
+:mod:`petastorm_tpu.ops.hyper_connections` (two a pass; interpreted off a
+TPU), which read the streams from HBM once each and keep nothing ``n`` wide
+there; at other widths as ``jax.numpy`` (:class:`StreamMaps`,
+:func:`mix_streams`: XLA fusions, and what the kernels are tested against).
+``model.layer_plan``'s ``stream_mixing`` says which.
 
 **Latent attention** (:class:`LatentAttention`): queries through a
 ``q_rank`` latent and keys and values through a ``kv_rank`` latent, an
@@ -56,9 +64,11 @@ import flax.linen as nn
 import jax.numpy as jnp
 import numpy as np
 
-from petastorm_tpu.models.hybrid import RMSNorm, SwiGLU, _projection
+from petastorm_tpu.models.hybrid import (RMSNorm, SwiGLU, _projection,
+                                         rms_normalise)
 from petastorm_tpu.models.moe import RoutedMoE
 from petastorm_tpu.models.transformer import self_attention
+from petastorm_tpu.ops import hyper_connections
 from petastorm_tpu.ops.grouped_matmul import TILE_M
 from petastorm_tpu.trace import get_global_tracer
 
@@ -123,10 +133,10 @@ def sinkhorn(logits, iterations, eps):
     return m
 
 
-class StreamMaps(nn.Module):
-    """The three maps of one sub-layer from the streams ``X [B, T, n, d]``:
-    ``(H_pre [B, T, n], H_post [B, T, n], H_res [B, T, n, n])``, float32
-    (``x~ Phi`` from ``dtype`` operands, accumulated in float32)."""
+class _StreamLeaves(nn.Module):
+    """What a sub-layer's maps are made from, under the names the benchmark's
+    weights have: ``norm/scale``, ``phi_pre``, ``phi_post``, ``phi_res``,
+    ``alpha_*``, ``b_*``."""
     sinkhorn_iterations: int = 20
     eps: float = 1e-6
     clamp: Sequence[float] = (-30.0, 30.0)
@@ -134,33 +144,58 @@ class StreamMaps(nn.Module):
     res_diagonal_init: float = 0.0
     dtype: Any = jnp.bfloat16
 
-    @nn.compact
-    def __call__(self, x):
-        b, t, n, d = x.shape
-        flat = RMSNorm(dtype=self.dtype, name='norm')(x.reshape(b, t, n * d))
+    def leaves(self, n, d):
         init = nn.initializers.normal(0.02)
-        phi = jnp.concatenate(
-            [self.param('phi_' + name, init, (n * d, width))
-             for name, width in (('pre', n), ('post', n), ('res', n * n))],
-            axis=-1).astype(self.dtype)
-        maps = jnp.einsum('btk,km->btm', flat, phi,
-                          preferred_element_type=jnp.float32)
-
-        def alpha(name):
-            return self.param('alpha_' + name, nn.initializers.constant(
-                self.alpha_init), ())
-
-        b_pre = self.param('b_pre', nn.initializers.zeros, (n,))
-        b_post = self.param('b_post', nn.initializers.zeros, (n,))
-        b_res = self.param(
+        out = {'scale': _Scale(name='norm')(n * d)}
+        for name, width in (('pre', n), ('post', n), ('res', n * n)):
+            out['phi_' + name] = self.param('phi_' + name, init,
+                                            (n * d, width))
+            out['alpha_' + name] = self.param(
+                'alpha_' + name, nn.initializers.constant(self.alpha_init), ())
+        out['b_pre'] = self.param('b_pre', nn.initializers.zeros, (n,))
+        out['b_post'] = self.param('b_post', nn.initializers.zeros, (n,))
+        out['b_res'] = self.param(
             'b_res', lambda key, shape: self.res_diagonal_init * jnp.eye(n),
             (n, n))
-        pre = nn.sigmoid(alpha('pre') * maps[..., :n] + b_pre)
-        post = 2.0 * nn.sigmoid(alpha('post') * maps[..., n:2 * n] + b_post)
-        res = alpha('res') * maps[..., 2 * n:].reshape(b, t, n, n) + b_res
+        return out
+
+    def maps(self, x, leaves):
+        """The ``jax.numpy`` formulation: ``x [B, T, n, d] -> (H_pre [B, T,
+        n], H_post [B, T, n], H_res [B, T, n, n])``, float32."""
+        b, t, n, d = x.shape
+        flat = rms_normalise(x.reshape(b, t, n * d).astype(self.dtype),
+                             leaves['scale'])
+        phi = jnp.concatenate([leaves['phi_' + name] for name in
+                               ('pre', 'post', 'res')], axis=-1)
+        maps = jnp.einsum('btk,km->btm', flat, phi.astype(self.dtype),
+                          preferred_element_type=jnp.float32)
+        pre = nn.sigmoid(leaves['alpha_pre'] * maps[..., :n] + leaves['b_pre'])
+        post = 2.0 * nn.sigmoid(leaves['alpha_post'] * maps[..., n:2 * n]
+                                + leaves['b_post'])
+        res = leaves['alpha_res'] * maps[..., 2 * n:].reshape(b, t, n, n) \
+            + leaves['b_res']
         res = sinkhorn(jnp.clip(res, *self.clamp), self.sinkhorn_iterations,
                        self.eps)
         return pre, post, res
+
+
+class _Scale(nn.Module):
+    """An rmsnorm's ``scale`` leaf where the norm itself runs elsewhere."""
+
+    @nn.compact
+    def __call__(self, width):
+        return self.param('scale', nn.initializers.ones, (width,))
+
+
+class StreamMaps(_StreamLeaves):
+    """The three maps of one sub-layer from the streams ``X [B, T, n, d]``:
+    ``(H_pre [B, T, n], H_post [B, T, n], H_res [B, T, n, n])``, float32
+    (``x~ Phi`` from ``dtype`` operands, accumulated in float32), in plain
+    ``jax.numpy``: what the kernels are held against."""
+
+    @nn.compact
+    def __call__(self, x):
+        return self.maps(x, self.leaves(*x.shape[2:]))
 
 
 def mix_streams(x, pre, post, res, fn):
@@ -177,6 +212,31 @@ def mix_streams(x, pre, post, res, fn):
     mixed = sum(res[..., j, None] * x32[:, :, j, None, :] for j in range(n))
     out = mixed + post[..., None] * y.astype(jnp.float32)[:, :, None, :]
     return out.astype(x.dtype), extra
+
+
+class StreamSubLayer(_StreamLeaves):
+    """``X [B, T, n d] -> (X', extra)``: the streams, side by side in the
+    lanes, through the sub-layer ``fn`` between its maps. Streams of whole
+    vregs (``d % 128 == 0``) go through the kernels of
+    ``ops.hyper_connections``, others through :meth:`maps` and
+    :func:`mix_streams`: one algorithm, chosen by the width it is handed."""
+    streams: int = 4
+    mesh: Any = None
+    batch_axis: Optional[str] = 'data'
+
+    @nn.compact
+    def __call__(self, x, fn):
+        b, t, width = x.shape
+        n, d = self.streams, width // self.streams
+        leaves = self.leaves(n, d)
+        if hyper_connections.implementation(n, d) == 'xla':
+            x = x.reshape(b, t, n, d)
+            x, extra = mix_streams(x, *self.maps(x, leaves), fn)
+            return x.reshape(b, t, width), extra
+        return hyper_connections.hyper_connection(
+            x.astype(self.dtype), fn, leaves, n, self.sinkhorn_iterations,
+            self.eps, tuple(self.clamp), mesh=self.mesh,
+            batch_axis=self.batch_axis)
 
 
 class LatentAttention(nn.Module):
@@ -223,7 +283,7 @@ class LatentAttention(nn.Module):
 
 
 class LatentMoEBlock(nn.Module):
-    """``X [B, T, n, d] -> (X, expert_load [G])``: an attention sub-layer
+    """``X [B, T, n d] -> (X, expert_load [G])``: an attention sub-layer
     and a feed-forward (``kind='dense'``) or expert (``'moe'``) sub-layer,
     each between its own stream maps."""
     kind: str
@@ -235,26 +295,31 @@ class LatentMoEBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        def attend(inner):
-            # Named as TransformerLM names its attention: a device trace
-            # names the flash kernels ``attn*`` in every model.
-            return LatentAttention(dtype=self.dtype, name='attn',
-                                   **self.attention_args)(
-                RMSNorm(dtype=self.dtype, name='attn_norm')(inner))
-
-        def feed_forward(inner):
-            inner = RMSNorm(dtype=self.dtype, name='ffn_norm')(inner)
-            if self.kind == 'dense':
-                return SwiGLU(self.d_ff, dtype=self.dtype, name='mlp')(inner)
-            return RoutedMoE(dtype=self.dtype, name='moe',
-                             **self.moe_args)(inner)
-
         if self.kind not in ('dense', 'moe'):
             raise ValueError('unknown layer kind {!r}'.format(self.kind))
-        maps = StreamMaps(dtype=self.dtype, name='attn_hc', **self.maps_args)
-        x, _ = mix_streams(x, *maps(x), attend)
-        maps = StreamMaps(dtype=self.dtype, name='ffn_hc', **self.maps_args)
-        x, load = mix_streams(x, *maps(x), feed_forward)
+        # Made here and called inside the sub-layers' wrappers: a module
+        # belongs to the one it is made in. Named as TransformerLM names its
+        # attention: a device trace names the flash kernels ``attn*`` in
+        # every model.
+        attn_norm = RMSNorm(dtype=self.dtype, name='attn_norm')
+        attn = LatentAttention(dtype=self.dtype, name='attn',
+                               **self.attention_args)
+        ffn_norm = RMSNorm(dtype=self.dtype, name='ffn_norm')
+        if self.kind == 'dense':
+            ffn = SwiGLU(self.d_ff, dtype=self.dtype, name='mlp')
+        else:
+            ffn = RoutedMoE(dtype=self.dtype, name='moe', **self.moe_args)
+
+        def attend(inner):
+            return attn(attn_norm(inner))
+
+        def feed_forward(inner):
+            return ffn(ffn_norm(inner))
+
+        x, _ = StreamSubLayer(dtype=self.dtype, name='attn_hc',
+                              **self.maps_args)(x, attend)
+        x, load = StreamSubLayer(dtype=self.dtype, name='ffn_hc',
+                                 **self.maps_args)(x, feed_forward)
         if load is None:
             load = jnp.zeros((len(self.moe_args['held']),), jnp.int32)
         return x, load
@@ -275,11 +340,20 @@ class NextTokenModule(nn.Module):
                                axis=-1)
         x = _projection(both, h.shape[-1], 'eh_proj', self.dtype)
         x, load = self.block(name='block')(_copies(x, self.streams))
-        return jnp.sum(x.astype(jnp.float32), axis=2).astype(self.dtype), load
+        return _summed(x, self.streams).astype(self.dtype), load
 
 
 def _copies(h, n):
-    return jnp.broadcast_to(h[:, :, None, :], h.shape[:2] + (n,) + h.shape[2:])
+    """``h [B, T, d]`` as ``n`` equal streams ``[B, T, n d]`` (joined, not
+    tiled: XLA makes a tile a 4-D broadcast and then copies it flat)."""
+    return jnp.concatenate([h] * n, axis=-1)
+
+
+def _summed(x, n):
+    """The ``n`` streams of ``x [B, T, n d]`` added up, float32."""
+    d = x.shape[-1] // n
+    return sum(x[..., j * d:(j + 1) * d].astype(jnp.float32)
+               for j in range(n))
 
 
 _plans_reported = set()
@@ -339,7 +413,9 @@ class LatentMoELM(nn.Module):
                 'streams': self.streams,
                 'next_token_depth': self.nextn,
                 'recompute': bool(self.remat),
-                'attention': self.attention, 'experts': self.experts}
+                'attention': self.attention, 'experts': self.experts,
+                'stream_mixing': hyper_connections.implementation(
+                    self.streams, self.d_model)}
 
     @nn.compact
     def __call__(self, tokens, train=True):
@@ -361,10 +437,12 @@ class LatentMoELM(nn.Module):
             softmax_scale=yarn_softmax_scale(
                 self.nope + self.rope, self.rope_factor,
                 self.rope_mscale_all_dim), **shared)
-        maps_args = dict(sinkhorn_iterations=self.sinkhorn_iterations,
+        maps_args = dict(streams=self.streams,
+                         sinkhorn_iterations=self.sinkhorn_iterations,
                          eps=self.stream_eps, clamp=tuple(self.stream_clamp),
                          alpha_init=self.stream_alpha_init,
-                         res_diagonal_init=self.stream_res_diagonal_init)
+                         res_diagonal_init=self.stream_res_diagonal_init,
+                         **shared)
         moe_args = dict(experts_published=self.experts_published,
                         held=tuple(self.experts_held), top_k=self.top_k,
                         scale=self.routed_scale, d_ff=self.expert_d_ff,
@@ -388,7 +466,7 @@ class LatentMoELM(nn.Module):
         for i, kind in enumerate(plan['layer_kinds']):
             x, counts = block(kind, 'block_{}'.format(i))(x)
             load = load + counts
-        h = jnp.sum(x.astype(jnp.float32), axis=2).astype(self.dtype)
+        h = _summed(x, self.streams).astype(self.dtype)
         logits = [head(final_norm(h)).astype(jnp.float32)]
         for depth in range(self.nextn):
             h, counts = NextTokenModule(

@@ -306,7 +306,8 @@ def test_the_layer_plan_instant_says_what_was_built(cfg, program, tokens,
         'heads_published': 4, 'experts_held': [1, 2, 5, 6],
         'experts_published': 8, 'top_k': 4, 'vocab_rows_held': 128,
         'streams': 4, 'next_token_depth': 1, 'recompute': True,
-        'attention': 'flash:interpret', 'experts': 'pallas:interpret'}
+        'attention': 'flash:interpret', 'experts': 'pallas:interpret',
+        'stream_mixing': 'xla'}      # 64-wide streams are no whole vregs
 
 
 # -- the flash kernels with keys and values of two widths ----------------------
