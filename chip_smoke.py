@@ -16,8 +16,9 @@
 * runs an epoch out of ``DeviceDatasetCache`` and compares it with the
   streamed one,
 * compiles the Pallas kernels (flash attention forward and backward, the
-  grouped product, a sub-layer between its hyper-connection maps,
-  ``normalize_images``) and checks them against their XLA references.
+  grouped product, a sub-layer between its hyper-connection maps, Kimi
+  delta attention's rule with every gate at its bound, ``normalize_images``)
+  and checks them against their XLA references.
 
 Nothing in it catches a failure to go on: the first phase that fails ends
 the run with a non-zero exit code. It reports compile seconds per program,
@@ -70,6 +71,9 @@ FULL = {
     'grouped': (4096, 4, 8, 3584, 2048, 128),
     # xing4.tokens4k's residual: 4,096 tokens of four 3584-wide streams
     'streams': (4096, 4, 3584),
+    # ling3.tokens8k's rule: 8,192 tokens, 32 heads of 128, chunks of 64 in
+    # sub-blocks of 16 (tokens, heads, width, chunk, sub-block)
+    'kda': (8192, 32, 128, 64, 16),
 }
 TINY = {
     'image_size': 32, 'per_chip': 4, 'classes': 10, 'resnet': 'ResNetTiny',
@@ -84,6 +88,7 @@ TINY = {
     'normalize': [(8, 16, 16, 3), (5, 10, 10, 3)],
     'grouped': (24, 2, 3, 32, 128, 8),
     'streams': (48, 4, 128),
+    'kda': (40, 2, 16, 16, 4),
 }
 
 # Max |kernel - reference| over max |reference|, forward and input gradients,
@@ -102,6 +107,11 @@ GROUPED_TOL = 2 ** -7
 # The kernels round the streams' gradient once where the jax.numpy
 # formulation rounds it in three places (and dPhi once more): two bf16 ulps.
 STREAMS_TOL = 2 ** -6
+
+
+# Both routes run the same chunk bodies in bfloat16 (the kernels through
+# Mosaic, the jax.numpy form through XLA): an ulp of bf16 at the largest value.
+KDA_TOL = 2 ** -7
 
 
 class Run(object):
@@ -797,6 +807,57 @@ def _stream_sub_layer_check(run, assert_mosaic):
     return [] if ok else ['streams']
 
 
+def _kimi_delta_check(run, assert_mosaic):
+    """The Pallas kernels of ``ops.kimi_delta`` against the ``jax.numpy``
+    form of the same chunked rule at the shape ``ling3.tokens8k`` runs it,
+    with every token's gate at its bound (the case the sub-blocks exist
+    for: a decay of e^-5 a token and channel, e^-320 over a chunk): forward
+    and all five gradients finite and equal, bf16."""
+    import jax
+    import jax.numpy as jnp
+
+    from petastorm_tpu.ops.kimi_delta import GATE_LOWER_BOUND, kda_rule
+
+    t, h, d, chunk, sub = run.cfg['kda']
+    keys = jax.random.split(jax.random.PRNGKey(13), 6)
+
+    def unit(a):
+        return a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+
+    q = (unit(jax.random.normal(keys[0], (1, t, h, d))) * d ** -0.5).astype(
+        jnp.bfloat16)
+    k = unit(jax.random.normal(keys[1], (1, t, h, d))).astype(jnp.bfloat16)
+    v = jax.random.normal(keys[2], (1, t, h, d), jnp.bfloat16)
+    g = GATE_LOWER_BOUND + 1e-3 * jax.random.uniform(keys[3], (1, t, h, d))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], (1, t, h)))
+    c = jax.random.normal(keys[5], (1, t, h, d), jnp.bfloat16)
+
+    def rule(impl):
+        def loss(q, k, v, g, beta):
+            o = kda_rule(q, k, v, g, beta, chunk=chunk, sub_block=sub,
+                         impl=impl)
+            return jnp.sum((o * c).astype(jnp.float32)), o
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
+                                          has_aux=True))
+
+    kernel = rule('pallas:interpret' if run.interpret else 'pallas')
+    assert_mosaic('kda', kernel.lower(q, k, v, g, beta))
+    (_, o), grads = run.compile('kda.fwd+bwd', kernel, q, k, v, g, beta)(
+        q, k, v, g, beta)
+    (_, want), want_grads = rule('chunked')(q, k, v, g, beta)
+    finite = all(bool(jnp.isfinite(a.astype(jnp.float32)).all())
+                 for a in (o,) + tuple(grads))
+    fwd_err = _rel_err(o, want)
+    grad_err = max(_rel_err(a, b) for a, b in zip(grads, want_grads))
+    run.say('kimi delta rule [{} tokens x {} heads of {}, chunks of {} in '
+            'sub-blocks of {}, every gate within 1e-3 of {}, bf16]: finite '
+            '{}, fwd err {:.4%}, grad err {:.4%} (tol {:.2%}) against '
+            'jax.numpy'.format(t, h, d, chunk, sub, GATE_LOWER_BOUND, finite,
+                               fwd_err, grad_err, KDA_TOL))
+    ok = finite and fwd_err <= KDA_TOL and grad_err <= KDA_TOL
+    return [] if ok else ['kda']
+
+
 def phase_kernels(run):
     import jax
     import jax.numpy as jnp
@@ -848,6 +909,7 @@ def phase_kernels(run):
 
     failures += _grouped_product_check(run, assert_mosaic)
     failures += _stream_sub_layer_check(run, assert_mosaic)
+    failures += _kimi_delta_check(run, assert_mosaic)
 
     for shape in run.cfg['normalize']:
         images = jax.random.randint(jax.random.PRNGKey(shape[0]), shape, 0,

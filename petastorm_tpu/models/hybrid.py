@@ -82,6 +82,20 @@ def causal_conv_silu(x, kernel):
     return nn.silu(y).astype(x.dtype)
 
 
+def over_row_shards(rule, mesh, batch_axis, impl, *operands):
+    """``rule(*operands)``, mapped over the batch's shards where a mesh is
+    given and the rule runs Pallas kernels: as for flash attention, a Pallas
+    call is opaque to the SPMD partitioner, and rows are independent. Every
+    operand and the result lead with the batch."""
+    if mesh is None or not impl.startswith('pallas'):
+        return rule(*operands)
+    axis = usable_axis(mesh, batch_axis, operands[0].shape[0])
+    specs = tuple(PartitionSpec(axis, *(None,) * (a.ndim - 1))
+                  for a in operands)
+    return jax.shard_map(rule, mesh=mesh, in_specs=specs, out_specs=specs[0],
+                         check_vma=impl == 'pallas')(*operands)
+
+
 class GatedDeltaRule(nn.Module):
     """The rule itself, in a module of its own so that a device trace names
     its Pallas calls by the module's name (``gdn``)."""
@@ -98,17 +112,8 @@ class GatedDeltaRule(nn.Module):
             return gated_delta_rule(q, k, v, g, beta, chunk=self.chunk,
                                     impl=self.impl)
 
-        if self.mesh is not None and self.impl.startswith('pallas'):
-            # As for flash attention: a Pallas call is opaque to the SPMD
-            # partitioner, and rows are independent.
-            axis = usable_axis(self.mesh, self.batch_axis, q.shape[0])
-            wide, flat = (PartitionSpec(axis, None, None, None),
-                          PartitionSpec(axis, None, None))
-            rule = jax.shard_map(rule, mesh=self.mesh,
-                                 in_specs=(wide, wide, wide, flat, flat),
-                                 out_specs=wide,
-                                 check_vma=self.impl == 'pallas')
-        return rule(q, k, v, g, beta)
+        return over_row_shards(rule, self.mesh, self.batch_axis, self.impl,
+                               q, k, v, g, beta)
 
 
 class GatedDeltaMixer(nn.Module):

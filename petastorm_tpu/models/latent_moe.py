@@ -28,10 +28,10 @@ there; at other widths as ``jax.numpy`` (:class:`StreamMaps`,
 ``model.layer_plan``'s ``stream_mixing`` says which.
 
 **Latent attention** (:class:`LatentAttention`): queries through a
-``q_rank`` latent and keys and values through a ``kv_rank`` latent, an
-RMSNorm on each; a head's key is ``nope`` content lanes from the latent and
-``rope`` rotary lanes that all heads share (rotated by yarn-scaled
-frequencies), its value ``v_dim`` wide. The flash kernel takes keys and
+``q_rank`` latent (``None``: straight from ``x``) and keys and values through
+a ``kv_rank`` latent, an RMSNorm on each; a head's key is ``nope`` content
+lanes from the latent and ``rope`` rotary lanes that all heads share (rotated
+by yarn-scaled frequencies), its value ``v_dim`` wide. The flash kernel takes keys and
 values of two widths (``ops.flash_attention``); the scale is ``(nope +
 rope) ** -0.5 * mscale ** 2``.
 
@@ -241,7 +241,7 @@ class StreamSubLayer(_StreamLeaves):
 
 class LatentAttention(nn.Module):
     heads_held: int
-    q_rank: int = 768
+    q_rank: Optional[int] = 768         # None: no query latent, one x W_q
     kv_rank: int = 512
     nope: int = 128
     rope: int = 64
@@ -257,9 +257,14 @@ class LatentAttention(nn.Module):
     def __call__(self, x):
         d_model, h = x.shape[-1], self.heads_held
         x = x.astype(self.dtype)
-        c_q = RMSNorm(dtype=self.dtype, name='q_norm')(
-            _projection(x, self.q_rank, 'q_down', self.dtype))
-        q = _projection(c_q, (h, self.nope + self.rope), 'q_up', self.dtype)
+        if self.q_rank is None:
+            q = _projection(x, (h, self.nope + self.rope), 'q_proj',
+                            self.dtype)
+        else:
+            c_q = RMSNorm(dtype=self.dtype, name='q_norm')(
+                _projection(x, self.q_rank, 'q_down', self.dtype))
+            q = _projection(c_q, (h, self.nope + self.rope), 'q_up',
+                            self.dtype)
         kv = _projection(x, self.kv_rank + self.rope, 'kv_down', self.dtype)
         c_kv = RMSNorm(dtype=self.dtype, name='kv_norm')(
             kv[..., :self.kv_rank])

@@ -144,12 +144,24 @@ def expert_param_spec(path, value, mesh):
 # dropless top-k routing over the experts held here
 # --------------------------------------------------------------------------
 
-def top_k_routing(scores, top_k, scale=1.0, normalise=True):
+def top_k_routing(scores, top_k, scale=1.0, normalise=True, n_group=1,
+                  topk_group=1):
     """``scores [..., E]`` float32 (one a published expert) -> ``(experts
     [..., k] int32, weights [..., k] float32)``: the ``top_k`` scores, each
     weighted by its own score over the picked scores' sum (``normalise``)
-    times ``scale``."""
-    _, experts = jax.lax.top_k(scores, top_k)
+    times ``scale``. With ``n_group`` > 1 the selection is limited by groups
+    (DeepSeek-V3's ``noaux_tc``): the experts lie in ``n_group`` equal groups
+    in their order, a group's score is the sum of its two best, and the top
+    ``top_k`` are taken among the experts of the best ``topk_group`` groups."""
+    allowed = scores
+    if n_group > 1:
+        grouped = scores.reshape(scores.shape[:-1] + (n_group, -1))
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        _, groups = jax.lax.top_k(group_score, topk_group)
+        kept = jnp.any(groups[..., None] == jnp.arange(n_group), axis=-2)
+        allowed = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+            scores.shape)
+    _, experts = jax.lax.top_k(allowed, top_k)
     picked = jnp.take_along_axis(scores, experts, axis=-1)
     if normalise:
         picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
@@ -284,9 +296,11 @@ class RoutedMoE(nn.Module):
     experts it holds: ``[B, T, d] -> ([B, T, d], expert_load [G])``.
 
     The router is ``experts_published`` wide whatever is held: ``s =
-    sigmoid(W_r x)`` in float32, the ``top_k`` experts of each token, their
-    scores normalised over the picked and times ``scale``. ``held`` lists the
-    published experts that live here (a chip of an expert-parallel group);
+    sigmoid(W_r x)`` in float32, the ``top_k`` experts of each token (among
+    the best ``topk_group`` of ``n_group`` groups where there are groups:
+    :func:`top_k_routing`), their scores normalised over the picked and times
+    ``scale``. ``held`` lists the published experts that live here (a chip
+    of an expert-parallel group);
     the layer computes ``shared(x) + sum over a token's picked experts that
     are held of weight * expert(x)`` and nothing for the absent ones: the
     partial result of the chip before the group's exchange, with the shared
@@ -310,6 +324,8 @@ class RoutedMoE(nn.Module):
     d_ff: int = 1024                    # an expert's width
     shared_d_ff: int = 0                # the shared expert's; 0: none
     normalise: bool = True
+    n_group: int = 1                    # groups the selection is limited by
+    topk_group: int = 1
     impl: str = 'pallas'
     tile_m: int = TILE_M
     mesh: Any = None
@@ -329,7 +345,8 @@ class RoutedMoE(nn.Module):
             precision=jax.lax.Precision.HIGHEST, name='router')(
                 x.astype(jnp.float32)))
         experts, weights = top_k_routing(scores, self.top_k, self.scale,
-                                         self.normalise)
+                                         self.normalise, self.n_group,
+                                         self.topk_group)
         init = nn.initializers.normal(0.02)
         w_gate_up = self.param('experts_gate_up', init,
                                (g, d, 2 * self.d_ff)).astype(self.dtype)

@@ -105,17 +105,21 @@ def chunk_plan(t, heads_held, dk, dv, chunk, impl, dtype):
             'impl': impl, 'dtype': dtype}
 
 
-def _plan_for(q, v, chunk, impl):
-    """The first time a process traces the rule with a plan, one
-    ``kernel.gdn_plan`` instant on the global tracer carries it (a model's
-    layers share one plan, so one record, not one a layer or a pass)."""
-    _, t, h, dk = q.shape
-    key = (t, h, dk, v.shape[-1], chunk, impl, jnp.dtype(q.dtype).name)
-    plan = chunk_plan(*key)
+def report_plan(name, plan):
+    """One ``name`` instant on the global tracer the first time a process
+    traces a rule with ``plan`` (a model's layers share one plan, so one
+    record, not one a layer or a pass)."""
+    key = (name,) + tuple(sorted(plan.items()))
     if key not in _plans_reported:
         _plans_reported.add(key)
-        get_global_tracer().instant('kernel.gdn_plan', cat='kernel', args=plan)
+        get_global_tracer().instant(name, cat='kernel', args=plan)
     return plan
+
+
+def _plan_for(q, v, chunk, impl):
+    _, t, h, dk = q.shape
+    return report_plan('kernel.gdn_plan', chunk_plan(
+        t, h, dk, v.shape[-1], chunk, impl, jnp.dtype(q.dtype).name))
 
 
 # --------------------------------------------------------------------------
@@ -252,14 +256,14 @@ def _pass_backward_jnp(do, qg, p, kg, w, ec, h, v_new):
 # stage 2: the pass over chunks, Pallas
 # --------------------------------------------------------------------------
 
-def _mosaic_params(interpret):
+def _mosaic_params(interpret, independent_axes=1):
     """Heads are independent, chunks follow one another: the state lives in
     scratch along the last grid axis only."""
     if interpret:
         return {}
     from jax.experimental.pallas import tpu as pltpu
     return {'compiler_params': pltpu.CompilerParams(
-        dimension_semantics=('parallel', 'arbitrary'))}
+        dimension_semantics=('parallel',) * independent_axes + ('arbitrary',))}
 
 
 def _heads_per_step(bh):

@@ -1,0 +1,568 @@
+"""Kimi delta attention's rule: the gated delta rule with a decay that is a
+vector a head and token (Kimi Linear, arXiv:2510.26692, section 3), chunked,
+the whole rule as Pallas TPU kernels (forward + backward).
+
+Per head, keys ``dk`` and values ``dv`` wide, a state ``S`` in ``R^{dk x dv}``
+that starts at zero::
+
+    S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1} + b_t k_t v_t^T    o_t = S_t^T q_t
+
+``g_t`` in ``R^dk`` (``<= 0``) forgets each key channel at its own rate.
+:func:`kda_scan` is that recurrence as written, token by token.
+
+**The chunked form** (:func:`kda_rule`). Tokens go in chunks of ``C``; ``G`` is
+the running sum of ``g`` inside the chunk, ``S`` the state it starts from::
+
+    A_ij = sum_c k_ic k_jc exp(G_ic - G_jc)          P = lower(sum_c q_ic k_jc exp(G_ic - G_jc))
+    L = strictly lower(b_i A_ij)                     T = (I + L)^-1
+    W = T (b K e^G)           U = T (b V)
+    V_new = U - W S           O = (Q e^G) S + P V_new
+    S' = Diag(e^G_C) S + (K e^(G_C - G))^T V_new
+
+With a scalar decay (:mod:`petastorm_tpu.ops.gated_delta`) ``exp(G_i - G_j)``
+is a mask laid over ``k k^T``. Here it sits inside the contraction, and its
+two factors ``exp(G_i)`` and ``exp(-G_j)`` cannot be formed over a chunk: with
+``g`` bounded at ``gate_lower_bound`` = -5 a chunk of 64 reaches -320 and
+float32 ends at ``e^88``. So the decayed products go by **sub-blocks** of
+``sub_block`` = 16 tokens, each with a reference row ``r_a`` of ``G`` (the
+sub-block's first): the rows of sub-block ``a`` take ``k_i exp(G_i - r_a)``
+(exponents in ``[-75, 0]``), the columns up to its end ``k_j exp(r_a - G_j)``
+(at most 75 inside the sub-block, not positive before it), the columns past it
+are not needed and masked before the exponential. No exponent passes ``16 x
+5 = 80`` and none is positive without bound.
+
+One chunk of one head is :func:`_chunk_forward_kda` / :func:`_chunk_backward_kda`
+on 2-D arrays: the chunk-local quantities (:func:`_local`), then the products
+with the state, which are ``ops.gated_delta``'s own (``_chunk_forward``,
+``_chunk_backward``: the state's decay a column here where it is a scalar
+there). The backward pass is written out (no ``jax`` differentiation inside a
+kernel): from the gradients of ``qg, p, kg, w, u`` back through ``T``
+(``dL = -lower(dWb W^T + dUb U^T)``), the sub-block products and the
+exponentials to ``q, k, v, g, b``. Two implementations run those two bodies:
+``lax.scan`` over chunks in ``jax.numpy`` (``impl='chunked'``) and Pallas calls
+on a grid ``(rows, heads, chunks)`` that read and write the model's own
+``[B, T, H d]`` arrays a 128-lane head at a time, the chunk axis in order
+with the state in VMEM scratch (``'pallas'``, ``'pallas:interpret'``). What
+the reverse pass reads beside the operands: the state each chunk starts from
+and its ``T``.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from petastorm_tpu.ops.flash_attention import _once_a_shape, _out_struct
+from petastorm_tpu.ops.gated_delta import (_NN, _NT, _TN, HIGHEST, IMPLS,
+                                           _chunk_backward, _chunk_forward,
+                                           _dot, _inverse_of_unit_lower,
+                                           _mosaic_params, report_plan)
+
+GATE_LOWER_BOUND = -5.0
+
+
+# --------------------------------------------------------------------------
+# the recurrence as written
+# --------------------------------------------------------------------------
+
+def kda_scan(q, k, v, g, beta):
+    """Token by token, float32: ``q, k, g [B, T, H, dk]``, ``v [B, T, H,
+    dv]``, ``beta [B, T, H]`` -> ``o [B, T, H, dv]``. The definition the
+    chunked forms are tested against; differentiable by ``jax`` as it
+    stands."""
+    f32 = jnp.float32
+    b, _, h, dk = q.shape
+    dv = v.shape[-1]
+
+    def step(s, xs):
+        q_t, k_t, v_t, g_t, b_t = xs                  # [B, H, .]
+        s = s * jnp.exp(g_t)[..., None]
+        old = jnp.einsum('bhkv,bhk->bhv', s, k_t, precision=HIGHEST)
+        s = s + jnp.einsum('bhk,bhv->bhkv', k_t, b_t[..., None] * (v_t - old),
+                           precision=HIGHEST)
+        return s, jnp.einsum('bhkv,bhk->bhv', s, q_t, precision=HIGHEST)
+
+    xs = tuple(jnp.moveaxis(a.astype(f32), 1, 0) for a in (q, k, v, g, beta))
+    _, o = lax.scan(step, jnp.zeros((b, h, dk, dv), f32), xs)
+    return jnp.moveaxis(o, 0, 1).astype(v.dtype)
+
+
+# --------------------------------------------------------------------------
+# the plan: what a call will run, reported once
+# --------------------------------------------------------------------------
+
+def kda_plan(t, heads_held, dk, dv, chunk, sub_block, impl, dtype):
+    """What a call on ``T`` tokens runs: the account ``kernel.kda_plan``
+    carries. ``vmem_bytes``: what the reverse kernel, the largest, holds at
+    once: its blocks twice (the pipeline's two buffers) and the state's
+    gradient."""
+    chunks = -(-t // chunk)
+    size = jnp.dtype(dtype).itemsize
+    wide = chunk * (2 * dk + dv)
+    blocks = (2 * wide * size + chunk * dv * size       # q k v dq dk dv, do
+              + 2 * chunk * dk * 4 + 2 * chunk * 4      # g dg, beta dbeta
+              + dk * dv * size + chunk * chunk * size)  # saved state, T
+    return {'t': t, 'chunk': chunk, 'sub_block': sub_block,
+            'chunks_per_row': chunks, 't_pad': chunks * chunk,
+            'heads_held': heads_held, 'key_width': dk, 'value_width': dv,
+            'gate_lower_bound': GATE_LOWER_BOUND,
+            'largest_exponent': -GATE_LOWER_BOUND * (sub_block - 1),
+            'state_bytes_per_head': 4 * dk * dv,
+            'vmem_bytes': 2 * blocks + 4 * dk * dv,
+            'impl': impl, 'dtype': dtype}
+
+
+# --------------------------------------------------------------------------
+# one chunk of one head
+# --------------------------------------------------------------------------
+
+def _dot32(a, b, dims):
+    """A float32 product that has to stay one: sums of ``g`` (to 320) and the
+    gradients that come back through them."""
+    return lax.dot_general(a, b, (dims, ((), ())), precision=HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis):
+    return lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _column(row):
+    """``[1, n] -> [n, 1]`` without a transpose: the row over the diagonal
+    of ``[n, n]``, summed along the lanes."""
+    n = row.shape[1]
+    eye = _iota((n, n), 0) == _iota((n, n), 1)
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(row, (n, n)), 0.0),
+                   axis=1, keepdims=True)
+
+
+def _row(column):
+    """``[n, 1] -> [1, n]``: :func:`_column` the other way."""
+    n = column.shape[0]
+    eye = _iota((n, n), 0) == _iota((n, n), 1)
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(column, (n, n)), 0.0),
+                   axis=0, keepdims=True)
+
+
+def _pick_row(a, index):
+    """Row ``index`` of ``a [c, n]`` as ``[1, n]`` (a masked sum: a slice of
+    one sublane is no tile)."""
+    return jnp.sum(jnp.where(_iota(a.shape, 0) == index, a, 0.0), axis=0,
+                   keepdims=True)
+
+
+def _local(q, k, v, g, beta, sub, inverse=None):
+    """The chunk's own quantities, from ``q, k [c, dk]``, ``v [c, dv]``, ``g
+    [c, dk]`` float32 and ``beta [c, 1]`` float32; everything float32 but
+    the operands of the products, which take ``q``'s dtype. ``inverse``: ``T``
+    as a forward pass saved it, where the caller has it."""
+    f32 = jnp.float32
+    dtype = q.dtype
+    c = q.shape[0]
+    blocks = c // sub
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    token = _iota(g.shape, 0)
+    g, beta = g.astype(f32), beta.astype(f32)
+    qf, kf = q.astype(f32), k.astype(f32)
+    tri = (row >= col).astype(f32)
+    # G by a product with ones, and the exponent inside a sub-block (G less
+    # its reference row) from g itself: a sum of at most ``sub`` terms.
+    intra = ((row >= col) & (col > row // sub * sub)).astype(f32)
+    big_g = _dot32(tri, g, _NN)
+    e_in = jnp.exp(_dot32(intra, g, _NN))
+    xk, xq = kf * e_in, qf * e_in
+    xkb, xqb = xk.astype(dtype), xq.astype(dtype)
+    a_kk = jnp.zeros((c, c), f32)
+    a_qk = jnp.zeros((c, c), f32)
+    e_out, ys = [], []
+    for a in range(blocks):
+        # Columns past the sub-block are not needed; masked before the
+        # exponential, where the difference is positive without bound.
+        e = jnp.exp(jnp.where(token < (a + 1) * sub,
+                              _pick_row(big_g, a * sub) - big_g, -jnp.inf))
+        y = kf * e
+        yb = y.astype(dtype)
+        mine = row // sub == a
+        a_kk = a_kk + jnp.where(mine, _dot(xkb, yb, _NT), 0.0)
+        a_qk = a_qk + jnp.where(mine, _dot(xqb, yb, _NT), 0.0)
+        e_out.append(e)
+        ys.append(y)
+    a_kk = jnp.where(row > col, a_kk, 0.0)
+    p = jnp.where(row >= col, a_qk, 0.0)
+    if inverse is None:
+        inverse = _inverse_of_unit_lower(beta * a_kk)
+    decay = jnp.exp(big_g)
+    last = _pick_row(big_g, c - 1)
+    e_last = jnp.exp(last - big_g)
+    kg = kf * decay
+    tb = inverse.astype(dtype)
+    w = _dot(tb, (beta * kg).astype(dtype), _NN)
+    u = _dot(tb, (beta * v.astype(f32)).astype(dtype), _NN)
+    return dict(tri=tri, intra=intra, row=row, col=col, token=token,
+                e_in=e_in, xk=xk, xq=xq, e_out=e_out, ys=ys, a_kk=a_kk, p=p,
+                inverse=inverse, decay=decay, e_last=e_last, kg=kg,
+                qg=qf * decay, kd=kf * e_last, ec_row=jnp.exp(last), w=w, u=u)
+
+
+def _chunk_forward_kda(h, q, k, v, g, beta, sub):
+    """One chunk of one head: the state ``h`` (float32 ``[dk, dv]``) it
+    starts from -> ``(h', o, T)``."""
+    dtype = q.dtype
+    m = _local(q, k, v, g, beta, sub)
+    h_next, o, _ = _chunk_forward(
+        h, m['qg'].astype(dtype), m['p'].astype(dtype),
+        m['kd'].astype(dtype), m['w'].astype(dtype), m['u'],
+        _column(m['ec_row']))
+    return h_next, o, m['inverse']
+
+
+def _chunk_backward_kda(grad, do, h, inverse, q, k, v, g, beta, sub):
+    """One chunk of one head in reverse: ``grad`` (float32 ``[dk, dv]``), the
+    gradient of the state the chunk ends in, ``h`` the state it started from
+    and ``inverse`` its ``T`` as the forward pass saved them -> ``(grad', dq,
+    dk, dv, dg, dbeta [c, 1])``, float32."""
+    f32 = jnp.float32
+    dtype = q.dtype
+    c = q.shape[0]
+    m = _local(q, k, v, g, beta, sub, inverse=inverse)
+    beta = beta.astype(f32)
+    row, col, token = m['row'], m['col'], m['token']
+    qg, p, kd, w = (m[name].astype(dtype) for name in ('qg', 'p', 'kd', 'w'))
+    hb = h.astype(dtype)
+    v_new = (m['u'] - _dot(w, hb, _NN)).astype(dtype)
+    d_ec = jnp.sum(grad * hb.astype(f32), axis=1, keepdims=True)   # [dk, 1]
+    grad, dqg, dp, dkd, dw, du, _ = _chunk_backward(
+        grad, do.astype(dtype), qg, p, kd, w, _column(m['ec_row']), hb, v_new)
+    # back through w = T wb, u = T ub and T = (I + L)^-1
+    tb = m['inverse'].astype(dtype)
+    dwb = _dot(tb, dw.astype(dtype), _TN)
+    dub = _dot(tb, du.astype(dtype), _TN)
+    d_low = -(_dot(dwb.astype(dtype), w, _NT)
+              + _dot(dub.astype(dtype), m['u'].astype(dtype), _NT))
+    d_low = jnp.where(row > col, d_low, 0.0)
+    vf = v.astype(f32)
+    dbeta = jnp.sum(dwb * m['kg'], axis=1, keepdims=True) \
+        + jnp.sum(dub * vf, axis=1, keepdims=True) \
+        + jnp.sum(d_low * m['a_kk'], axis=1, keepdims=True)
+    dkg, dv = beta * dwb, beta * dub
+    da = (beta * d_low).astype(dtype)
+    db = jnp.where(row >= col, dp, 0.0).astype(dtype)
+    # back through the sub-block products
+    xkb, xqb = m['xk'].astype(dtype), m['xq'].astype(dtype)
+    dxk = jnp.zeros(m['xk'].shape, f32)
+    dxq = jnp.zeros(m['xq'].shape, f32)
+    dk = jnp.zeros(m['xk'].shape, f32)
+    d_big = dkg * m['kg'] + dqg * m['qg']
+    zero = jnp.zeros((), dtype)
+    for a, (e, y) in enumerate(zip(m['e_out'], m['ys'])):
+        mine = row // sub == a
+        da_a, db_a = jnp.where(mine, da, zero), jnp.where(mine, db, zero)
+        yb = y.astype(dtype)
+        dxk = dxk + _dot(da_a, yb, _NN)
+        dxq = dxq + _dot(db_a, yb, _NN)
+        dy = _dot(da_a, xkb, _TN) + _dot(db_a, xqb, _TN)
+        dk = dk + dy * e
+        d_exp = dy * y
+        d_big = d_big - d_exp + jnp.where(
+            token == a * sub, jnp.sum(d_exp, axis=0, keepdims=True), 0.0)
+    d_in = dxk * m['xk'] + dxq * m['xq']
+    d_last = dkd * m['kd']
+    d_big = d_big - d_last + jnp.where(
+        token == c - 1, jnp.sum(d_last, axis=0, keepdims=True)
+        + _row(d_ec) * m['ec_row'], 0.0)
+    dq = dxq * m['e_in'] + dqg * m['decay']
+    dk = dk + dxk * m['e_in'] + dkg * m['decay'] + dkd * m['e_last']
+    dg = _dot32(m['intra'], d_in, _TN) + _dot32(m['tri'], d_big, _TN)
+    return grad, dq, dk, dv, dg, dbeta
+
+
+# --------------------------------------------------------------------------
+# the pass over chunks, jax.numpy: [B, H, N, C, .] operands
+# --------------------------------------------------------------------------
+
+def _chunks_first(a):
+    """``[B, H, N, ...]`` -> ``[N, B, H, ...]``."""
+    return jnp.moveaxis(a, 2, 0)
+
+
+def _chunks_third(a):
+    return jnp.moveaxis(a, 0, 2)
+
+
+def _over_heads(fn):
+    return jax.vmap(jax.vmap(fn))
+
+
+def _forward_jnp(q, k, v, g, beta, sub):
+    b, h, _, _, dk = q.shape
+    dv = v.shape[-1]
+    body = _over_heads(functools.partial(_chunk_forward_kda, sub=sub))
+
+    def step(state, xs):
+        state_next, o, inverse = body(state, *xs)
+        return state_next, (o, state.astype(q.dtype),
+                            inverse.astype(q.dtype))
+
+    xs = tuple(_chunks_first(a) for a in (q, k, v, g, beta))
+    _, out = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), xs)
+    o, states, inverses = (_chunks_third(a) for a in out)
+    return o.astype(q.dtype), states, inverses
+
+
+def _backward_jnp(do, states, inverses, q, k, v, g, beta, sub):
+    b, h, _, _, dk = q.shape
+    dv = v.shape[-1]
+    body = _over_heads(functools.partial(_chunk_backward_kda, sub=sub))
+
+    def step(grad, xs):
+        out = body(grad, *xs)
+        return out[0], out[1:]
+
+    xs = tuple(_chunks_first(a)
+               for a in (do, states, inverses, q, k, v, g, beta))
+    _, grads = lax.scan(step, jnp.zeros((b, h, dk, dv), jnp.float32), xs,
+                        reverse=True)
+    return tuple(_chunks_third(a) for a in grads)
+
+
+def _to_chunks(a, n, chunk):
+    """``[B, T_pad, H, ...]`` -> ``[B, H, N, C, ...]``."""
+    a = jnp.moveaxis(a, 2, 1)
+    return a.reshape(a.shape[:2] + (n, chunk) + a.shape[3:])
+
+
+def _from_chunks(a):
+    """``[B, H, N, C, ...]`` -> ``[B, T_pad, H, ...]``."""
+    a = a.reshape(a.shape[:2] + (-1,) + a.shape[4:])
+    return jnp.moveaxis(a, 1, 2)
+
+
+# --------------------------------------------------------------------------
+# the pass over chunks, Pallas: the model's own [B, T, H d] arrays
+# --------------------------------------------------------------------------
+
+def _beta_column(beta_ref):
+    """This head's column of the ``[C, H]`` block."""
+    import jax.experimental.pallas as pl
+    block = beta_ref[...].astype(jnp.float32)
+    return jnp.sum(jnp.where(_iota(block.shape, 1) == pl.program_id(1),
+                             block, 0.0), axis=1, keepdims=True)
+
+
+def _forward_kernel(sub, save, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
+                    *rest):
+    import jax.experimental.pallas as pl
+    state_ref = rest[-1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    h = state_ref[...]
+    state_ref[...], o, inverse = _chunk_forward_kda(
+        h, q_ref[...], k_ref[...], v_ref[...], g_ref[...],
+        _beta_column(beta_ref), sub)
+    o_ref[...] = o.astype(o_ref.dtype)
+    if save:
+        h_ref, t_ref = rest[:2]
+        h_ref[...] = h.astype(h_ref.dtype)
+        t_ref[...] = inverse.astype(t_ref.dtype)
+
+
+def _backward_kernel(sub, do_ref, h_ref, t_ref, q_ref, k_ref, v_ref, g_ref,
+                     beta_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
+                     grad_ref):
+    import jax.experimental.pallas as pl
+    i = pl.program_id(2)
+
+    @pl.when(i == 0)
+    def _init():
+        grad_ref[...] = jnp.zeros_like(grad_ref)
+
+    grad_ref[...], dq, dk, dv, dg, dbeta = _chunk_backward_kda(
+        grad_ref[...], do_ref[...], h_ref[...], t_ref[...], q_ref[...],
+        k_ref[...], v_ref[...], g_ref[...], _beta_column(beta_ref), sub)
+    dq_ref[...] = dq.astype(dq_ref.dtype)
+    dk_ref[...] = dk.astype(dk_ref.dtype)
+    dv_ref[...] = dv.astype(dv_ref.dtype)
+    dg_ref[...] = dg
+    # The heads' write strengths share a lane block, so a head's gradient
+    # goes out as a row of its own [chunks, C] block, the last chunk first.
+    dbeta_ref[pl.ds(pl.num_programs(2) - 1 - i, 1), :] = _row(dbeta)
+
+
+def _lanes(a):
+    """``[B, T, H, d] -> [B, T, H d]``: what the projections write."""
+    return a.reshape(a.shape[:2] + (-1,))
+
+
+def _call(kernel, grid, chunk_of, operands, h, chunk, outs, scratch,
+          interpret):
+    """``operands``: name -> array; ``outs``: name -> struct. Blocks by the
+    array's kind: a head's 128-lane band of a chunk of ``[B, T, H d]``, the
+    chunk's ``[C, H]`` write strengths, a chunk's ``[., .]`` of a ``[B, H,
+    N, ., .]`` residual, a head's whole ``[N, C]`` of ``dbeta``."""
+    import jax.experimental.pallas as pl
+
+    def spec(name, a):
+        if name == 'beta':
+            return pl.BlockSpec((None, chunk, h),
+                                lambda b, j, i: (b, chunk_of(i), 0))
+        if name == 'dbeta':
+            return pl.BlockSpec((None, None) + a.shape[2:],
+                                lambda b, j, i: (b, j, 0, 0))
+        if a.ndim == 5:
+            return pl.BlockSpec((None, None, None) + a.shape[3:],
+                                lambda b, j, i: (b, j, chunk_of(i), 0, 0))
+        return pl.BlockSpec((None, chunk, a.shape[2] // h),
+                            lambda b, j, i: (b, chunk_of(i), j))
+
+    return pl.pallas_call(
+        kernel, grid=grid,
+        in_specs=[spec(*item) for item in operands.items()],
+        out_specs=[spec(*item) for item in outs.items()],
+        out_shape=list(outs.values()), scratch_shapes=scratch,
+        interpret=interpret,
+        **_mosaic_params(interpret, independent_axes=2))(*operands.values())
+
+
+def _forward_pallas(q, k, v, g, beta, chunk, sub, save, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    n = t // chunk
+    operands = {'q': _lanes(q), 'k': _lanes(k), 'v': _lanes(v),
+                'g': _lanes(g), 'beta': beta}
+    outs = {'o': _out_struct((b, t, h * dv), q.dtype, q)}
+    if save:
+        outs['h'] = _out_struct((b, h, n, dk, dv), q.dtype, q)
+        outs['inverse'] = _out_struct((b, h, n, chunk, chunk), q.dtype, q)
+    out = _call(functools.partial(_forward_kernel, sub, save), (b, h, n),
+                lambda i: i, operands, h, chunk, outs,
+                [pltpu.VMEM((dk, dv), jnp.float32)], interpret)
+    return (out[0].reshape(b, t, h, dv),) + tuple(out[1:])
+
+
+def _backward_pallas(do, states, inverses, q, k, v, g, beta, chunk, sub,
+                     interpret):
+    from jax.experimental.pallas import tpu as pltpu
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    n = t // chunk
+    operands = {'do': _lanes(do), 'h': states, 'inverse': inverses,
+                'q': _lanes(q), 'k': _lanes(k), 'v': _lanes(v),
+                'g': _lanes(g), 'beta': beta}
+    f32 = jnp.float32
+    outs = {'dq': _out_struct((b, t, h * dk), q.dtype, q),
+            'dk': _out_struct((b, t, h * dk), q.dtype, q),
+            'dv': _out_struct((b, t, h * dv), q.dtype, q),
+            'dg': _out_struct((b, t, h * dk), f32, q),
+            'dbeta': _out_struct((b, h, n, chunk), f32, q)}
+    dq, dk_, dv_, dg, dbeta = _call(
+        functools.partial(_backward_kernel, sub), (b, h, n),
+        lambda i: n - 1 - i, operands, h, chunk, outs,
+        [pltpu.VMEM((dk, dv), f32)], interpret)
+    wide = (b, t, h, dk)
+    return (dq.reshape(wide), dk_.reshape(wide), dv_.reshape(b, t, h, dv),
+            dg.reshape(wide),
+            jnp.moveaxis(dbeta.reshape(b, h, t), 1, 2))
+
+
+# --------------------------------------------------------------------------
+# the rule as one differentiable function
+# --------------------------------------------------------------------------
+
+# ``_once_a_shape`` (flash_attention): one trace a shape, not one a layer, and
+# the kernels keep the name of the scope they were called in.
+
+@functools.partial(_once_a_shape, static_argnums=(5, 6, 7, 8))
+def _forward(q, k, v, g, beta, chunk, sub, impl, save):
+    """``[B, T_pad, H, .]`` operands -> ``(o, states, inverses)``, the last
+    two ``None`` where ``save`` is false and the kernels run."""
+    if impl == 'chunked':
+        n = q.shape[1] // chunk
+        o, states, inverses = _forward_jnp(
+            *(_to_chunks(a, n, chunk) for a in (q, k, v, g, beta[..., None])),
+            sub)
+        return _from_chunks(o), states, inverses
+    out = _forward_pallas(q, k, v, g, beta, chunk, sub, save,
+                          impl == 'pallas:interpret')
+    return out if save else out + (None, None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _rule(q, k, v, g, beta, chunk, sub, impl):
+    return _forward(q, k, v, g, beta, chunk, sub, impl, False)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, sub, impl):
+    o, states, inverses = _forward(q, k, v, g, beta, chunk, sub, impl, True)
+    return o, (states, inverses, q, k, v, g, beta)
+
+
+@functools.partial(_once_a_shape, static_argnums=(0, 1, 2))
+def _rule_bwd(chunk, sub, impl, residuals, do):
+    states, inverses, q, k, v, g, beta = residuals
+    if impl == 'chunked':
+        n = q.shape[1] // chunk
+        grads = _backward_jnp(
+            _to_chunks(do, n, chunk), states, inverses,
+            *(_to_chunks(a, n, chunk) for a in (q, k, v, g, beta[..., None])),
+            sub)
+        grads = tuple(_from_chunks(a) for a in grads)
+        grads = grads[:4] + (grads[4][..., 0],)
+    else:
+        grads = _backward_pallas(do, states, inverses, q, k, v, g, beta,
+                                 chunk, sub, impl == 'pallas:interpret')
+    return tuple(a.astype(like.dtype) for a, like in
+                 zip(grads, (q, k, v, g, beta)))
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+
+
+# --------------------------------------------------------------------------
+# the public function
+# --------------------------------------------------------------------------
+
+def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked'):
+    """``q, k [B, T, H, dk]`` (normalised and scaled by the caller), ``v [B,
+    T, H, dv]``, ``g [B, T, H, dk]`` float32 (log of the decay a key channel,
+    in ``[GATE_LOWER_BOUND, 0]``: the sub-blocks keep every exponent under
+    ``-GATE_LOWER_BOUND * sub_block`` only for such a gate) and ``beta [B, T,
+    H]`` -> ``o [B, T, H, dv]`` in ``q``'s dtype.
+
+    ``impl`` as :func:`petastorm_tpu.ops.gated_delta.gated_delta_rule`'s;
+    the compiled kernels read a head as a band of whole 128-lane blocks, so
+    ``dk`` and ``dv`` are multiples of 128 there. ``T`` is padded to a
+    multiple of ``chunk`` with tokens that write nothing (``beta`` 0) and
+    forget nothing (``g`` 0)."""
+    if impl not in IMPLS:
+        raise ValueError('unknown impl {!r}: one of {}'.format(impl, IMPLS))
+    if chunk % sub_block:
+        raise ValueError('chunk {} is not whole sub-blocks of {}'.format(
+            chunk, sub_block))
+    b, t, h, dk = q.shape
+    dv = v.shape[-1]
+    if impl == 'pallas':
+        if jax.devices()[0].platform != 'tpu':
+            raise RuntimeError(
+                "kda_rule(impl='pallas') compiles Pallas TPU kernels but the "
+                'default jax backend is {!r}; use impl=\'pallas:interpret\' '
+                "or 'chunked'".format(jax.devices()[0].platform))
+        if dk % 128 or dv % 128:
+            raise ValueError('the compiled kernels read a head as whole '
+                             '128-lane blocks: widths {} and {}'.format(dk, dv))
+    plan = kda_plan(t, h, dk, dv, chunk, sub_block, impl,
+                    jnp.dtype(q.dtype).name)
+    report_plan('kernel.kda_plan', plan)
+    pad = plan['t_pad'] - t
+
+    def padded(a):
+        return jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+
+    o = _rule(padded(q), padded(k), padded(v.astype(q.dtype)),
+              padded(g.astype(jnp.float32)), padded(beta.astype(jnp.float32)),
+              chunk, sub_block, impl)
+    return o[:, :t]

@@ -1,0 +1,187 @@
+"""Kimi delta attention's rule (a delta rule whose decay is a vector a head
+and token): the chunked ``jax.numpy`` form and the Pallas kernels (in the
+interpreter) against the recurrence token by token, forward and all five
+gradients, with a mild gate and with every token's gate at its bound; the
+plan instant; the kernels compiled for a TPU at the benchmark's widths."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from petastorm_tpu import trace
+from petastorm_tpu.ops import gated_delta as gd
+from petastorm_tpu.ops import kimi_delta as kd
+
+
+def _operands(t, gate, b=1, h=2, dk=8, dv=16, seed=0, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q = jax.random.normal(ks[0], (b, t, h, dk))
+    k = jax.random.normal(ks[1], (b, t, h, dk))
+    q = q / jnp.linalg.norm(q, axis=-1, keepdims=True) / np.sqrt(dk)
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    u = jax.random.uniform(ks[3], (b, t, h, dk))
+    if gate == 'mild':          # from nearly none to e^-2.5 a token and channel
+        g = kd.GATE_LOWER_BOUND * jax.nn.sigmoid(4 * u - 4)
+    else:                       # every token's gate within 1e-3 of its bound
+        g = kd.GATE_LOWER_BOUND + 1e-3 * u
+    beta = jax.nn.sigmoid(2 * jax.random.normal(ks[4], (b, t, h)))
+    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
+
+
+def _value_and_grads(impl, chunk, sub, operands):
+    """``impl`` ``'scan'``: the recurrence as written, the reference."""
+    def f(*a):
+        o = kd.kda_scan(*a) if impl == 'scan' else \
+            kd.kda_rule(*a, chunk=chunk, sub_block=sub, impl=impl)
+        return jnp.sum(jnp.sin(o) * o), o
+    (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                       has_aux=True)(*operands)
+    return o, grads
+
+
+@functools.lru_cache(maxsize=None)
+def _reference(t, gate):
+    operands = _operands(t, gate)
+    return (operands,) + _value_and_grads('scan', None, None, operands)
+
+
+# several chunks, a row that is no whole number of chunks (the last
+# chunk padded with tokens that write and forget nothing), and the
+# benchmark's own chunk of 64 in sub-blocks of 16
+@pytest.mark.parametrize('impl', ['chunked', 'pallas:interpret'])
+@pytest.mark.parametrize('gate', ['mild', 'bound'])
+@pytest.mark.parametrize('t,chunk,sub', [(48, 16, 4), (75, 16, 8),
+                                         (70, 64, 16)])
+def test_chunked_forms_agree_with_the_recurrence(impl, gate, t, chunk, sub):
+    operands, o_ref, g_ref = _reference(t, gate)
+    o, grads = _value_and_grads(impl, chunk, sub, operands)
+    assert np.isfinite(np.asarray(o)).all()
+    assert all(np.isfinite(np.asarray(x)).all() for x in grads)
+    # float32 throughout: what is left is the order of summation (a chunk's
+    # sums against a token's). At the bound the state all but vanishes a
+    # token (e^-5), the gate's gradient is a sum of terms that all but cancel
+    # and a thousandth of the others' size: its tolerance is of the keys'.
+    np.testing.assert_allclose(o, o_ref, atol=1e-5)
+    scale = max(float(jnp.max(jnp.abs(x))) for x in g_ref)
+    for name, got, want in zip('q k v g beta'.split(), grads, g_ref):
+        own = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(
+            got, want, err_msg=name,
+            atol=4e-5 * (scale if name == 'g' else own))
+
+
+def test_a_whole_chunk_formulation_overflows_where_the_sub_blocks_do_not():
+    """What the sub-blocks exist for: with every gate at -5 the factors
+    ``exp(G_i)`` and ``exp(-G_j)`` over a chunk of 64 leave float32 (e^-315
+    is 0, e^315 inf, their product nan), while no exponent of the sub-block
+    form passes 75."""
+    q, k, v, g, beta = _operands(64, 'bound')
+    big_g = jnp.cumsum(g[0, :, 0], axis=0)                          # [64, dk]
+    whole = (k[0, :, 0] * jnp.exp(big_g)) @ (k[0, :, 0] * jnp.exp(-big_g)).T
+    assert not np.isfinite(np.asarray(jnp.tril(whole))).all()
+    plan = kd.kda_plan(64, 2, 8, 16, 64, 16, 'chunked', 'float32')
+    assert plan['largest_exponent'] == 75.0
+    o = kd.kda_rule(q, k, v, g, beta, chunk=64, sub_block=16)
+    np.testing.assert_allclose(o, kd.kda_scan(q, k, v, g, beta), atol=1e-5)
+
+
+def test_pallas_interpreter_runs_the_jax_numpy_bodies_exactly():
+    """Both run ``_chunk_forward_kda`` / ``_chunk_backward_kda`` in bfloat16:
+    the same numbers (the float32 gradients of the gate and of the write
+    strength to the last place or two: XLA fuses their sums its own way)."""
+    operands = _operands(48, 'mild', dtype=jnp.bfloat16)
+    o_a, g_a = _value_and_grads('chunked', 16, 4, operands)
+    o_b, g_b = _value_and_grads('pallas:interpret', 16, 4, operands)
+    np.testing.assert_array_equal(np.asarray(o_a, np.float32),
+                                  np.asarray(o_b, np.float32))
+    for a, b in zip(g_a, g_b):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=2e-6,
+                                   atol=1e-7)
+
+
+def test_unknown_impl_sub_blocks_and_compiled_kernels_off_a_tpu_are_refused():
+    operands = _operands(16, 'mild')
+    with pytest.raises(ValueError, match='unknown impl'):
+        kd.kda_rule(*operands, impl='scan')
+    with pytest.raises(ValueError, match='whole sub-blocks'):
+        kd.kda_rule(*operands, chunk=16, sub_block=5)
+    with pytest.raises(RuntimeError, match='pallas:interpret'):
+        kd.kda_rule(*operands, impl='pallas')
+
+
+def test_kda_plan_instant_once_per_distinct_plan(monkeypatch):
+    monkeypatch.setattr(gd, '_plans_reported', set())
+    tracer = trace.Tracer(spill_dir=False)
+    previous = trace.set_global_tracer(tracer)
+    try:
+        shapes = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                  for a in _operands(100, 'mild', h=2, dk=128, dv=128,
+                                     dtype=jnp.bfloat16)]
+
+        def layers(impl, *a):
+            def loss(q, k, v, g, beta):
+                x = v
+                for _ in range(3):              # three layers, one plan
+                    x = kd.kda_rule(q, k, x, g, beta, impl=impl)
+                return jnp.sum(x.astype(jnp.float32))
+            return jax.grad(loss, argnums=(0, 1, 2, 3, 4))(*a)
+
+        for impl in ('pallas:interpret', 'pallas:interpret', 'chunked'):
+            jax.eval_shape(lambda *a: layers(impl, *a), *shapes)
+    finally:
+        trace.set_global_tracer(previous)
+    plans = [r for r in tracer.records() if r[0] == 'kernel.kda_plan']
+    assert len(plans) == 2
+    assert all(r[1] == 'kernel' and r[3] is None for r in plans)   # instants
+    assert plans[0][7] == {
+        't': 100, 'chunk': 64, 'sub_block': 16, 'chunks_per_row': 2,
+        't_pad': 128, 'heads_held': 2, 'key_width': 128, 'value_width': 128,
+        'gate_lower_bound': -5.0, 'largest_exponent': 75.0,
+        'state_bytes_per_head': 4 * 128 * 128,
+        'vmem_bytes': plans[0][7]['vmem_bytes'],
+        'impl': 'pallas:interpret', 'dtype': 'bfloat16'}
+    assert 200_000 < plans[0][7]['vmem_bytes'] < 1_000_000
+    assert plans[1][7]['impl'] == 'chunked'
+
+
+# -- compiled for the chip that is described, not attached -----------------------
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:  # noqa: BLE001 - whatever says there is no compiler
+        pytest.skip('no v5e:2x2 topology can be described here: {}'.format(e))
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_the_kernels_compile_for_a_v5e_at_the_benchmark_s_widths(one_chip):
+    """Mosaic takes what the interpreter cannot refuse: 128-wide keys and
+    values read as a head's lane band of ``[B, T, H 128]``, 64-token chunks
+    in sub-blocks of 16, bfloat16, forward and backward, as
+    ``ling3.tokens8k`` runs them (fewer heads and chunks a row)."""
+    b, t, h, d, c = 1, 256, 4, 128, 64
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    wide, g, beta = (shape((b, t, h, d), bf), shape((b, t, h, d), f32),
+                     shape((b, t, h), f32))
+    forward = jax.jit(lambda *a: kd._forward_pallas(
+        *a, c, 16, True, False)).lower(wide, wide, wide, g, beta).compile()
+    backward = jax.jit(lambda *a: kd._backward_pallas(
+        *a, c, 16, False)).lower(
+            wide, shape((b, h, t // c, d, d), bf),
+            shape((b, h, t // c, c, c), bf), wide, wide, wide, g,
+            beta).compile()
+    assert 'tpu_custom_call' in forward.as_text()
+    assert 'tpu_custom_call' in backward.as_text()

@@ -190,6 +190,90 @@ def test_top_k_routing_normalises_over_the_picked_and_scales():
                                rtol=1e-6)
 
 
+LING = 'ling3-flash-ctx8192'
+
+
+@pytest.fixture(scope='module')
+def ling_ref():
+    spec = importlib.util.spec_from_file_location(
+        'ling3_reference', os.path.join(CONFIGS, LING + '.reference.py'))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+@pytest.fixture(scope='module')
+def ling_cfg():
+    """Ling-3.0-flash's file with every one of 16 experts held, in 4 groups
+    of which the best 2 are kept, top 4: the uncut layer under selection by
+    groups."""
+    cfg = json.load(open(os.path.join(CONFIGS, LING + '.json')))
+    cfg.update(hidden_size=32, moe_intermediate_size=16,
+               moe_shared_expert_intermediate_size=16, num_experts=16,
+               n_group=4, topk_group=2, num_experts_per_tok=4)
+    cfg['published'] = dict(cfg['published'], num_experts=16)
+    cfg['assumed'] = dict(cfg['assumed'], experts_held=list(range(16)))
+    return cfg
+
+
+def test_selection_by_groups_is_the_reference_s_and_one_group_is_today_s(
+        ling_cfg, ling_ref):
+    scores = jax.nn.sigmoid(jnp.asarray(
+        np.random.default_rng(3).standard_normal((200, 16)), jnp.float32))
+    experts, weights = moe.top_k_routing(scores, 4, scale=2.5, n_group=4,
+                                         topk_group=2)
+    want = ling_ref.select(scores, ling_cfg)
+    assert experts.tolist() == want.tolist()
+    # by hand: a group's score is the sum of its two best, the best two
+    # groups are kept and every pick lies in one of them
+    by_group = np.sort(np.asarray(scores).reshape(200, 4, 4), axis=-1)
+    kept = np.argsort(-(by_group[..., -1] + by_group[..., -2]), axis=-1)[:, :2]
+    assert all(set((np.asarray(experts[i]) // 4).tolist()) <= set(kept[i])
+               for i in range(200))
+    # and the selection differs from the ungrouped one somewhere: the
+    # groups bind
+    plain, plain_weights = moe.top_k_routing(scores, 4, scale=2.5)
+    assert plain.tolist() != experts.tolist()
+    picked = np.take_along_axis(np.asarray(scores), np.asarray(experts), -1)
+    np.testing.assert_allclose(
+        weights, 2.5 * picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    # one group of all the experts is no group: today's picks to the bit
+    same, same_weights = moe.top_k_routing(scores, 4, scale=2.5, n_group=1,
+                                           topk_group=1)
+    assert same.tolist() == plain.tolist() == \
+        jax.lax.top_k(scores, 4)[1].tolist()
+    np.testing.assert_array_equal(np.asarray(same_weights),
+                                  np.asarray(plain_weights))
+
+
+@pytest.mark.parametrize('impl', ['pallas:interpret', 'ragged_dot'])
+def test_four_shares_under_group_selection_add_up_to_the_uncut_layer(
+        ling_cfg, ling_ref, impl):
+    """Four chips hold a group of four experts each of sixteen; every chip
+    selects by groups over all sixteen and computes its own experts' part;
+    the shared expert is counted once. A chip whose group a token did not
+    keep is sent nothing by that token."""
+    params = _layer_params()
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((2, 24, 32)),
+                    jnp.float32)
+    whole = ling_ref._experts(params, x, ling_cfg, None)
+    total, loads = 0.0, []
+    for chip in range(4):
+        held = list(range(4 * chip, 4 * chip + 4))
+        layer = RoutedMoE(
+            experts_published=16, held=tuple(held), top_k=4, scale=2.5,
+            d_ff=16, shared_d_ff=16 if chip == 0 else 0, n_group=4,
+            topk_group=2, impl=impl, tile_m=8, dtype=jnp.float32)
+        y, load = layer.apply({'params': _share(params, held, chip == 0)}, x)
+        total = total + y
+        loads.append(np.asarray(load))
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=2e-5 * float(jnp.abs(whole).max()), rtol=0)
+    assert int(np.sum(loads)) == 2 * 24 * 4
+    # each token keeps two of the four groups: a chip sees about half of them
+    assert all(0 < int(load.sum()) < 2 * 24 * 4 for load in loads)
+
+
 def test_the_expert_load_counter_writes_running_totals_a_step_late():
     tracer = trace.Tracer(spill_dir=False)
     previous = trace.set_global_tracer(tracer)
