@@ -16,7 +16,8 @@
 * runs an epoch out of ``DeviceDatasetCache`` and compares it with the
   streamed one,
 * compiles the Pallas kernels (flash attention forward and backward, the
-  grouped product, a sub-layer between its hyper-connection maps, Kimi
+  grouped product, the routed experts' two layouts, a sub-layer between its
+  hyper-connection maps, Kimi
   delta attention's rule with every gate at its bound, ``normalize_images``)
   and checks them against their XLA references.
 
@@ -69,6 +70,9 @@ FULL = {
     # xing4.tokens4k's first product: pairs of 4,096 tokens x top 4 against
     # eight experts, tiles of 128 rows (tokens, top_k, experts, k, n, tile)
     'grouped': (4096, 4, 8, 3584, 2048, 128),
+    # ling3.tokens8k's routed part: 8,192 tokens, top 8, 8 of 512 experts
+    # held (tokens, top_k, held, published, d, f, tile)
+    'routed': (8192, 8, 8, 512, 2560, 768, 128),
     # xing4.tokens4k's residual: 4,096 tokens of four 3584-wide streams
     'streams': (4096, 4, 3584),
     # ling3.tokens8k's rule: 8,192 tokens, 32 heads of 128, chunks of 64 in
@@ -87,6 +91,7 @@ TINY = {
               ('f32-ragged-T50', 'float32', 2, 50, 2, 16)],
     'normalize': [(8, 16, 16, 3), (5, 10, 10, 3)],
     'grouped': (24, 2, 3, 32, 128, 8),
+    'routed': (48, 4, 2, 32, 32, 16, 8),
     'streams': (48, 4, 128),
     'kda': (40, 2, 16, 16, 4),
 }
@@ -749,6 +754,76 @@ def _grouped_product_check(run, assert_mosaic):
     return [] if ok else ['grouped']
 
 
+def _routed_layouts_check(run, assert_mosaic):
+    """The held experts' part of a routed layer (``models.moe.
+    routed_experts``) under the rows laid out for four times the held share
+    against the rows of every pair there could be, at the shape
+    ``ling3.tokens8k`` runs it: outputs and the gradients of the tokens, the
+    weights and both expert leaves, bf16. Then every pair on a held expert:
+    the count that passes the rows, where the held experts are applied to
+    every token by two dense products."""
+    import jax
+    import jax.numpy as jnp
+
+    from petastorm_tpu.models import moe
+
+    tokens, top_k, held, published, d, f, tile = run.cfg['routed']
+    keys = jax.random.split(jax.random.PRNGKey(36), 6)
+    x, c = (jax.random.normal(key, (tokens, d), jnp.bfloat16)
+            for key in keys[:2])
+    w1 = (jax.random.normal(keys[2], (held, d, 2 * f), jnp.float32)
+          / np.sqrt(d)).astype(jnp.bfloat16)
+    w2 = (jax.random.normal(keys[3], (held, f, d), jnp.float32)
+          / np.sqrt(f)).astype(jnp.bfloat16)
+    experts, weights = moe.top_k_routing(jax.nn.sigmoid(jax.random.normal(
+        keys[4], (tokens, published), jnp.float32)), top_k)
+    all_held = jax.random.randint(keys[5], (tokens, top_k), 0, held)
+    impl = 'pallas:interpret' if run.interpret else 'pallas'
+
+    def layer(name, experts, over_share):
+        def loss(x, weights, w1, w2):
+            y, counts = moe.routed_experts(x, experts, weights, w1, w2,
+                                           tuple(range(held)), published,
+                                           tile_m=tile, impl=impl)
+            return jnp.sum((y * c).astype(jnp.float32)), (y, counts)
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                          has_aux=True))
+        # The capacity is read as the layer is traced: a share so large that
+        # it is every pair lays out every pair's rows with no choice.
+        compact, moe.CAPACITY_OVER_SHARE = moe.CAPACITY_OVER_SHARE, over_share
+        try:
+            if name == 'routed.compact':
+                assert_mosaic(name, step.lower(x, weights, w1, w2))
+            (_, (y, counts)), grads = run.compile(
+                name + '.fwd+bwd', step, x, weights, w1, w2)(x, weights, w1, w2)
+        finally:
+            moe.CAPACITY_OVER_SHARE = compact
+        return y, counts, grads
+
+    capacity = moe.pairs_capacity(tokens * top_k, held, published, tile)
+    failures = []
+    for routing, picked in (('routed', experts), ('all held', all_held)):
+        y, counts, grads = layer('routed.compact', picked,
+                                 moe.CAPACITY_OVER_SHARE)
+        want_y, want_counts, want_grads = layer('routed.full', picked,
+                                                published)
+        fwd_err = _rel_err(y, want_y)
+        grad_err = max(_rel_err(g, t) for g, t in zip(grads, want_grads))
+        fell_back = int(counts.sum()) > capacity
+        run.say('routed experts [{} tokens, top {}, {} of {} held, {} -> {}, '
+                '{}: {} held pairs against rows for {}{}]: fwd err {:.4%}, '
+                'grad err {:.4%} (tol {:.2%}) against the rows of every '
+                'pair'.format(tokens, top_k, held, published, d, f, routing,
+                              int(counts.sum()), capacity,
+                              ', the held experts on every token' if fell_back else '',
+                              fwd_err, grad_err, GROUPED_TOL))
+        if not (counts.tolist() == want_counts.tolist()
+                and fwd_err <= GROUPED_TOL and grad_err <= GROUPED_TOL
+                and fell_back == (routing == 'all held')):
+            failures.append('routed ' + routing)
+    return failures
+
+
 def _stream_sub_layer_check(run, assert_mosaic):
     """A sub-layer between its hyper-connection maps through the kernels of
     ``ops.hyper_connections`` against the ``jax.numpy`` formulation at the
@@ -908,6 +983,7 @@ def phase_kernels(run):
             failures.append(name)
 
     failures += _grouped_product_check(run, assert_mosaic)
+    failures += _routed_layouts_check(run, assert_mosaic)
     failures += _stream_sub_layer_check(run, assert_mosaic)
     failures += _kimi_delta_check(run, assert_mosaic)
 

@@ -3,8 +3,9 @@ DeepSeek-V3 family's decoder with manifold-constrained hyper-connections:
 Xing4.0's layout), told which share of each layer it holds.
 
 ``[B, T + nextn] int32 tokens -> {'logits': (next token, second next, ...),
-'metrics': {'expert_load': [experts held]}}``, every logits ``[B, T, vocab
-rows held]`` float32. RMSNorm throughout, SwiGLU feed-forward, no bias.
+'metrics': {'expert_load': [experts held], 'layout_fallbacks': []}}``, every
+logits ``[B, T, vocab rows held]`` float32. RMSNorm throughout, SwiGLU
+feed-forward, no bias.
 
 **Streams** (:class:`StreamSubLayer`; arXiv:2512.24880 after
 arXiv:2409.19606). The residual is ``n`` streams side by side in the lanes of
@@ -66,7 +67,7 @@ import numpy as np
 
 from petastorm_tpu.models.hybrid import (RMSNorm, SwiGLU, _projection,
                                          rms_normalise)
-from petastorm_tpu.models.moe import RoutedMoE
+from petastorm_tpu.models.moe import RoutedMoE, total_load
 from petastorm_tpu.models.transformer import self_attention
 from petastorm_tpu.ops import hyper_connections
 from petastorm_tpu.ops.grouped_matmul import TILE_M
@@ -288,9 +289,10 @@ class LatentAttention(nn.Module):
 
 
 class LatentMoEBlock(nn.Module):
-    """``X [B, T, n d] -> (X, expert_load [G])``: an attention sub-layer
-    and a feed-forward (``kind='dense'``) or expert (``'moe'``) sub-layer,
-    each between its own stream maps."""
+    """``X [B, T, n d] -> (X, load)``: an attention sub-layer and a
+    feed-forward (``kind='dense'``, ``load`` None) or expert (``'moe'``,
+    :class:`RoutedMoE`'s ``load``) sub-layer, each between its own stream
+    maps."""
     kind: str
     attention_args: Any
     maps_args: Any
@@ -325,13 +327,11 @@ class LatentMoEBlock(nn.Module):
                               **self.maps_args)(x, attend)
         x, load = StreamSubLayer(dtype=self.dtype, name='ffn_hc',
                                  **self.maps_args)(x, feed_forward)
-        if load is None:
-            load = jnp.zeros((len(self.moe_args['held']),), jnp.int32)
         return x, load
 
 
 class NextTokenModule(nn.Module):
-    """``(h [B, T, d], e [B, T, d]) -> (h' [B, T, d], expert_load)``: the
+    """``(h [B, T, d], e [B, T, d]) -> (h' [B, T, d], load)``: the
     summed streams of the depth before and the embedding of the token one
     further on, through ``W_eh`` and one expert block with its own streams."""
     block: Any
@@ -467,17 +467,18 @@ class LatentMoELM(nn.Module):
                                dtype=self.dtype, name='head')
         t = tokens.shape[1] - self.nextn
         x = _copies(embed(tokens[:, :t]), self.streams)
-        load = 0
+        loads = []
         for i, kind in enumerate(plan['layer_kinds']):
-            x, counts = block(kind, 'block_{}'.format(i))(x)
-            load = load + counts
+            x, load = block(kind, 'block_{}'.format(i))(x)
+            loads.append(load)
         h = _summed(x, self.streams).astype(self.dtype)
         logits = [head(final_norm(h)).astype(jnp.float32)]
         for depth in range(self.nextn):
-            h, counts = NextTokenModule(
+            h, load = NextTokenModule(
                 lambda name: block('moe', name), self.streams,
                 dtype=self.dtype, name='mtp_{}'.format(depth))(
                     h, embed(tokens[:, depth + 1:depth + 1 + t]))
-            load = load + counts
+            loads.append(load)
             logits.append(head(final_norm(h)).astype(jnp.float32))
-        return {'logits': tuple(logits), 'metrics': {'expert_load': load}}
+        return {'logits': tuple(logits),
+                'metrics': total_load(self.experts_held, loads)}

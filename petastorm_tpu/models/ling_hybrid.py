@@ -4,9 +4,10 @@ latent attention there, routed experts with a shared one after a leading
 dense layer, told which share of each layer it holds.
 
 ``[B, T] int32 tokens -> {'logits': [B, T, vocab rows held] float32,
-'metrics': {'expert_load': [experts held]}}``. A layer is ``x + mixer(norm(x))``
-then ``x + ffn(norm(x))`` (pre-norm, one residual stream), RMSNorm throughout,
-no bias anywhere. Layer ``i`` of a period of ``layer_group_size``:
+'metrics': {'expert_load': [experts held], 'layout_fallbacks': []}}``. A layer
+is ``x + mixer(norm(x))`` then ``x + ffn(norm(x))`` (pre-norm, one residual
+stream), RMSNorm throughout, no bias anywhere. Layer ``i`` of a period of
+``layer_group_size``:
 
 * **Kimi delta attention** (:class:`KimiDeltaMixer`; arXiv:2510.26692) where
   ``(i + 1) % layer_group_size != 0``: q, k, v pass a causal depthwise
@@ -47,7 +48,7 @@ from petastorm_tpu.models.hybrid import (EPS, RMSNorm, SwiGLU, _projection,
 from petastorm_tpu.models.latent_moe import (LatentAttention,
                                              yarn_frequencies,
                                              yarn_softmax_scale)
-from petastorm_tpu.models.moe import RoutedMoE
+from petastorm_tpu.models.moe import RoutedMoE, total_load
 from petastorm_tpu.models.transformer import FlatDenseGeneral
 from petastorm_tpu.ops.grouped_matmul import TILE_M
 from petastorm_tpu.ops.kimi_delta import GATE_LOWER_BOUND, kda_rule
@@ -141,7 +142,8 @@ class KimiDeltaMixer(nn.Module):
 
 
 class LingHybridBlock(nn.Module):
-    """``x [B, T, d] -> (x, expert_load [G])``."""
+    """``x [B, T, d] -> (x, load)``: :class:`RoutedMoE`'s ``load``, None
+    from a dense layer."""
     kind: str                           # the mixer: 'kda' | 'latent'
     dense: bool                         # a SwiGLU in the experts' place
     kda_args: Any
@@ -166,8 +168,8 @@ class LingHybridBlock(nn.Module):
         x = x + mixer(RMSNorm(dtype=self.dtype, name='mixer_norm')(x))
         inner = RMSNorm(dtype=self.dtype, name='ffn_norm')(x)
         if self.dense:
-            return x + SwiGLU(self.d_ff, dtype=self.dtype, name='mlp')(inner), \
-                jnp.zeros((len(self.moe_args['held']),), jnp.int32)
+            return x + SwiGLU(self.d_ff, dtype=self.dtype,
+                              name='mlp')(inner), None
         y, load = RoutedMoE(dtype=self.dtype, name='moe',
                             **self.moe_args)(inner)
         return x + y, load
@@ -262,14 +264,13 @@ class LingHybridLM(nn.Module):
         block = nn.remat(LingHybridBlock) if self.remat else LingHybridBlock
         x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
                      name='embed')(tokens)
-        load = 0
+        loads = []
         for i, kind in enumerate(plan['layer_kinds']):
-            x, counts = block(kind, i < self.dense_layers, kda_args,
-                              latent_args, moe_args, self.d_ff,
-                              dtype=self.dtype,
-                              name='block_{}'.format(i))(x)
-            load = load + counts
+            x, load = block(kind, i < self.dense_layers, kda_args,
+                            latent_args, moe_args, self.d_ff,
+                            dtype=self.dtype, name='block_{}'.format(i))(x)
+            loads.append(load)
         x = RMSNorm(dtype=self.dtype, name='final_norm')(x)
         logits = _projection(x, self.vocab_size, 'head', self.dtype)
         return {'logits': logits.astype(jnp.float32),
-                'metrics': {'expert_load': load}}
+                'metrics': total_load(self.experts_held, loads)}

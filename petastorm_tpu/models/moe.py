@@ -1,7 +1,9 @@
 """Mixture-of-experts feed-forward layers: :class:`SwitchMoE` (top-1 with a
 capacity, dense dispatch, the expert axis sharded over a mesh) and
 :class:`RoutedMoE` (dropless top-k over the experts *held here* of a wider
-published router, with a shared expert; below).
+published router, with a shared expert; below: its rows are laid out for
+four times the pairs the held share expects, and a step that is sent more,
+which its count says, applies the held experts to every token instead).
 
 **Switch-style Mixture-of-Experts MLP with expert parallelism.**
 
@@ -39,7 +41,9 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from petastorm_tpu.ops.grouped_matmul import (TILE_M, aligned_layout,
-                                              grouped_matmul, tile_groups)
+                                              grouped_matmul,
+                                              grouped_matmul_grads,
+                                              tile_groups, token_sums)
 from petastorm_tpu.trace import get_global_tracer
 
 
@@ -171,129 +175,265 @@ def top_k_routing(scores, top_k, scale=1.0, normalise=True, n_group=1,
 Dispatch = collections.namedtuple('Dispatch', [
     'group_sizes',  # [G] rows of each held expert's group, whole tiles
     'counts',       # [G] pairs routed to each held expert
-    'dest',         # [N, k] the row of each pair (0 where its expert is absent)
-    'is_held',      # [N, k] whether the pair's expert is held here
     'row_token',    # [rows] the token each row holds (0 for a padding row)
     'row_pair',     # [rows] the pair each row holds, as n * k + slot
     'row_valid'])   # [rows] whether the row holds a pair at all
 
 
-def dispatch_plan(experts, held, experts_published, tile_m):
-    """Where every (token, expert) pair goes: pairs sorted by the expert held
-    (in ``held``'s order, a token's pairs in its own order), each group
-    starting on a tile of ``tile_m`` rows and at least one tile long
+#: The rows are laid out for this many times the pairs the held experts get
+#: of an even routing. Over whole benchmark runs (PERF.md section 6, PR 36)
+#: an eighth of Xing4.0's experts never passed it in any layer, and a
+#: sixty-fourth of Ling-3.0's passed it in one layer of some steps, until its
+#: routers collapse onto the held experts and every layer passes it for good:
+#: what passes four times the share is the collapse or next to it, and
+#: :func:`held_experts_on_every_token` takes that at the matrix unit's rate.
+CAPACITY_OVER_SHARE = 4
+
+
+def pairs_capacity(pairs, held, experts_published, tile_m):
+    """The held pairs the layout has rows for: ``CAPACITY_OVER_SHARE`` times
+    the ``held`` experts' share of ``pairs``, in whole tiles, and never more
+    than ``pairs`` (a chip that holds a quarter of the experts or more lays
+    out every pair there could be)."""
+    share = -(-CAPACITY_OVER_SHARE * pairs * held // experts_published)
+    return min(pairs, -(-share // tile_m) * tile_m)
+
+
+def sorted_pairs(experts, held):
+    """``(counts [G], order [N k])``: the pairs each held expert was sent, and
+    the pairs sorted by the expert held (in ``held``'s order, a token's pairs
+    in their own order, the pairs of absent experts behind every held one)."""
+    g, pairs = len(held), experts.size
+    # [G, P], the pairs along the lanes. An absent expert's pair matches no
+    # row of it and takes the key g.
+    match = jnp.asarray(held, jnp.int32)[:, None] == experts.reshape(1, pairs)
+    key = jnp.min(jnp.where(match, jnp.arange(g, dtype=jnp.int32)[:, None], g),
+                  axis=0)
+    _, order = jax.lax.sort((key, jnp.arange(pairs, dtype=jnp.int32)),
+                            num_keys=1, is_stable=True)
+    return jnp.sum(match, axis=1, dtype=jnp.int32), order
+
+
+def layout(counts, order, k, tile_m, capacity):
+    """Where every (token, expert) pair goes, from :func:`sorted_pairs`: the
+    rows of ``capacity`` pairs and a tile a group, each group starting on a
+    tile of ``tile_m`` rows and at least one tile long
     (:func:`petastorm_tpu.ops.grouped_matmul.aligned_layout`); the pairs of
-    absent experts have no row. ``experts [N, k]`` int32 ids over the
-    published experts. Static shapes: ``rows = N * k + len(held) * tile_m``
-    holds every pair there could be, so nothing is dropped; the group sizes
-    are data."""
-    n, k = experts.shape
-    g = len(held)
-    local = np.full((experts_published,), g, np.int32)
-    local[np.asarray(held)] = np.arange(g, dtype=np.int32)
-    key = jnp.asarray(local)[experts].reshape(-1)                   # [P]
-    pairs = n * k
-    onehot = key[:, None] == jnp.arange(g, dtype=jnp.int32)[None]   # [P, G]
-    counts = jnp.sum(onehot, axis=0, dtype=jnp.int32)
-    rank = jnp.sum(jnp.where(onehot, jnp.cumsum(onehot, axis=0,
-                                                dtype=jnp.int32) - 1, 0),
-                   axis=-1)
+    absent experts have no row. Static shapes: every table is ``rows =
+    capacity + len(counts) * tile_m`` long, which holds any held pairs that
+    number ``capacity`` or fewer. More of them (``sum(counts)`` says so) and
+    the tables place only the first ``capacity``: the caller has to compute
+    those steps another way. The group sizes are data."""
+    g = counts.shape[0]
     sizes, starts = aligned_layout(counts, tile_m)
-    is_held = key < g
-    dest = jnp.where(is_held, starts[jnp.minimum(key, g - 1)] + rank, 0)
-    # The other way: which pair a row holds. Sorted by group, a group's
-    # pairs lie from ``first[g]`` on in the order ``rank`` counts them.
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     first = jnp.cumsum(counts) - counts
-    rows = pairs + g * tile_m
-    row = jnp.arange(rows, dtype=jnp.int32)
-    group, used = tile_groups(sizes, tile_m, rows // tile_m)
-    mine = group[row // tile_m]
-    within = row - starts[mine]
-    valid = (within < counts[mine]) & (row // tile_m < used[0])
-    pair = order[jnp.clip(first[mine] + within, 0, pairs - 1)]
-    return Dispatch(sizes, counts, dest.reshape(n, k), is_held.reshape(n, k),
-                    jnp.where(valid, pair // k, 0), pair, valid)
+    # A tile lies in one group, so its rows count on from where the group's
+    # pairs begin among the sorted ones.
+    tiles = capacity // tile_m + g
+    group, used = tile_groups(sizes, tile_m, tiles)
+    tile = jnp.arange(tiles, dtype=jnp.int32)
+    within = ((tile * tile_m - starts[group])[:, None]
+              + jnp.arange(tile_m, dtype=jnp.int32)[None])
+    valid = ((within < counts[group][:, None])
+             & (tile < used[0])[:, None]).reshape(-1)
+    at = (first[group][:, None] + within).reshape(-1)
+    pair = order[:capacity][jnp.clip(at, 0, capacity - 1)]
+    return Dispatch(sizes, counts, jnp.where(valid, pair // k, 0), pair, valid)
 
 
-# Both directions of dispatch and combine are gathers: a token's pairs know
-# their rows and a row knows its token, so neither transpose is a scatter.
+# --------------------------------------------------------------------------
+# the routed part: over the rows of a layout, forward and backward by hand,
+# and over every token where the held pairs pass the rows
+# --------------------------------------------------------------------------
 
-@jax.custom_vjp
-def _to_rows(x, row_token, dest, is_held):
-    """``x [N, d]`` -> ``[rows, d]``: each row its token's vector."""
-    return x[row_token]
-
-
-def _to_rows_fwd(x, row_token, dest, is_held):
-    return x[row_token], (dest, is_held)
+def _swiglu(hidden):
+    f = hidden.shape[-1] // 2
+    return nn.silu(hidden[..., :f]) * hidden[..., f:]
 
 
-def _to_rows_bwd(residuals, g):
-    dest, is_held = residuals
-    picked = jnp.where(is_held[..., None], g[dest].astype(jnp.float32), 0.0)
-    return jnp.sum(picked, axis=1).astype(g.dtype), None, None, None
+def _row_weight(weights, plan):
+    return jnp.where(plan.row_valid, weights.reshape(-1)[plan.row_pair], 0.0)
 
 
-_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+def _sum_rows(values, weights, plan, n, tile_m, impl):
+    """``values [rows, d]`` -> ``[N, d]``: a token the sum of the rows that
+    hold a pair of it, each under its weight. Dispatch is a gather of the
+    tokens by the rows' ``row_token``; this is its transpose, and nothing
+    ``N * k`` long with a trailing ``d`` exists on either side."""
+    return token_sums(values, weights,
+                      jnp.where(plan.row_valid, plan.row_token, n), n, tile_m,
+                      impl)
 
 
-def _weighted(y, weights, dest, is_held):
-    picked = y[dest].astype(jnp.float32)                            # [N, k, d]
-    return picked, jnp.where(is_held, weights, 0.0)
+def _rows_forward(x, weights, w_gate_up, w_down, plan, tile_m, impl):
+    """``(out [N, d], kept)``: every row's expert applied to its token, and a
+    token the sum of its rows under their weights, float32 inside. ``kept``
+    is what :func:`_rows_backward` reads again."""
+    rows = x[plan.row_token]
+    hidden = grouped_matmul(rows, w_gate_up, plan.group_sizes, tile_m, impl)
+    y = grouped_matmul(_swiglu(hidden), w_down, plan.group_sizes, tile_m,
+                       impl)
+    out = _sum_rows(y, _row_weight(weights, plan), plan, x.shape[0], tile_m,
+                    impl)
+    return out, (rows, hidden, y)
 
 
-@jax.custom_vjp
-def _from_rows(y, weights, dest, is_held, row_token, row_weight):
-    """``y [rows, d]`` -> ``[N, d]``: each token the weighted sum of its
-    held pairs' rows."""
-    picked, w = _weighted(y, weights, dest, is_held)
-    return jnp.sum(picked * w[..., None], axis=1).astype(y.dtype)
+def _rows_backward(g, weights, w_gate_up, w_down, plan, kept, tile_m, impl):
+    """The cotangents of ``x, weights, w_gate_up, w_down`` from ``g [N, d]``:
+    :func:`_rows_forward` gone back through piece by piece."""
+    rows, hidden, y = kept
+    g_rows = g[plan.row_token].astype(jnp.float32)
+    dy = (g_rows * _row_weight(weights, plan)[:, None]).astype(y.dtype)
+    # <y[r], g[token of r]> a row, then a scatter of ``rows`` scalars.
+    d_weights = jnp.zeros((weights.size,), jnp.float32).at[
+        jnp.where(plan.row_valid, plan.row_pair, weights.size)].set(
+            jnp.sum(y.astype(jnp.float32) * g_rows, axis=-1),
+            mode='drop').reshape(weights.shape)
+    activated, activation_back = jax.vjp(_swiglu, hidden)
+    d_activated, d_w_down = grouped_matmul_grads(
+        activated, w_down, plan.group_sizes, dy, tile_m, impl)
+    d_rows, d_w_gate_up = grouped_matmul_grads(
+        rows, w_gate_up, plan.group_sizes, activation_back(d_activated)[0],
+        tile_m, impl)
+    dx = _sum_rows(d_rows, plan.row_valid.astype(jnp.float32), plan,
+                   g.shape[0], tile_m, impl)
+    return dx, d_weights.astype(weights.dtype), d_w_gate_up, d_w_down
 
 
-def _from_rows_fwd(y, weights, dest, is_held, row_token, row_weight):
-    return (_from_rows(y, weights, dest, is_held, row_token, row_weight),
-            (y, weights, dest, is_held, row_token, row_weight))
+def held_experts_on_every_token(x, experts, weights, w_gate_up, w_down, held):
+    """The same ``[N, d]`` with no rows at all: every held expert applied to
+    every token, a token's sum taken under the weight of its pair that picked
+    the expert and under 0 where none did. Two dense products (``[N, d]`` by
+    ``[d, G 2f]``, ``[N, G f]`` by ``[G f, d]``, the second summing a token's
+    pairs in float32 as it goes), which cost what ``N G`` pairs cost however
+    many are held: what :func:`routed_experts` runs where the held pairs pass
+    its rows, at most ``experts_published / (4 k)`` times the arithmetic the
+    pairs asked for, on nothing but the matrix unit."""
+    picked = experts[:, :, None] == jnp.asarray(held, jnp.int32)
+    weight = jnp.sum(jnp.where(picked, weights[:, :, None], 0.0), axis=1)
+    hidden = jnp.einsum('nd,gdf->ngf', x, w_gate_up.astype(x.dtype))
+    weighted = _swiglu(hidden).astype(jnp.float32) * weight[:, :, None]
+    return jnp.einsum('ngf,gfd->nd', weighted.astype(x.dtype),
+                      w_down.astype(x.dtype),
+                      preferred_element_type=jnp.float32).astype(x.dtype)
 
 
-def _from_rows_bwd(residuals, g):
-    y, weights, dest, is_held, row_token, row_weight = residuals
-    dy = (g[row_token].astype(jnp.float32)
-          * row_weight[:, None]).astype(y.dtype)
-    picked, _ = _weighted(y, weights, dest, is_held)
-    dw = jnp.sum(picked * g.astype(jnp.float32)[:, None, :], axis=-1)
-    return (dy, jnp.where(is_held, dw, 0.0).astype(weights.dtype), None,
-            None, None, None)
+def _varying_like(value, like):
+    """``value`` varying over the mesh axes ``like`` varies over: inside
+    ``jax.shard_map`` the branches of a ``cond`` have to agree on that."""
+    axes = tuple(jax.typeof(like).vma)
+    return jax.lax.pcast(value, axes, to='varying') if axes else value
 
 
-_from_rows.defvjp(_from_rows_fwd, _from_rows_bwd)
+# The choice between the rows and every token sits outside differentiation: a
+# ``cond`` that ``jax.grad`` goes through hands back both branches' residuals,
+# the untaken branch's as zeros, written in every forward pass. Here the
+# forward's and the backward's ``cond`` each choose by the same flag and the
+# residuals have the rows' shapes in both branches: the dense branch keeps
+# nothing and forms its first product again going back. A branch is a scope
+# of its own, and a device trace names a Pallas call by the innermost scope:
+# ``name`` is entered again inside. Both directions are ``jax.jit(inline=True)``
+# (PERF.md section 6, PR 27): a model's layers call them with the same shapes,
+# so they are traced once a shape, and the step's program holds no call that
+# is not its own (jitted apart, ``ling3.tokens8k``'s step never came back from
+# the persistent cache: PERF.md section 6, PR 36).
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _routed(x, experts, weights, w_gate_up, w_down, static):
+    return _routed_fwd(x, experts, weights, w_gate_up, w_down, static)[0]
+
+
+@functools.partial(jax.jit, static_argnums=(5,), inline=True)
+def _routed_fwd(x, experts, weights, w_gate_up, w_down, static):
+    held, capacity, tile_m, impl, name = static
+    counts, order = sorted_pairs(experts, held)
+    plan = layout(counts, order, experts.shape[1], tile_m, capacity)
+    operands = (x, weights, w_gate_up, w_down)
+
+    def rows(*operands):
+        with jax.named_scope(name):
+            return _rows_forward(*operands, plan, tile_m, impl)
+
+    def every_token(x, weights, w_gate_up, w_down):
+        out = held_experts_on_every_token(x, experts, weights, w_gate_up,
+                                          w_down, held)
+        return out, tuple(
+            _varying_like(jnp.zeros((plan.row_token.shape[0], width), x.dtype),
+                          x)
+            for width in (x.shape[1], w_gate_up.shape[2], x.shape[1]))
+
+    fits = jnp.sum(counts) <= capacity
+    if capacity == experts.size:        # rows for every pair: no choice
+        out, kept = rows(*operands)
+    else:
+        out, kept = jax.lax.cond(fits, rows, every_token, *operands)
+    return ((out, counts, 1 - fits.astype(jnp.int32)),
+            (experts, fits, operands, plan, kept))
+
+
+def _routed_bwd(static, residuals, cotangents):
+    return _routed_back(static, residuals, cotangents[0])   # the ints' is none
+
+
+@functools.partial(jax.jit, static_argnums=(0,), inline=True)
+def _routed_back(static, residuals, g):
+    held, capacity, tile_m, impl, name = static
+    experts, fits, (x, weights, w_gate_up, w_down), plan, kept = residuals
+
+    def rows(g, x, weights, w_gate_up, w_down, kept):
+        with jax.named_scope(name):
+            return _rows_backward(g, weights, w_gate_up, w_down, plan, kept,
+                                  tile_m, impl)
+
+    def every_token(g, x, weights, w_gate_up, w_down, kept):
+        _, back = jax.vjp(
+            lambda x, weights, w_gate_up, w_down: held_experts_on_every_token(
+                x, experts, weights, w_gate_up, w_down, held),
+            x, weights, w_gate_up, w_down)
+        return back(g)
+
+    operands = (g, x, weights, w_gate_up, w_down, kept)
+    if capacity == weights.size:
+        dx, d_weights, d_w_gate_up, d_w_down = rows(*operands)
+    else:
+        dx, d_weights, d_w_gate_up, d_w_down = jax.lax.cond(
+            fits, rows, every_token, *operands)
+    return dx, None, d_weights, d_w_gate_up, d_w_down
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
+
+
+def _routed_static(pairs, held, experts_published, tile_m, impl, name):
+    return (tuple(held), pairs_capacity(pairs, len(held), experts_published,
+                                        tile_m), tile_m, impl, name)
 
 
 def routed_experts(x, experts, weights, w_gate_up, w_down, held,
-                   experts_published, tile_m=TILE_M, impl='pallas'):
+                   experts_published, tile_m=TILE_M, impl='pallas',
+                   name='moe'):
     """The held experts' part of a routed layer. ``x [N, d]``, ``experts,
     weights [N, k]`` (:func:`top_k_routing`), ``w_gate_up [G, d, 2 f]`` (an
     expert's gate columns, then its up columns), ``w_down [G, f, d]``.
     Returns ``([N, d], counts [G])``: ``sum over a token's held pairs of
     weight * expert(x)``, every expert ``down(silu(gate x) * up x)``, and the
-    pairs each held expert was sent. Nothing is dropped whatever the routing
-    (:func:`dispatch_plan`)."""
-    plan = dispatch_plan(experts, held, experts_published, tile_m)
-    f = w_down.shape[1]
-    rows = _to_rows(x, plan.row_token, plan.dest, plan.is_held)
-    hidden = grouped_matmul(rows, w_gate_up, plan.group_sizes, tile_m, impl)
-    hidden = nn.silu(hidden[:, :f]) * hidden[:, f:]
-    y = grouped_matmul(hidden, w_down, plan.group_sizes, tile_m, impl)
-    row_weight = jnp.where(plan.row_valid,
-                           weights.reshape(-1)[plan.row_pair], 0.0)
-    out = _from_rows(y, weights, plan.dest, plan.is_held, plan.row_token,
-                     row_weight)
-    return out, plan.counts
+    pairs each held expert was sent. The rows are laid out for
+    :func:`pairs_capacity` held pairs (:func:`layout`) and the experts'
+    products run over them group by group; a step whose routing sends more
+    (``sum(counts)`` says so) applies the held experts to every token instead
+    (:func:`held_experts_on_every_token`), chosen by ``jax.lax.cond`` on that
+    count, so nothing is dropped whatever the routing and both ways compute
+    the same function. Differentiable in ``x``, ``weights`` and both expert
+    leaves, each direction holding its own ``cond``. ``name`` is the scope a
+    device trace names the Pallas calls by: the module's."""
+    return _routed(x, experts, weights, w_gate_up, w_down, _routed_static(
+        experts.size, held, experts_published, tile_m, impl, name))[:2]
 
 
 class RoutedMoE(nn.Module):
     """Dropless top-k routed experts with a shared expert, told which
-    experts it holds: ``[B, T, d] -> ([B, T, d], expert_load [G])``.
+    experts it holds: ``[B, T, d] -> ([B, T, d], load)``, ``load`` a dict of
+    ``expert_load [G]`` and ``layout_fallbacks []``, both int32.
 
     The router is ``experts_published`` wide whatever is held: ``s =
     sigmoid(W_r x)`` in float32, the ``top_k`` experts of each token (among
@@ -304,12 +444,17 @@ class RoutedMoE(nn.Module):
     the layer computes ``shared(x) + sum over a token's picked experts that
     are held of weight * expert(x)`` and nothing for the absent ones: the
     partial result of the chip before the group's exchange, with the shared
-    expert, which every chip computes alike, counted here. **No capacity and
-    no drop**: the held experts' products run over row groups of
+    expert, which every chip computes alike, counted here. **A capacity that
+    never drops**: the held experts' products run over row groups of
     data-dependent size (:mod:`petastorm_tpu.ops.grouped_matmul`; ``impl``
     ``'pallas'``, ``'pallas:interpret'`` or ``'ragged_dot'``), in an array
-    that holds every pair there could be. ``expert_load`` is how many pairs
-    each held expert was sent.
+    laid out for :data:`CAPACITY_OVER_SHARE` times the pairs the held experts
+    get of an even routing (:func:`pairs_capacity`: the held share and the
+    tokens say it, nothing sets it); a step that sends them more applies the
+    held experts to every token, two dense products and no rows
+    (:func:`routed_experts`). ``expert_load`` is how many pairs each held
+    expert was sent, ``layout_fallbacks`` how many shards of the batch went
+    that second way (0 or 1 without a mesh).
 
     A device trace names the layer's Pallas calls by this module's name, so
     name it ``moe``. With ``mesh`` the routed part is mapped over the
@@ -363,12 +508,14 @@ class RoutedMoE(nn.Module):
                 w_gate_up, w_down = (jax.lax.pcast(w, (axis,), to='varying')
                                      for w in (w_gate_up, w_down))
             rows = x.shape[0] * x.shape[1]
-            y, counts = routed_experts(
+            # :func:`routed_experts`, and the flag its ``cond`` chose by.
+            y, counts, fell_back = _routed(
                 x.reshape(rows, d), experts.reshape(rows, self.top_k),
                 weights.reshape(rows, self.top_k), w_gate_up, w_down,
-                tuple(self.held), self.experts_published, self.tile_m,
-                self.impl)
-            return y.reshape(x.shape), counts[None]
+                _routed_static(rows * self.top_k, self.held,
+                               self.experts_published, self.tile_m,
+                               self.impl, self.name or 'moe'))
+            return y.reshape(x.shape), counts[None], fell_back[None]
 
         if self.mesh is not None and self.impl.startswith('pallas'):
             axis = usable_axis(self.mesh, self.batch_axis, b)
@@ -376,13 +523,26 @@ class RoutedMoE(nn.Module):
             routed = jax.shard_map(
                 functools.partial(routed, axis=axis), mesh=self.mesh,
                 in_specs=(rows, rows, rows, whole, whole),
-                out_specs=(rows, PartitionSpec(axis, None)),
+                out_specs=(rows, PartitionSpec(axis, None),
+                           PartitionSpec(axis)),
                 check_vma=self.impl == 'pallas')
-        y, counts = routed(x, experts, weights, w_gate_up, w_down)
+        y, counts, fell_back = routed(x, experts, weights, w_gate_up, w_down)
         if self.shared_d_ff:
             y = y + SwiGLU(self.shared_d_ff, dtype=self.dtype,
                            name='shared')(x)
-        return y, jnp.sum(counts, axis=0)
+        return y, {'expert_load': jnp.sum(counts, axis=0),
+                   'layout_fallbacks': jnp.sum(fell_back)}
+
+
+def total_load(held, loads):
+    """A step's ``metrics`` of its expert layers: the ``load`` of every
+    :class:`RoutedMoE` summed (``None``: a layer that routes nothing)."""
+    total = {'expert_load': jnp.zeros((len(held),), jnp.int32),
+             'layout_fallbacks': jnp.zeros((), jnp.int32)}
+    for load in loads:
+        if load is not None:
+            total = jax.tree.map(jnp.add, total, load)
+    return total
 
 
 # A loop that dispatches step k and then awaits step k - 1 (one step kept in
@@ -393,21 +553,26 @@ LOAD_LAG = 2
 
 class ExpertLoadCounter(object):
     """Running totals of a step's ``expert_load`` on the global tracer's
-    ring, as counters ``moe.expert_load.e<slot>`` (one a held expert): what a
-    reader takes the difference of at a window's two ends. ``add`` is handed
-    every step's ``metrics`` and reads the load of the step ``LOAD_LAG`` calls
-    back, so it never waits for the device."""
+    ring, as counters ``moe.expert_load.e<slot>`` (one a held expert), and of
+    its ``layout_fallbacks`` as ``moe.layout_fallbacks`` (expert layers whose
+    held pairs passed their rows): what a reader takes the difference of at a window's
+    two ends. ``add`` is handed every step's ``metrics`` and reads those of
+    the step ``LOAD_LAG`` calls back, so it never waits for the device."""
 
     def __init__(self):
         self._pending, self._total = collections.deque(), None
 
     def add(self, metrics):
-        self._pending.append(metrics['expert_load'])
+        self._pending.append((metrics['expert_load'],
+                              metrics.get('layout_fallbacks', 0)))
         if len(self._pending) <= LOAD_LAG:
             return
-        load = np.asarray(self._pending.popleft()).astype(np.int64)
-        self._total = load if self._total is None else self._total + load
+        step = np.concatenate([np.asarray(a, np.int64).reshape(-1)
+                               for a in self._pending.popleft()])
+        self._total = step if self._total is None else self._total + step
         tracer = get_global_tracer()
-        for slot, value in enumerate(self._total.tolist()):
+        *load, fallbacks = self._total.tolist()
+        for slot, value in enumerate(load):
             tracer.counter('moe.expert_load.e{}'.format(slot), value,
                            cat='step')
+        tracer.counter('moe.layout_fallbacks', fallbacks, cat='step')

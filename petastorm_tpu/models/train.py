@@ -15,12 +15,12 @@ them and a float32 log-sum-exp a row.
 **A model with several heads.** Where a model hands back a dict, ``'logits'``
 is one array or a tuple of them (``models.LatentMoELM``: the next token and
 the second next) and ``'metrics'`` arrays of its own that the step passes on
-in its ``metrics`` (``expert_load``). The labels of a tuple of heads are a
-tuple of ``(labels, weights)`` pairs, one a head; the step's loss is the sum
-over the heads of ``sum(weights * loss)``, so a head's mean, its mask and its
-coefficient are all in its weights. Each head goes through the same op (one
-``step.loss_plan`` instant a distinct plan). A model that hands back an array
-runs the program it always ran.
+in its ``metrics`` (``expert_load``, ``layout_fallbacks``). The labels of a
+tuple of heads are a tuple of ``(labels, weights)`` pairs, one a head; the
+step's loss is the sum over the heads of ``sum(weights * loss)``, so a head's
+mean, its mask and its coefficient are all in its weights. Each head goes
+through the same op (one ``step.loss_plan`` instant a distinct plan). A model
+that hands back an array runs the program it always ran.
 """
 
 from typing import Any

@@ -11,8 +11,9 @@ of a dropless mixture-of-experts layer (:class:`petastorm_tpu.models.moe.
 RoutedMoE`): the groups are the experts held here, their sizes are how many
 (token, expert) pairs the router sent to each in this step.
 
-**Sizes are data, shapes are static.** ``M`` is the capacity (every pair
-the layer could be sent), the sizes arrive as an int32 array, and the work
+**Sizes are data, shapes are static.** ``M`` is the capacity (the rows the
+layer laid out: for a multiple of the pairs its held share expects, or for
+every pair it could be sent), the sizes arrive as an int32 array, and the work
 follows the sizes: the kernels run over *row tiles* of ``tile_m`` rows, a
 scalar-prefetched table says which group a tile belongs to
 (:func:`tile_groups`), and a tile past the last group is not multiplied (its
@@ -35,6 +36,10 @@ weights' gradient) runs on a grid ``(K blocks, N blocks, row tiles)`` and
 accumulates the tiles of a group in float32 scratch, written when the group
 ends. ``interpret=True`` runs all three in the Pallas interpreter (the CPU
 tests); the compiled kernels on a backend that is not a TPU raise.
+
+A fourth kernel sums rows into the tokens they came from
+(:func:`token_sums`): the transpose of the gather that laid the tokens out in
+rows, as a one-hot product over blocks of ``tile_m`` tokens.
 """
 
 import functools
@@ -100,10 +105,13 @@ def _dw_blocks(k, n, itemsize):
 
 def moe_plan(rows, k, n, groups, tile_m, dtype, impl):
     """The account a ``kernel.moe_plan`` instant carries: what one product of
-    ``[rows, k]`` by ``[groups, k, n]`` runs."""
+    ``[rows, k]`` by ``[groups, k, n]`` runs. ``rows`` are the capacity the
+    caller laid out, which holds any ``pairs_capacity = rows - groups *
+    tile_m`` pairs (:func:`aligned_layout`)."""
     item = jnp.dtype(dtype).itemsize
     block_k_dw, block_n_dw = _dw_blocks(k, n, item)
-    return {'groups': groups, 'rows_capacity': rows, 'k': k, 'n': n,
+    return {'groups': groups, 'rows_capacity': rows,
+            'pairs_capacity': rows - groups * tile_m, 'k': k, 'n': n,
             'tile_m': tile_m, 'tiles': rows // tile_m,
             'block_n': _block(n, k * item), 'block_k_dw': block_k_dw,
             'block_n_dw': block_n_dw,
@@ -237,6 +245,98 @@ def _dw(x, dy, group, used, groups, tile_m, interpret):
     )(group, used, x, dy)
 
 
+# -- rows summed into tokens ---------------------------------------------------
+
+def _token_sums_kernel(block_ref, tile_ref, total_ref, token_ref, w_ref, v_ref,
+                       o_ref, acc_ref, *, steps, tile_m):
+    import jax.experimental.pallas as pl
+
+    del tile_ref
+    s, total = pl.program_id(1), total_ref[0]
+    mine = block_ref[s]
+    first = (s == 0) | (block_ref[jnp.maximum(s - 1, 0)] != mine)
+    last = (s == total - 1) | (block_ref[jnp.minimum(s + 1, steps - 1)] != mine)
+
+    @pl.when(s < total)
+    def _accumulate():
+        @pl.when(first)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        # [tokens of the block, rows of the tile]: a row under its token.
+        tokens = mine * tile_m + jax.lax.broadcasted_iota(
+            jnp.int32, (tile_m, tile_m), 0)
+        onehot = jnp.where(token_ref[...] == tokens, 1.0, 0.0).astype(
+            jnp.bfloat16)
+        # The weighted rows, float32, in three bfloat16 pieces: against ones
+        # and zeros the three passes of the MXU add up to the float32 sum.
+        rest = v_ref[...].astype(jnp.float32) * w_ref[...]
+        for _ in range(3):
+            piece = rest.astype(jnp.bfloat16)
+            acc_ref[...] += jax.lax.dot_general(
+                onehot, piece, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            rest = rest - piece.astype(jnp.float32)
+
+        @pl.when(last)
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _token_sums(values, weights, tokens, n, tile_m, interpret):
+    """The Pallas route of :func:`token_sums`."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, d = values.shape
+    tiles, blocks = rows // tile_m, -(-n // tile_m)
+    steps = tiles + blocks
+    nowhere = blocks * tile_m
+    tokens, order = jax.lax.sort(
+        (jnp.where((tokens >= 0) & (tokens < n), tokens, nowhere),
+         jnp.arange(rows, dtype=jnp.int32)), num_keys=1)
+    # The tiles a block's rows lie in, at least one (an empty block's sum is
+    # written as zeros from a tile that holds nothing of it), then the
+    # (block, tile) pair of every step, the last pair again past the end.
+    edges = jnp.searchsorted(tokens, jnp.arange(blocks + 1) * tile_m).astype(
+        jnp.int32)
+    first = jnp.minimum(edges[:-1] // tile_m, tiles - 1)
+    count = jnp.where(edges[1:] > edges[:-1],
+                      (edges[1:] - 1) // tile_m - first, 0) + 1
+    ends = jnp.cumsum(count)
+    at = jnp.minimum(jnp.arange(steps, dtype=jnp.int32), ends[-1] - 1)
+    block = jnp.searchsorted(ends, at, side='right').astype(jnp.int32)
+    tile = first[block] + at - (ends - count)[block]
+    block_d = _block(d, 512 * values.dtype.itemsize)
+
+    with jax.named_scope('token_sums'):     # not the caller's ``moe*``
+        out = pl.pallas_call(
+            functools.partial(_token_sums_kernel, steps=steps, tile_m=tile_m),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(d // block_d, steps),
+                in_specs=[
+                    pl.BlockSpec((None, 1, tile_m),
+                                 lambda j, s, block, tile, total:
+                                 (tile[s], 0, 0)),
+                    pl.BlockSpec((tile_m, 1),
+                                 lambda j, s, block, tile, total:
+                                 (tile[s], 0)),
+                    pl.BlockSpec((tile_m, block_d),
+                                 lambda j, s, block, tile, total:
+                                 (tile[s], j))],
+                out_specs=pl.BlockSpec(
+                    (tile_m, block_d),
+                    lambda j, s, block, tile, total: (block[s], j)),
+                scratch_shapes=[pltpu.VMEM((tile_m, block_d), jnp.float32)]),
+            out_shape=_out_struct((nowhere, d), values.dtype, values),
+            interpret=interpret,
+            **_params(interpret, ('parallel', 'arbitrary')),
+        )(block, tile, ends[-1:].astype(jnp.int32),
+          tokens.reshape(tiles, 1, tile_m),
+          weights[order].astype(jnp.float32)[:, None], values[order])
+    return out[:n]
+
+
 # -- public entry + custom vjp -------------------------------------------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -260,15 +360,9 @@ def _grouped_bwd(tile_m, interpret, residuals, dy):
 _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def grouped_matmul(x, w, group_sizes, tile_m=TILE_M, impl='pallas'):
-    """``[M, K] x [G, K, N] -> [M, N]``, group ``g``'s rows by ``w[g]``, the
-    rows after the last group zero. ``impl``: ``'pallas'`` (compiled, a TPU),
-    ``'pallas:interpret'``, or ``'ragged_dot'`` (``jax.lax.ragged_dot``, any
-    sizes). The Pallas route asks for aligned groups (module docstring):
-    ``M`` and every size a multiple of ``tile_m``, every group at least one
-    tile. Differentiable in ``x`` and ``w``; ``dw`` has ``w``'s dtype."""
-    if impl == 'ragged_dot':
-        return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
+def _check(x, k, tile_m, impl):
+    """``interpret`` of a Pallas ``impl``, for rows ``x [M, k]`` the kernels
+    take in tiles of ``tile_m``."""
     if impl not in ('pallas', 'pallas:interpret'):
         raise ValueError('impl {!r}: pallas, pallas:interpret or ragged_dot'
                          .format(impl))
@@ -278,12 +372,63 @@ def grouped_matmul(x, w, group_sizes, tile_m=TILE_M, impl='pallas'):
             'grouped_matmul compiles Pallas TPU kernels but the default jax '
             'backend is {!r}; use impl=\'pallas:interpret\' or \'ragged_dot\''
             .format(jax.devices()[0].platform))
-    if x.shape[0] % tile_m or x.shape[1] != w.shape[1]:
-        raise ValueError('x {} against w {} in tiles of {} rows'.format(
-            x.shape, w.shape, tile_m))
+    if x.shape[0] % tile_m or x.shape[1] != k:
+        raise ValueError('x {} against a contraction of {} in tiles of {} '
+                         'rows'.format(x.shape, k, tile_m))
     if tile_m % (8 * 4 // np.dtype(x.dtype).itemsize) and not interpret:
         raise ValueError('tile_m {} is no whole number of {} sublane tiles'
                          .format(tile_m, x.dtype))
+    return interpret
+
+
+def grouped_matmul(x, w, group_sizes, tile_m=TILE_M, impl='pallas'):
+    """``[M, K] x [G, K, N] -> [M, N]``, group ``g``'s rows by ``w[g]``, the
+    rows after the last group zero. ``impl``: ``'pallas'`` (compiled, a TPU),
+    ``'pallas:interpret'``, or ``'ragged_dot'`` (``jax.lax.ragged_dot``, any
+    sizes). The Pallas route asks for aligned groups (module docstring):
+    ``M`` and every size a multiple of ``tile_m``, every group at least one
+    tile. Differentiable in ``x`` and ``w``; ``dw`` has ``w``'s dtype."""
+    if impl == 'ragged_dot':
+        return jax.lax.ragged_dot(x, w, group_sizes.astype(jnp.int32))
+    interpret = _check(x, w.shape[1], tile_m, impl)
     _report_plan(x, w, tile_m, impl)
     return _grouped(x, w.astype(x.dtype), group_sizes.astype(jnp.int32),
                     tile_m, interpret)
+
+
+def grouped_matmul_grads(x, w, group_sizes, dy, tile_m=TILE_M, impl='pallas'):
+    """``(dx, dw)`` of :func:`grouped_matmul` at ``(x, w)`` against ``dy [M,
+    N]``: what differentiating it gives, for a caller that writes a backward
+    pass out by hand (:mod:`petastorm_tpu.models.moe`); nothing of the forward
+    product runs."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if impl == 'ragged_dot':
+        _, back = jax.vjp(lambda x, w: jax.lax.ragged_dot(x, w, group_sizes),
+                          x, w)
+        return back(dy)
+    interpret = _check(x, w.shape[1], tile_m, impl)
+    dx, dw, _ = _grouped_bwd(tile_m, interpret,
+                             (x, w.astype(x.dtype), group_sizes), dy)
+    return dx, dw.astype(w.dtype)
+
+
+def token_sums(values, weights, tokens, n, tile_m=TILE_M, impl='pallas'):
+    """``out[t] = sum over the rows r with tokens[r] == t of weights[r] *
+    values[r]``: ``values [M, D]``, ``weights [M]`` float32, ``tokens [M]``
+    int32 (a row whose token is not in ``[0, n)`` is in no sum) -> ``[n, D]``
+    in ``values``' dtype, summed in float32. The transpose of the row gather
+    ``x[tokens]``, which as an XLA scatter of row updates runs update by
+    update (PERF.md section 6, PR 36). ``impl='ragged_dot'`` is the plain route
+    (``jax.ops.segment_sum``); the Pallas route sorts the rows by token and
+    sums each block of ``tile_m`` tokens as the product of a one-hot with the
+    tiles of rows its tokens lie in, one grid step a (block, tile) pair: the
+    pairs are data, at most ``M / tile_m + ceil(n / tile_m)`` of them. ``M``
+    a multiple of ``tile_m``. A device trace names the call ``token_sums*``
+    whatever scope it runs in."""
+    if impl == 'ragged_dot':
+        return jax.ops.segment_sum(
+            values.astype(jnp.float32) * weights[:, None],
+            jnp.where((tokens >= 0) & (tokens < n), tokens, n),
+            num_segments=n).astype(values.dtype)
+    return _token_sums(values, weights, tokens, n, tile_m,
+                       _check(values, values.shape[1], tile_m, impl))

@@ -1,7 +1,8 @@
 """``ops.grouped_matmul``: the Pallas grouped product (interpret mode) against
 ``jax.lax.ragged_dot``, forward and both gradients, over groups of no row,
-one row and uneven sizes; the tile tables; and the three kernels compiled for
-a described v5e at the widths ``xing4.tokens4k`` runs."""
+one row and uneven sizes; the tile tables; the rows summed into tokens against
+``jax.ops.segment_sum``; and the kernels compiled for a described v5e at the
+widths ``xing4.tokens4k`` and ``ling3.tokens8k`` run."""
 
 import importlib
 
@@ -124,10 +125,72 @@ def test_moe_plan_instant_once_a_distinct_plan(monkeypatch):
             plan['n'], plan['tile_m'], plan['impl'], plan['dtype']) == (
                 2, x.shape[0], x.shape[0] // 8, 16, 128, 8, 'pallas:interpret',
                 'float32')
+    # the rows hold all but a tile a group
+    assert plan['pairs_capacity'] == x.shape[0] - 2 * 8
+    # ling3.tokens8k's: 5,120 rows for 4,096 pairs
+    compact = gm.moe_plan(5120, 2560, 1536, 8, 128, jnp.bfloat16, 'pallas')
+    assert (compact['rows_capacity'], compact['pairs_capacity'],
+            compact['tiles']) == (5120, 4096, 40)
     # the cell's first product: [16384 + 8 x 128, 3584] x [8, 3584, 2048] bf16
     real = gm.moe_plan(17408, 3584, 2048, 8, 128, jnp.bfloat16, 'pallas')
     assert (real['tiles'], real['block_n'], real['block_k_dw'],
             real['block_n_dw']) == (136, 512, 3584, 2048)
+
+
+@pytest.mark.parametrize('counts', COUNTS)
+def test_the_gradients_by_hand_are_what_differentiating_gives(counts):
+    x, w, sizes, _, c = _case(counts, 8, 32, 48, 2, jnp.float32)
+    for impl in ('pallas:interpret', 'ragged_dot'):
+        _, back = jax.vjp(lambda x, w: gm.grouped_matmul(
+            x, w, sizes, tile_m=8, impl=impl), x, w)
+        for a, b in zip(gm.grouped_matmul_grads(x, w, sizes, c, 8, impl),
+                        back(c)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize('rows,d,n,tile_m', [
+    (64, 32, 50, 8),        # tokens that end inside a block
+    (128, 16, 9, 8),        # more tiles than blocks: a block over many tiles
+    (256, 128, 1000, 8),    # more blocks than tiles: most of them empty
+    (512, 256, 384, 128),   # the cells' tile
+])
+@pytest.mark.parametrize('tokens', ['any', 'one', 'none'])
+def test_rows_summed_into_tokens_equal_the_segment_sum(rows, d, n, tile_m,
+                                                       tokens):
+    """Rows whose tokens lie anywhere (some outside ``[0, n)``: in no sum),
+    all on one token, all outside. float32 rows in three bfloat16 pieces
+    against ones and zeros: the sums differ by their order alone."""
+    rng = np.random.default_rng(rows + n)
+    values = jnp.asarray(rng.standard_normal((rows, d)), jnp.float32)
+    weights = jnp.asarray(rng.random(rows), jnp.float32)
+    at = {'any': rng.integers(-1, n + 3, rows), 'one': np.full(rows, n // 2),
+          'none': np.full(rows, n)}[tokens].astype(np.int32)
+    got = jax.jit(lambda *a: gm.token_sums(*a, n, tile_m, 'pallas:interpret'))(
+        values, weights, jnp.asarray(at))
+    want = np.zeros((n, d), np.float64)
+    inside = (at >= 0) & (at < n)
+    np.add.at(want, at[inside], np.asarray(values, np.float64)[inside]
+              * np.asarray(weights, np.float64)[inside, None])
+    assert got.shape == (n, d) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=0,
+                               atol=2e-6 * max(1.0, np.abs(want).max()))
+    np.testing.assert_allclose(
+        np.asarray(gm.token_sums(values, weights, jnp.asarray(at), n, tile_m,
+                                 'ragged_dot')), want, rtol=0,
+        atol=2e-6 * max(1.0, np.abs(want).max()))
+
+
+def test_bfloat16_rows_are_summed_in_float32():
+    """Two rows of a token whose bfloat16 sum would round: the weighted rows
+    are formed and summed in float32 and rounded once."""
+    values = jnp.asarray([[1.0] * 8, [2.0 ** -9] * 8] * 4, jnp.bfloat16)
+    weights = jnp.asarray([1.0, 3.0] * 4, jnp.float32)
+    tokens = jnp.asarray([0, 0, 1, 1, 2, 2, 3, 3], jnp.int32)
+    got = gm.token_sums(values, weights, tokens, 4, 8, 'pallas:interpret')
+    assert got.dtype == jnp.bfloat16
+    want = jnp.asarray(1.0 + 3.0 * 2.0 ** -9, jnp.float32).astype(jnp.bfloat16)
+    assert float(want) != 1.0
+    assert np.asarray(got, np.float32).tolist() == [[float(want)] * 8] * 4
 
 
 @pytest.fixture(scope='module')
@@ -162,3 +225,28 @@ def test_the_kernels_compile_for_a_v5e_at_the_cell_s_widths(v5e, k, n):
         struct((rows, k), jnp.bfloat16), struct((8, k, n), jnp.bfloat16),
         struct((8,), jnp.int32), struct((rows, n), jnp.float32)).compile()
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
+@pytest.mark.parametrize('n,rows,d', [(8192, 5120, 2560), (4096, 9216, 3584),
+                                      (8192, 66560, 2560)])
+def test_the_token_sums_compile_for_a_v5e_at_the_cells_shapes(v5e, n, rows, d):
+    """``ling3.tokens8k``'s and ``xing4.tokens4k``'s compact rows into their
+    tokens, and the rows of every pair there could be (the fallback): bf16,
+    blocks of 128 tokens. The call is named ``token_sums``, not by its
+    caller's scope."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(v5e[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def sums(values, weights, tokens):
+        with jax.named_scope('moe'):
+            return gm._token_sums(values, weights, tokens, n, 128, False)
+
+    text = jax.jit(sums).lower(
+        struct((rows, d), jnp.bfloat16), struct((rows,), jnp.float32),
+        struct((rows,), jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and '%token_sums' in calls[0].split('=')[0]

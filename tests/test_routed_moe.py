@@ -91,7 +91,7 @@ def test_the_eight_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(
         y, load = _module(cfg, held, impl, shared=chip == 0).apply(
             {'params': _share(params, held, chip == 0)}, x)
         total = total + y
-        loads.append(np.asarray(load))
+        loads.append(np.asarray(load['expert_load']))
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                atol=2e-5 * float(jnp.abs(whole).max()), rtol=0)
     # every pair of every token landed on exactly one chip
@@ -148,34 +148,240 @@ def test_dropless_every_token_to_one_held_expert(impl, expert):
     assert counts.tolist() == [96 if i == expert else 0 for i in range(4)]
 
 
-def test_the_dispatch_plan_places_every_held_pair_once():
+@pytest.mark.parametrize('capacity', [200, 64, 48, 40])
+def test_the_layout_places_every_held_pair_once(capacity):
+    """Every pair there could be, two capacities with room to spare and the
+    held pairs' own number rounded up to tiles."""
     rng = np.random.default_rng(6)
     n, k, held, tile_m = 50, 4, (3, 7, 8, 15), 8
-    experts = jnp.asarray(np.stack([rng.permutation(16)[:k] for _ in range(n)]),
-                          jnp.int32)
-    plan = moe.dispatch_plan(experts, held, 16, tile_m)
-    is_held = np.isin(np.asarray(experts), held)
-    assert np.array_equal(np.asarray(plan.is_held), is_held)
-    counts = [int((np.asarray(experts) == e).sum()) for e in held]
+    experts = np.stack([rng.permutation(16)[:k] for _ in range(n)])
+    plan = moe.layout(*moe.sorted_pairs(jnp.asarray(experts, jnp.int32), held),
+                      k, tile_m, capacity)
+    is_held = np.isin(experts, held)
+    counts = [int((experts == e).sum()) for e in held]
+    assert 32 < sum(counts) <= 40
     assert plan.counts.tolist() == counts
     assert plan.group_sizes.tolist() == [max(1, -(-c // tile_m)) * tile_m
                                          for c in counts]
-    rows = n * k + len(held) * tile_m
-    assert plan.row_token.shape == plan.row_valid.shape == (rows,)
-    dest = np.asarray(plan.dest)[is_held]
-    assert len(set(dest.tolist())) == is_held.sum() == int(plan.row_valid.sum())
-    # a row knows its pair, and the pair knows its row
+    rows = capacity + len(held) * tile_m
+    assert plan.row_token.shape == plan.row_pair.shape == \
+        plan.row_valid.shape == (rows,)
+    # a row knows its pair, and every held pair has one row
     valid = np.asarray(plan.row_valid)
     pair = np.asarray(plan.row_pair)[valid]
-    assert np.array_equal(np.asarray(plan.dest).reshape(-1)[pair],
-                          np.flatnonzero(valid))
+    assert sorted(pair.tolist()) == np.flatnonzero(is_held).tolist()
     assert np.array_equal(np.asarray(plan.row_token)[valid], pair // k)
-    # groups lie in the order of ``held``, each from a tile's first row
+    assert not np.asarray(plan.row_token)[~valid].any()
+    # groups lie in the order of ``held``, each from a tile's first row, a
+    # group's pairs in their own order
     starts = np.cumsum([0] + plan.group_sizes.tolist()[:-1])
     for slot, e in enumerate(held):
-        mine = np.asarray(plan.dest)[np.asarray(experts) == e]
-        assert sorted(mine.tolist()) == list(range(
-            starts[slot], starts[slot] + counts[slot]))
+        mine = np.flatnonzero(valid)[experts.reshape(-1)[pair] == e]
+        assert mine.tolist() == list(range(starts[slot],
+                                           starts[slot] + counts[slot]))
+        assert np.all(np.diff(np.asarray(plan.row_pair)[mine]) > 0)
+
+
+def _routed_case(n, k, published, held, d=16, f=8, seed=7):
+    """Every token picks ``k`` distinct experts of ``published`` at random."""
+    rng = np.random.default_rng(seed)
+    experts = np.stack([rng.permutation(published)[:k] for _ in range(n)])
+    weights = rng.random((n, k)).astype(np.float32)
+    x, c = (jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+            for _ in range(2))
+    w1 = jnp.asarray(rng.standard_normal((len(held), d, 2 * f)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((len(held), f, d)), jnp.float32)
+    return experts, jnp.asarray(weights), x, c, w1, w2
+
+
+def _out_counts_grads(experts, weights, x, c, w1, w2, held, published, impl):
+    def loss(x, weights, w1, w2):
+        y, counts = moe.routed_experts(x, jnp.asarray(experts, jnp.int32),
+                                       weights, w1, w2, held, published,
+                                       tile_m=8, impl=impl)
+        return jnp.sum(y * c), (y, counts)
+    (_, (y, counts)), grads = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3), has_aux=True))(x, weights, w1, w2)
+    return y, counts, grads
+
+
+def _assert_same(got, want):
+    """``(out, counts, gradients)``: counts to the bit, the float32 arrays
+    to the order of their sums."""
+    assert got[1].tolist() == want[1].tolist()
+    for a, b in zip((got[0],) + got[2], (want[0],) + want[2]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=3e-5 * float(jnp.abs(b).max()))
+
+
+@pytest.mark.parametrize('impl', ['pallas:interpret', 'ragged_dot'])
+@pytest.mark.parametrize('published,held,n,capacity', [
+    (16, (3, 12), 64, 128), (64, (41,), 128, 32)])
+def test_the_compact_layout_equals_the_layout_of_every_pair(
+        monkeypatch, published, held, n, capacity, impl):
+    """Shares of an eighth and a sixty-fourth: rows for four times the share
+    against rows for every pair there could be (a capacity over the share so
+    large that it is every pair), in ``out``, ``counts`` and the gradients of
+    ``x``, the weights and both expert leaves."""
+    case = _routed_case(n, 4, published, held)
+    assert moe.pairs_capacity(n * 4, len(held), published, 8) == capacity
+    compact = _out_counts_grads(*case, held, published, impl)
+    assert 0 < int(compact[1].sum()) < capacity
+    monkeypatch.setattr(moe, 'CAPACITY_OVER_SHARE', published)
+    assert moe.pairs_capacity(n * 4, len(held), published, 8) == n * 4
+    _assert_same(compact, _out_counts_grads(*case, held, published, impl))
+
+
+def _held_pairs(n, k, published, held, pairs_held):
+    """``experts [n, k]`` whose first ``pairs_held`` pairs (token after token)
+    go to held experts and no other does."""
+    absent = [e for e in range(published) if e not in held]
+    experts = np.tile(np.asarray(absent[:k]), (n, 1)).reshape(-1)
+    slot = np.arange(n * k) % k
+    experts[:pairs_held] = np.asarray(held)[slot[:pairs_held]]
+    return experts.reshape(n, k)
+
+
+@pytest.mark.parametrize('impl', ['pallas:interpret', 'ragged_dot'])
+@pytest.mark.parametrize('over', [128, 1, 0, -1])
+def test_one_pair_over_the_capacity_goes_to_every_token_and_loses_nothing(
+        monkeypatch, impl, over):
+    """Four of 32 experts held, 64 tokens, top 4: rows for 128 held pairs.
+    129 of them pass the rows (the tables place 128: a pair would be lost)
+    and the held experts are applied to every token, as with all 256 held
+    (the collapse); 128 and 127 run over the rows; all four equal the rows of
+    every pair."""
+    held, published, n, k = (5, 6, 20, 31), 32, 64, 4
+    capacity = moe.pairs_capacity(n * k, len(held), published, 8)
+    assert capacity == 128
+    _, weights, x, c, w1, w2 = _routed_case(n, k, published, held)
+    experts = _held_pairs(n, k, published, held, capacity + over)
+    got = _out_counts_grads(experts, weights, x, c, w1, w2, held, published,
+                            impl)
+    assert int(got[1].sum()) == capacity + over
+    monkeypatch.setattr(moe, 'CAPACITY_OVER_SHARE', published)
+    _assert_same(got, _out_counts_grads(experts, weights, x, c, w1, w2, held,
+                                        published, impl))
+    # every held pair's weight has a gradient: none was left out of the rows
+    assert int((np.asarray(got[2][1]) != 0).sum()) == capacity + over
+
+
+@pytest.mark.parametrize('over,fallbacks', [(1, 1), (0, 0), (-1, 0)])
+def test_the_layer_counts_a_fallback_one_pair_over_and_none_one_under(
+        monkeypatch, over, fallbacks):
+    held, published, n, k = (5, 6, 20, 31), 32, 64, 4
+    experts = jnp.asarray(_held_pairs(n, k, published, held, 128 + over),
+                          jnp.int32).reshape(1, n, k)
+    monkeypatch.setattr(moe, 'top_k_routing', lambda scores, *a: (
+        experts, jnp.full((1, n, k), 0.25, jnp.float32)))
+    layer = RoutedMoE(experts_published=published, held=held, top_k=k,
+                      d_ff=8, impl='pallas:interpret', tile_m=8,
+                      dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(8).standard_normal((1, n, 16)),
+                    jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)
+    _, load = jax.jit(layer.apply)(params, x)
+    assert int(load['layout_fallbacks']) == fallbacks
+    assert int(load['expert_load'].sum()) == 128 + over
+
+
+def _sub_jaxprs(jaxpr, skip):
+    """``jaxpr`` and every jaxpr inside it but those of ``skip`` equations."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in skip:
+            continue
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (tuple, list)) else [value]:
+                inner = getattr(inner, 'jaxpr', inner)
+                if hasattr(inner, 'eqns'):
+                    for found in _sub_jaxprs(inner, skip):
+                        yield found
+
+
+def _gradient_jaxpr(held, published, n, impl='pallas:interpret'):
+    case = _routed_case(n, 4, published, held)
+    return jax.make_jaxpr(lambda *a: _out_counts_grads(
+        case[0], *a, held, published, impl))(*case[1:]).jaxpr
+
+
+def _primitives(held, published):
+    """Of the gradient's jaxpr, the kernels' own bodies left out (a
+    ``pl.when`` is a ``cond`` too)."""
+    return {eqn.primitive.name for jaxpr in _sub_jaxprs(
+        _gradient_jaxpr(held, published, 32), skip=('pallas_call',))
+            for eqn in jaxpr.eqns}
+
+
+def test_every_expert_held_traces_no_cond():
+    """A chip that holds every expert (or a quarter of them) lays out every
+    pair there could be: one layout, no choice."""
+    for held, published in ((tuple(range(8)), 8), ((0, 1), 8)):
+        names = _primitives(held, published)
+        assert 'cond' not in names and 'pallas_call' in names
+    assert 'cond' in _primitives((0,), 8)
+
+
+def test_no_array_of_every_pair_anywhere_and_none_of_every_token_outside():
+    """A share of a sixty-fourth, forward and backward. Nowhere, the branches
+    included, has an array ``N k`` rows (or those and a tile a group) by
+    ``d``, ``f`` or ``2 f``: the layout of every pair is gone. The arrays of
+    every token by every held expert live inside the second branches alone:
+    outside (the ``cond``s' operands and results included, what the common
+    path writes whichever branch runs) there are the rows' and no more.
+    Differentiating through a ``cond`` would hand the second branch's
+    residuals out of it as zeros."""
+    n, k, d, f, tile_m = 128, 4, 16, 8, 8
+    every = list(_sub_jaxprs(_gradient_jaxpr((41,), 64, n), skip=()))
+    outer = list(_sub_jaxprs(_gradient_jaxpr((41,), 64, n), skip=('cond',)))
+    conds = [eqn for jaxpr in outer for eqn in jaxpr.eqns
+             if eqn.primitive.name == 'cond']
+    assert len(conds) == 2                  # the forward's and the backward's
+
+    def shapes(jaxprs):
+        return {tuple(v.aval.shape) for jaxpr in jaxprs for eqn in jaxpr.eqns
+                for v in list(eqn.invars) + list(eqn.outvars)
+                if hasattr(v.aval, 'shape')}
+
+    rows = moe.pairs_capacity(n * k, 1, 64, tile_m) + tile_m
+    assert (rows, d) in shapes(outer) and (rows, 2 * f) in shapes(outer)
+    assert not [s for s in shapes(every)
+                if len(s) >= 2 and s[-1] in (d, f, 2 * f)
+                and int(np.prod(s[:-1])) in (n * k + tile_m, n * k)]
+    assert (n, 1, 2 * f) in shapes(every) and (n, 1, f) in shapes(every)
+    assert not [s for s in shapes(outer) if len(s) == 3 and s[0] == n
+                and s[-1] in (f, 2 * f)]
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize('held_pairs', [0, 37, 256])
+def test_the_held_experts_on_every_token_equal_the_rows_of_every_pair(
+        held_pairs, dtype):
+    """No held pair, some, and every pair held (the collapse): the dense
+    form against the rows laid out for every pair, forward and the four
+    gradients; in bfloat16 to the rounding of two products."""
+    held, published, n, k = (5, 6, 20, 31), 32, 64, 4
+    _, weights, x, c, w1, w2 = _routed_case(n, k, published, held)
+    experts = jnp.asarray(_held_pairs(n, k, published, held, held_pairs),
+                          jnp.int32)
+    x, c, w1, w2 = (a.astype(dtype) for a in (x, c, w1 / 4, w2 / 4))
+
+    def dense(x, weights, w1, w2):
+        return jnp.sum((moe.held_experts_on_every_token(
+            x, experts, weights, w1, w2, held) * c).astype(jnp.float32))
+
+    def rows(x, weights, w1, w2):
+        plan = moe.layout(*moe.sorted_pairs(experts, held), k, 8, n * k)
+        out, _ = moe._rows_forward(x, weights, w1, w2, plan, 8, 'ragged_dot')
+        return jnp.sum((out * c).astype(jnp.float32))
+
+    got = jax.value_and_grad(dense, argnums=(0, 1, 2, 3))(x, weights, w1, w2)
+    want = jax.value_and_grad(rows, argnums=(0, 1, 2, 3))(x, weights, w1, w2)
+    tol = 3e-5 if dtype == jnp.float32 else 3e-2
+    for a, b in zip((got[0],) + got[1], (want[0],) + want[1]):
+        a, b = (np.asarray(v, np.float32) for v in (a, b))
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=tol * max(1e-6, np.abs(b).max()))
 
 
 def test_top_k_routing_normalises_over_the_picked_and_scales():
@@ -266,7 +472,7 @@ def test_four_shares_under_group_selection_add_up_to_the_uncut_layer(
             topk_group=2, impl=impl, tile_m=8, dtype=jnp.float32)
         y, load = layer.apply({'params': _share(params, held, chip == 0)}, x)
         total = total + y
-        loads.append(np.asarray(load))
+        loads.append(np.asarray(load['expert_load']))
     np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
                                atol=2e-5 * float(jnp.abs(whole).max()), rtol=0)
     assert int(np.sum(loads)) == 2 * 24 * 4
@@ -280,12 +486,17 @@ def test_the_expert_load_counter_writes_running_totals_a_step_late():
     try:
         counter = moe.ExpertLoadCounter()
         for step in range(5):
-            counter.add({'expert_load': jnp.asarray([step, 10], jnp.int32)})
+            counter.add({'expert_load': jnp.asarray([step, 10], jnp.int32),
+                         'layout_fallbacks': jnp.asarray(step % 2, jnp.int32)})
     finally:
         trace.set_global_tracer(previous)
-    records = [r for r in tracer.records() if r[0].startswith('moe.expert_load')]
+    records = [r for r in tracer.records() if r[0].startswith('moe.')]
     # five steps handed in, three read (the last LOAD_LAG may be in flight)
     assert moe.LOAD_LAG == 2
     assert [r[3] for r in records if r[0].endswith('.e0')] == [0, 1, 3]
     assert [r[3] for r in records if r[0].endswith('.e1')] == [10, 20, 30]
+    assert [r[3] for r in records if r[0] == 'moe.layout_fallbacks'] == \
+        [0, 1, 1]
+    assert {r[0] for r in records} == {
+        'moe.expert_load.e0', 'moe.expert_load.e1', 'moe.layout_fallbacks'}
     assert all(r[1] == 'step' and len(r) == 4 for r in records)
