@@ -76,10 +76,12 @@ def causal_conv_silu(x, kernel):
     ``x [B, T, ...]``, ``kernel [K, ...]``; position ``t`` sees ``t - K + 1
     .. t``, zeros before the row's start."""
     taps, t = kernel.shape[0], x.shape[1]
-    x32 = x.astype(jnp.float32)
-    padded = jnp.pad(x32, ((0, 0), (taps - 1, 0)) + ((0, 0),) * (x.ndim - 2))
-    y = sum(padded[:, i:i + t] * kernel[i] for i in range(taps))
-    return nn.silu(y).astype(x.dtype)
+    with jax.named_scope('conv_silu'):
+        x32 = x.astype(jnp.float32)
+        padded = jnp.pad(x32, ((0, 0), (taps - 1, 0))
+                         + ((0, 0),) * (x.ndim - 2))
+        y = sum(padded[:, i:i + t] * kernel[i] for i in range(taps))
+        return nn.silu(y).astype(x.dtype)
 
 
 def over_row_shards(rule, mesh, batch_axis, impl, *operands):
@@ -248,9 +250,14 @@ class HybridBlock(nn.Module):
         else:
             raise ValueError('unknown layer type {!r}: one of {}'.format(
                 self.layer_type, LAYER_TYPES))
-        x = x + RMSNorm(dtype=self.dtype, name='mixer_norm')(mixer(x))
-        y = SwiGLU(self.d_ff, dtype=self.dtype, name='mlp')(x)
-        return x + RMSNorm(dtype=self.dtype, name='mlp_norm')(y)
+        # A sub-layer's norm and its residual sum under the sub-layer's
+        # name (``Tracer.op_scopes``); the Pallas calls stay innermost in
+        # ``gdn`` and ``attn``.
+        with jax.named_scope('mixer'):
+            x = x + RMSNorm(dtype=self.dtype, name='mixer_norm')(mixer(x))
+        with jax.named_scope('mlp'):
+            y = SwiGLU(self.d_ff, dtype=self.dtype, name='mlp')(x)
+            return x + RMSNorm(dtype=self.dtype, name='mlp_norm')(y)
 
 
 _plans_reported = set()

@@ -62,6 +62,7 @@ import math
 from typing import Any, Optional, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -115,12 +116,14 @@ def rotate(x, frequencies):
     products are those of the interleaved form."""
     t = x.shape[1]
     shape = (1, t) + (1,) * (x.ndim - 3) + (len(frequencies),)
-    cos, sin = (jnp.asarray(table.reshape(shape))
-                for table in _rotary_tables(t, tuple(frequencies)))
-    x32 = x.astype(jnp.float32)
-    even, odd = x32[..., 0::2], x32[..., 1::2]
-    return jnp.concatenate([even * cos - odd * sin, even * sin + odd * cos],
-                           axis=-1).astype(x.dtype)
+    with jax.named_scope('rotary'):
+        cos, sin = (jnp.asarray(table.reshape(shape))
+                    for table in _rotary_tables(t, tuple(frequencies)))
+        x32 = x.astype(jnp.float32)
+        even, odd = x32[..., 0::2], x32[..., 1::2]
+        return jnp.concatenate(
+            [even * cos - odd * sin, even * sin + odd * cos],
+            axis=-1).astype(x.dtype)
 
 
 def sinkhorn(logits, iterations, eps):
@@ -321,7 +324,12 @@ class LatentMoEBlock(nn.Module):
             return attn(attn_norm(inner))
 
         def feed_forward(inner):
-            return ffn(ffn_norm(inner))
+            # The norm under the name of the sub-layer it feeds: the rules
+            # of ``Tracer.op_scopes`` cannot tell a dense layer's
+            # ``ffn_norm`` from an expert layer's.
+            with jax.named_scope(ffn.name):
+                inner = ffn_norm(inner)
+            return ffn(inner)
 
         x, _ = StreamSubLayer(dtype=self.dtype, name='attn_hc',
                               **self.maps_args)(x, attend)
@@ -351,14 +359,16 @@ class NextTokenModule(nn.Module):
 def _copies(h, n):
     """``h [B, T, d]`` as ``n`` equal streams ``[B, T, n d]`` (joined, not
     tiled: XLA makes a tile a 4-D broadcast and then copies it flat)."""
-    return jnp.concatenate([h] * n, axis=-1)
+    with jax.named_scope('streams'):
+        return jnp.concatenate([h] * n, axis=-1)
 
 
 def _summed(x, n):
     """The ``n`` streams of ``x [B, T, n d]`` added up, float32."""
     d = x.shape[-1] // n
-    return sum(x[..., j * d:(j + 1) * d].astype(jnp.float32)
-               for j in range(n))
+    with jax.named_scope('streams'):
+        return sum(x[..., j * d:(j + 1) * d].astype(jnp.float32)
+                   for j in range(n))
 
 
 _plans_reported = set()

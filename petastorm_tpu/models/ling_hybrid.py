@@ -165,14 +165,19 @@ class LingHybridBlock(nn.Module):
         else:
             raise ValueError('unknown layer kind {!r}: one of {}'.format(
                 self.kind, LAYER_KINDS))
-        x = x + mixer(RMSNorm(dtype=self.dtype, name='mixer_norm')(x))
-        inner = RMSNorm(dtype=self.dtype, name='ffn_norm')(x)
-        if self.dense:
-            return x + SwiGLU(self.d_ff, dtype=self.dtype,
-                              name='mlp')(inner), None
-        y, load = RoutedMoE(dtype=self.dtype, name='moe',
-                            **self.moe_args)(inner)
-        return x + y, load
+        # A sub-layer's norm and its residual sum under the sub-layer's
+        # name (``Tracer.op_scopes``); the Pallas calls stay innermost in
+        # ``kda``, ``attn`` and ``moe``.
+        with jax.named_scope('mixer'):
+            x = x + mixer(RMSNorm(dtype=self.dtype, name='mixer_norm')(x))
+        with jax.named_scope('mlp' if self.dense else 'moe'):
+            inner = RMSNorm(dtype=self.dtype, name='ffn_norm')(x)
+            if self.dense:
+                return x + SwiGLU(self.d_ff, dtype=self.dtype,
+                                  name='mlp')(inner), None
+            y, load = RoutedMoE(dtype=self.dtype, name='moe',
+                                **self.moe_args)(inner)
+            return x + y, load
 
 
 _plans_reported = set()

@@ -157,19 +157,20 @@ def top_k_routing(scores, top_k, scale=1.0, normalise=True, n_group=1,
     (DeepSeek-V3's ``noaux_tc``): the experts lie in ``n_group`` equal groups
     in their order, a group's score is the sum of its two best, and the top
     ``top_k`` are taken among the experts of the best ``topk_group`` groups."""
-    allowed = scores
-    if n_group > 1:
-        grouped = scores.reshape(scores.shape[:-1] + (n_group, -1))
-        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
-        _, groups = jax.lax.top_k(group_score, topk_group)
-        kept = jnp.any(groups[..., None] == jnp.arange(n_group), axis=-2)
-        allowed = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
-            scores.shape)
-    _, experts = jax.lax.top_k(allowed, top_k)
-    picked = jnp.take_along_axis(scores, experts, axis=-1)
-    if normalise:
-        picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
-    return experts, picked * scale
+    with jax.named_scope('routing'):
+        allowed = scores
+        if n_group > 1:
+            grouped = scores.reshape(scores.shape[:-1] + (n_group, -1))
+            group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+            _, groups = jax.lax.top_k(group_score, topk_group)
+            kept = jnp.any(groups[..., None] == jnp.arange(n_group), axis=-2)
+            allowed = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+                scores.shape)
+        _, experts = jax.lax.top_k(allowed, top_k)
+        picked = jnp.take_along_axis(scores, experts, axis=-1)
+        if normalise:
+            picked = picked / jnp.sum(picked, axis=-1, keepdims=True)
+        return experts, picked * scale
 
 
 Dispatch = collections.namedtuple('Dispatch', [
@@ -269,7 +270,8 @@ def _rows_forward(x, weights, w_gate_up, w_down, plan, tile_m, impl):
     """``(out [N, d], kept)``: every row's expert applied to its token, and a
     token the sum of its rows under their weights, float32 inside. ``kept``
     is what :func:`_rows_backward` reads again."""
-    rows = x[plan.row_token]
+    with jax.named_scope('gather'):
+        rows = x[plan.row_token]
     hidden = grouped_matmul(rows, w_gate_up, plan.group_sizes, tile_m, impl)
     y = grouped_matmul(_swiglu(hidden), w_down, plan.group_sizes, tile_m,
                        impl)
@@ -282,7 +284,8 @@ def _rows_backward(g, weights, w_gate_up, w_down, plan, kept, tile_m, impl):
     """The cotangents of ``x, weights, w_gate_up, w_down`` from ``g [N, d]``:
     :func:`_rows_forward` gone back through piece by piece."""
     rows, hidden, y = kept
-    g_rows = g[plan.row_token].astype(jnp.float32)
+    with jax.named_scope('gather'):
+        g_rows = g[plan.row_token].astype(jnp.float32)
     dy = (g_rows * _row_weight(weights, plan)[:, None]).astype(y.dtype)
     # <y[r], g[token of r]> a row, then a scatter of ``rows`` scalars.
     d_weights = jnp.zeros((weights.size,), jnp.float32).at[
@@ -309,13 +312,14 @@ def held_experts_on_every_token(x, experts, weights, w_gate_up, w_down, held):
     many are held: what :func:`routed_experts` runs where the held pairs pass
     its rows, at most ``experts_published / (4 k)`` times the arithmetic the
     pairs asked for, on nothing but the matrix unit."""
-    picked = experts[:, :, None] == jnp.asarray(held, jnp.int32)
-    weight = jnp.sum(jnp.where(picked, weights[:, :, None], 0.0), axis=1)
-    hidden = jnp.einsum('nd,gdf->ngf', x, w_gate_up.astype(x.dtype))
-    weighted = _swiglu(hidden).astype(jnp.float32) * weight[:, :, None]
-    return jnp.einsum('ngf,gfd->nd', weighted.astype(x.dtype),
-                      w_down.astype(x.dtype),
-                      preferred_element_type=jnp.float32).astype(x.dtype)
+    with jax.named_scope('every_token'):
+        picked = experts[:, :, None] == jnp.asarray(held, jnp.int32)
+        weight = jnp.sum(jnp.where(picked, weights[:, :, None], 0.0), axis=1)
+        hidden = jnp.einsum('nd,gdf->ngf', x, w_gate_up.astype(x.dtype))
+        weighted = _swiglu(hidden).astype(jnp.float32) * weight[:, :, None]
+        return jnp.einsum('ngf,gfd->nd', weighted.astype(x.dtype),
+                          w_down.astype(x.dtype),
+                          preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _varying_like(value, like):
@@ -346,8 +350,9 @@ def _routed(x, experts, weights, w_gate_up, w_down, static):
 @functools.partial(jax.jit, static_argnums=(5,), inline=True)
 def _routed_fwd(x, experts, weights, w_gate_up, w_down, static):
     held, capacity, tile_m, impl, name = static
-    counts, order = sorted_pairs(experts, held)
-    plan = layout(counts, order, experts.shape[1], tile_m, capacity)
+    with jax.named_scope('dispatch'):
+        counts, order = sorted_pairs(experts, held)
+        plan = layout(counts, order, experts.shape[1], tile_m, capacity)
     operands = (x, weights, w_gate_up, w_down)
 
     def rows(*operands):
@@ -539,9 +544,10 @@ def total_load(held, loads):
     :class:`RoutedMoE` summed (``None``: a layer that routes nothing)."""
     total = {'expert_load': jnp.zeros((len(held),), jnp.int32),
              'layout_fallbacks': jnp.zeros((), jnp.int32)}
-    for load in loads:
-        if load is not None:
-            total = jax.tree.map(jnp.add, total, load)
+    with jax.named_scope('routing'):
+        for load in loads:
+            if load is not None:
+                total = jax.tree.map(jnp.add, total, load)
     return total
 
 

@@ -10,6 +10,7 @@ from functools import partial
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 ModuleDef = Any
@@ -82,15 +83,17 @@ class ResNet(nn.Module):
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         norm = partial(nn.BatchNorm, use_running_average=not train, momentum=0.9,
                        epsilon=1e-5, dtype=self.dtype)
-        x = x.astype(self.dtype)
+        with jax.named_scope('stem'):       # in no module of their own
+            x = x.astype(self.dtype)
         if self.stem == 'space_to_depth':
             b, h, w, c = x.shape
             if h % 2 or w % 2:
                 raise ValueError('space_to_depth stem needs even H/W, got '
                                  '{}x{}'.format(h, w))
-            x = (x.reshape(b, h // 2, 2, w // 2, 2, c)
-                 .transpose(0, 1, 3, 2, 4, 5)
-                 .reshape(b, h // 2, w // 2, 4 * c))
+            with jax.named_scope('stem'):
+                x = (x.reshape(b, h // 2, 2, w // 2, 2, c)
+                     .transpose(0, 1, 3, 2, 4, 5)
+                     .reshape(b, h // 2, w // 2, 4 * c))
             x = conv(self.num_filters, (4, 4), padding='SAME',
                      name='conv_init')(x)
         elif self.stem == 'conv7':
@@ -99,16 +102,18 @@ class ResNet(nn.Module):
         else:
             raise ValueError('unknown stem {!r}'.format(self.stem))
         x = norm(name='bn_init')(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding='SAME')
+        with jax.named_scope('stem'):
+            x = nn.relu(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding='SAME')
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
                 strides = 2 if i > 0 and j == 0 else 1
                 x = self.block_cls(self.num_filters * 2 ** i, conv=conv, norm=norm,
                                    act=nn.relu, strides=strides)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=self.dtype, name='head')(x)
-        return x.astype(jnp.float32)
+        with jax.named_scope('head'):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=self.dtype, name='head')(x)
+            return x.astype(jnp.float32)
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=ResNetBlock)

@@ -32,6 +32,7 @@ from flax.training import train_state
 from jax.sharding import NamedSharding, PartitionSpec
 
 from petastorm_tpu.ops.cross_entropy import softmax_cross_entropy
+from petastorm_tpu.trace import StepProgram
 
 
 class TrainState(train_state.TrainState):
@@ -144,8 +145,9 @@ def summed_loss(logits, labels):
 
 def make_train_step(mesh=None, batch_axis='data'):
     """Build a jitted train step ``(state, images, labels) -> (state, metrics)``."""
-    return jax.jit(make_train_step_fn(mesh=mesh, batch_axis=batch_axis),
-                   donate_argnums=(0,))
+    return StepProgram(jax.jit(
+        make_train_step_fn(mesh=mesh, batch_axis=batch_axis),
+        donate_argnums=(0,)))
 
 
 def make_scan_train_step(mesh=None, batch_axis='data', microbatches=8,
@@ -189,7 +191,7 @@ def make_scan_train_step(mesh=None, batch_axis='data', microbatches=8,
         return state, {'loss': losses.mean(), 'accuracy': accs.mean(),
                        'last_loss': losses[-1]}
 
-    return jax.jit(scan_train, donate_argnums=(0,))
+    return StepProgram(jax.jit(scan_train, donate_argnums=(0,)))
 
 
 def make_train_step_fn(mesh=None, batch_axis='data'):
@@ -216,15 +218,19 @@ def make_train_step_fn(mesh=None, batch_axis='data'):
                 logits = state.apply_fn(variables, images, train=True)
                 new_batch_stats = None
             logits, extra = _model_outputs(logits)
-            loss, hit = summed_loss(logits, labels)
-            return loss, (hit, new_batch_stats, extra)
+            with jax.named_scope('loss'):
+                loss, hit = summed_loss(logits, labels)
+                accuracy = jnp.mean(hit)
+            return loss, (accuracy, new_batch_stats, extra)
 
-        (loss, (hit, new_batch_stats, extra)), grads = jax.value_and_grad(
+        (loss, (accuracy, new_batch_stats, extra)), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state.params)
-        state = state.apply_gradients(grads=grads)
+        # The gradients' casts, the optimizer's update and its application.
+        with jax.named_scope('optimizer'):
+            state = state.apply_gradients(grads=grads)
         if new_batch_stats is not None:
             state = state.replace(batch_stats=new_batch_stats)
-        return state, dict(extra, loss=loss, accuracy=jnp.mean(hit))
+        return state, dict(extra, loss=loss, accuracy=accuracy)
 
     return train_step
 

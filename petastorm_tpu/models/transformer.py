@@ -190,26 +190,31 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         d_model = x.shape[-1]
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        y = MultiHeadAttention(self.num_heads, attention=self.attention,
-                               causal=self.causal,
-                               mesh=self.mesh, seq_axis=self.seq_axis,
-                               batch_axis=self.batch_axis,
-                               head_axis=self.head_axis,
-                               dtype=self.dtype, name='attn')(y)
-        x = x + y
-        y = nn.LayerNorm(dtype=self.dtype)(x)
-        if self.moe_experts > 0:
-            from petastorm_tpu.models.moe import SwitchMoE
-            y = SwitchMoE(num_experts=self.moe_experts,
-                          mlp_ratio=self.mlp_ratio, mesh=self.mesh,
-                          expert_axis=self.expert_axis, dtype=self.dtype,
-                          name='moe')(y)
-        else:
-            y = nn.Dense(d_model * self.mlp_ratio, dtype=self.dtype)(y)
-            y = nn.gelu(y)
-            y = nn.Dense(d_model, dtype=self.dtype)(y)
-        return x + y
+        # A sub-layer's norm, its activation and its residual sum run in no
+        # module of their own: the two scopes say which sub-layer they are
+        # (``Tracer.op_scopes``). The Pallas calls stay innermost in ``attn``.
+        with jax.named_scope('mixer'):
+            y = nn.LayerNorm(dtype=self.dtype)(x)
+            y = MultiHeadAttention(self.num_heads, attention=self.attention,
+                                   causal=self.causal,
+                                   mesh=self.mesh, seq_axis=self.seq_axis,
+                                   batch_axis=self.batch_axis,
+                                   head_axis=self.head_axis,
+                                   dtype=self.dtype, name='attn')(y)
+            x = x + y
+        with jax.named_scope('moe' if self.moe_experts > 0 else 'mlp'):
+            y = nn.LayerNorm(dtype=self.dtype)(x)
+            if self.moe_experts > 0:
+                from petastorm_tpu.models.moe import SwitchMoE
+                y = SwitchMoE(num_experts=self.moe_experts,
+                              mlp_ratio=self.mlp_ratio, mesh=self.mesh,
+                              expert_axis=self.expert_axis, dtype=self.dtype,
+                              name='moe')(y)
+            else:
+                y = nn.Dense(d_model * self.mlp_ratio, dtype=self.dtype)(y)
+                y = nn.gelu(y)
+                y = nn.Dense(d_model, dtype=self.dtype)(y)
+            return x + y
 
 
 class TransformerLM(nn.Module):
@@ -242,16 +247,20 @@ class TransformerLM(nn.Module):
             # last positional embedding — fail loudly instead (t is static).
             raise ValueError('sequence length {} exceeds max_len {}'.format(
                 t, self.max_len))
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype)(tokens)
-        pos = nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
-                       name='pos_embed')(jnp.arange(t)[None, :])
-        x = x + pos
+        with jax.named_scope('embed'):
+            x = nn.Embed(self.vocab_size, self.d_model,
+                         dtype=self.dtype)(tokens)
+            pos = nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
+                           name='pos_embed')(jnp.arange(t)[None, :])
+            x = x + pos
         for i in range(self.num_layers):
             x = Block(self.num_heads, attention=self.attention, mesh=self.mesh,
                       seq_axis=self.seq_axis, batch_axis=self.batch_axis,
                       head_axis=self.head_axis, moe_experts=self.moe_experts,
                       expert_axis=self.expert_axis, dtype=self.dtype,
                       name='block_{}'.format(i))(x)
-        x = nn.LayerNorm(dtype=self.dtype)(x)
-        logits = nn.Dense(self.vocab_size, dtype=self.dtype, name='head')(x)
-        return logits.astype(jnp.float32)
+        with jax.named_scope('head'):
+            x = nn.LayerNorm(dtype=self.dtype)(x)
+            logits = nn.Dense(self.vocab_size, dtype=self.dtype,
+                              name='head')(x)
+            return logits.astype(jnp.float32)

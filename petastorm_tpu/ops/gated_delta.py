@@ -438,8 +438,11 @@ def gated_delta_rule(q, k, v, g, beta, chunk=64, impl='chunked'):
         return a.reshape((b * h, n, chunk) + a.shape[3:])
 
     v = v.astype(q.dtype)
-    operands = _transform(chunked(q), chunked(k), chunked(v), chunked(g),
-                          chunked(beta))
+    # Stage 1 under a scope of its own (``Tracer.op_scopes`` tells it from
+    # the pass); the pass's Pallas calls stay innermost in the caller's.
+    with jax.named_scope('transform'):
+        operands = _transform(chunked(q), chunked(k), chunked(v), chunked(g),
+                              chunked(beta))
     o = _chunk_pass(*operands, impl)                        # [BH, N, C, dv]
     o = o.reshape(b, h, n * chunk, -1)[:, :, :t]
     return jnp.moveaxis(o, 1, 2)

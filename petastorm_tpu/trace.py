@@ -76,6 +76,11 @@ TRACE_DIR_ENV = 'PETASTORM_TPU_TRACE_DIR'
 #: should write about 600 a second, 50 s of which fit.
 DEFAULT_RING_EVENTS = 32768
 
+#: How many train steps (:class:`StepProgram`) a tracer keeps for
+#: :meth:`Tracer.op_scopes`: the newest, as the ring keeps its newest
+#: records. A kept step keeps its ``jax.jit`` object and what that compiled.
+MAX_STEP_PROGRAMS = 4
+
 _SIDECAR_GLOB = 'trace-*.jsonl'
 _HEADER_KEY = '__pst_trace_sidecar__'
 
@@ -204,6 +209,11 @@ class Tracer(object):
                            if spill_max_events is not None else max_events)
         self._merged = []            # events folded in from sidecar files
         self._roles = {}             # pid -> role (merged sidecar headers)
+        # The newest train steps that compiled a program
+        # (:class:`StepProgram`, each with its signatures and tables), and
+        # how many programs each function's name has named so far
+        self._steps = deque(maxlen=MAX_STEP_PROGRAMS)
+        self._programs_named = {}
 
     # -- recording ---------------------------------------------------------
 
@@ -230,6 +240,60 @@ class Tracer(object):
         next to the spans it explains: arena-pool occupancy, the in-flight
         transfer window, a wait's empty wake-ups."""
         self._record((name, cat, _perf_ns(), value))
+
+    def note_program(self, step, function, leaves, nbytes):
+        """A :class:`StepProgram` compiled a program for a signature it had
+        not met: name it (``train_step``, then ``train_step#2``...), write
+        one ``step.program`` instant (cat ``step``; args: the function, the
+        program's name, the signature's leaves and bytes) and keep the step
+        for :meth:`op_scopes`. The instant's time says when the step was
+        traced anew: before a timed window it is a warm-up, inside one it is
+        the retrace behind that window's ``jax.compile`` span
+        (``docs/troubleshoot.rst``), and a reader of several programs takes
+        the newest before its window's end as the one the window ran
+        (``perfbench/scope_reduce.py``). The newest
+        :data:`MAX_STEP_PROGRAMS` steps are kept, as the ring keeps its
+        newest records: a step has to outlive its caller's last reference to
+        be asked about after a run, and a process that builds steps in a
+        loop holds no more than these."""
+        with self._lock:
+            n = self._programs_named.get(function, 0) + 1
+            self._programs_named[function] = n
+            if not any(known is step for known in self._steps):
+                self._steps.append(step)
+        program = function if n == 1 else '{}#{}'.format(function, n)
+        self.instant('step.program', cat='step', args={
+            'function': function, 'program': program, 'leaves': leaves,
+            'bytes': nbytes})
+        return program
+
+    def op_scopes(self):
+        """``{program: {'module': name, 'instructions': {instruction name:
+        {'opcode', 'result', 'part', 'pass', 'path', 'parts_fused'}}}}`` for
+        the train steps this process compiled (one entry a distinct
+        signature of :func:`petastorm_tpu.models.train.make_train_step`'s
+        callable, named as its ``step.program`` instant names it): which
+        part of the model and which pass (``forward``, ``recompute``,
+        ``backward``, ``update``) each instruction of the compiled step
+        belongs to, by the scope its ``op_name`` carries
+        (``petastorm_tpu.models.scopes``: the parts and their rules). A
+        device trace names its events by the same instruction names, so
+        this is what puts a ``jax.profiler`` capture down to the model's
+        parts (``docs/troubleshoot.rst``).
+
+        Computed here, on demand and once a program, from
+        ``jitted.lower(*signature).compile().as_text()``: jax answers that
+        from the caches the step's own call filled (no compilation, no
+        ``jax.compile`` span), and nothing is computed before somebody
+        asks. ``parts_fused`` is ``None`` but for a fusion; a container
+        (``scopes.CONTAINERS``) keeps its opcode so that a reader can leave
+        it out and count what it runs once."""
+        with self._lock:
+            steps = list(self._steps)
+        out = {}
+        for step in steps:
+            out.update(step.op_scopes())
+        return out
 
     def _record(self, record):
         self._events.append(record)
@@ -578,6 +642,95 @@ def watch_jax_compiles():
         monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
+# -- the step's programs: what :meth:`Tracer.op_scopes` hands out -------------------
+
+
+def _abstract(x):
+    """What ``jit.lower`` needs of one argument and no more: never the array
+    (a train step donates its state)."""
+    shape, dtype = getattr(x, 'shape', None), getattr(x, 'dtype', None)
+    if shape is None or dtype is None:
+        return x
+    import jax
+    # An array nobody placed lowers with no sharding, as its call did.
+    sharding = getattr(x, 'sharding', None) if getattr(
+        x, 'committed', False) else None
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding,
+                                weak_type=bool(getattr(x, 'weak_type', False)))
+
+
+class StepProgram(object):
+    """A train step's ``jax.jit`` object behind one Python frame: calls and
+    attributes (``.lower``, ``.trace``, ...) go through, and when a call
+    grew the jit's cache (a first call, a new signature) the call's abstract
+    signature is kept here, beside the jit object it belongs to, and the
+    global tracer is told (:meth:`Tracer.note_program`). :meth:`op_scopes`
+    lowers the signatures again when somebody asks."""
+
+    def __init__(self, jitted):
+        self._jitted = jitted
+        self._name = getattr(jitted, '__name__', 'step')
+        # jax's own count of the programs a jit object holds (private, and
+        # all there is that costs a call an integer compare); a jax without
+        # it gets its first call's signature alone.
+        self._cache_size = getattr(jitted, '_cache_size', lambda: 1)
+        self._compiled = 0
+        self._signatures = {}       # treedef and leaves -> program's name
+        self._tables = {}           # program's name -> [signature, table]
+        self.__wrapped__ = jitted
+
+    def __call__(self, *args, **kwargs):
+        out = self._jitted(*args, **kwargs)
+        compiled = self._cache_size()
+        if compiled != self._compiled:
+            self._compiled = compiled
+            self._note(args, kwargs)
+        return out
+
+    def __getattr__(self, name):
+        return getattr(self._jitted, name)
+
+    def _note(self, args, kwargs):
+        import jax
+        leaves = jax.tree_util.tree_leaves((args, kwargs))
+        # A call under another trace is that trace's program, not one of
+        # its own.
+        if any(isinstance(leaf, jax.core.Tracer) for leaf in leaves):
+            return
+        signature = jax.tree_util.tree_map(_abstract, (args, kwargs))
+        flat, treedef = jax.tree_util.tree_flatten(signature)
+        key = (treedef, tuple(flat))
+        if key in self._signatures:
+            return
+        program = get_global_tracer().note_program(
+            self, self._name, len(leaves),
+            sum(getattr(leaf, 'nbytes', 0) for leaf in leaves))
+        if program is not None:         # None: recording is switched off
+            self._signatures[key] = program
+            self._tables[program] = [signature, None]
+
+    def op_scopes(self):
+        """``{program: table}`` of this step's programs
+        (:meth:`Tracer.op_scopes`), each parsed once."""
+        import jax
+        from petastorm_tpu.models.scopes import parse_hlo_scopes
+        for known in self._tables.values():
+            if known[1] is not None:
+                continue
+            if jax.config.jax_compilation_cache_dir and not \
+                    jax.config.jax_compilation_cache_include_metadata_in_key:
+                logger.warning(
+                    'op_scopes: the persistent compilation cache is on and '
+                    'does not key a program by its metadata: a step that '
+                    'came back from it carries the scopes of the build that '
+                    'cached it (petastorm_tpu.utils.enable_compile_cache '
+                    'sets the key)')
+            args, kwargs = known[0]
+            known[1] = parse_hlo_scopes(self._jitted.lower(
+                *args, **kwargs).compile().as_text())
+        return {program: known[1] for program, known in self._tables.items()}
+
+
 class _NullSpan(object):
     id = cause = None
 
@@ -607,4 +760,7 @@ class NullTracer(Tracer):
     def instant(self, *args, **kwargs):
         pass
 
-    counter = _record = instant
+    counter = _record = note_program = instant
+
+    def op_scopes(self):
+        return None

@@ -58,12 +58,21 @@ def enable_compile_cache():
     it lives at one fixed path inside the checkout (git-ignored): the path
     is part of the cache key, so a temp name, a pid or a timestamp would
     never hit.
+
+    Either way a program's metadata is made part of its key
+    (``jax_compilation_cache_include_metadata_in_key``; jax leaves it out by
+    default): the scopes an instruction was traced in are metadata, and
+    ``Tracer.op_scopes()`` reads them from the compiled step's text, so a
+    step that differs from a cached one only in a ``jax.named_scope`` has
+    to be a program of its own, or it would come back from the cache under
+    the other one's names.
     """
     import os
+    import jax
+    jax.config.update('jax_compilation_cache_include_metadata_in_key', True)
     placed = os.environ.get('JAX_COMPILATION_CACHE_DIR')
     if placed:
         return placed
-    import jax
     path = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         '.jax_compile_cache')

@@ -105,6 +105,45 @@ def test_compile_cache_defaults_to_one_fixed_path_in_the_checkout():
     assert '.jax_compile_cache/' in ignored
 
 
+_SCOPES_PROBE = (
+    'import json, os, re, sys\n'
+    'import jax, jax.numpy as jnp\n'
+    'from petastorm_tpu.utils import enable_compile_cache\n'
+    'if sys.argv[1] == "keyed": enable_compile_cache()\n'
+    'jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)\n'
+    'jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)\n'
+    'def program(scope):\n'
+    '    def f(x, w):\n'
+    '        with jax.named_scope(scope):\n'
+    '            return jnp.tanh(x @ w).sum()\n'
+    '    return jax.jit(f)\n'
+    'x = jnp.ones((8, 8))\n'
+    'names = []\n'
+    'for scope in ("mixer", "mlp"):\n'
+    '    text = program(scope).lower(x, x).compile().as_text()\n'
+    '    names.append(sorted(set(re.findall(\n'
+    '        r\'op_name="jit\\(f\\)/([^/"]*)/\', text))))\n'
+    'print(json.dumps({"names": names, "entries": sum(\n'
+    '    name.startswith("jit_f-") for name in os.listdir(\n'
+    '        os.environ["JAX_COMPILATION_CACHE_DIR"]))}))\n')
+
+
+def test_a_cached_program_is_keyed_by_its_scopes_too(tmp_path):
+    """Two programs that differ in a ``jax.named_scope`` alone: with jax's
+    default key the second comes back from the cache under the first one's
+    names, which is what ``Tracer.op_scopes()`` would then hand out;
+    ``enable_compile_cache`` makes each a program of its own."""
+    out = {}
+    for mode in ('default', 'keyed'):
+        proc = _run(['-c', _SCOPES_PROBE, mode], {
+            'JAX_COMPILATION_CACHE_DIR': str(tmp_path / mode),
+            'JAX_PLATFORMS': 'cpu'})
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        out[mode] = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out['default'] == {'names': [['mixer'], ['mixer']], 'entries': 1}
+    assert out['keyed'] == {'names': [['mixer'], ['mlp']], 'entries': 2}
+
+
 # -- kernels ----------------------------------------------------------------
 
 def _tpu_lowering(fn, *avals):
