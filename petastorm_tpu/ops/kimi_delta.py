@@ -40,11 +40,17 @@ kernel): from the gradients of ``qg, p, kg, w, u`` back through ``T``
 (``dL = -lower(dWb W^T + dUb U^T)``), the sub-block products and the
 exponentials to ``q, k, v, g, b``. Two implementations run those two bodies:
 ``lax.scan`` over chunks in ``jax.numpy`` (``impl='chunked'``) and Pallas calls
-on a grid ``(rows, heads, chunks)`` that read and write the model's own
-``[B, T, H d]`` arrays a 128-lane head at a time, the chunk axis in order
-with the state in VMEM scratch (``'pallas'``, ``'pallas:interpret'``). What
-the reverse pass reads beside the operands: the state each chunk starts from
-and its ``T``.
+on a grid ``(rows, heads / s, chunks)`` that read and write the model's own
+``[B, T, H d]`` arrays a band of ``s`` 128-lane heads at a time, the chunk
+axis in order with the ``s`` states in VMEM scratch (``'pallas'``,
+``'pallas:interpret'``). A grid step runs the one-head body batched over
+its ``s`` heads (``jax.vmap``: every product and every vector operation
+takes the ``s`` heads at once; straight-line code a head at a time gained
+a fifth as much, PERF.md section 6, PR 38), so the heads' independent
+chains of small products fill each other's waits and the step's fixed cost
+is paid once for all ``s`` (:func:`kda_plan`'s ``heads_per_step``). What the
+reverse pass reads beside the operands: the state each chunk starts from and
+its ``T``.
 """
 
 import functools
@@ -60,6 +66,15 @@ from petastorm_tpu.ops.gated_delta import (_NN, _NT, _TN, HIGHEST, IMPLS,
                                            _mosaic_params, report_plan)
 
 GATE_LOWER_BOUND = -5.0
+#: Heads a grid step of the Pallas calls holds at most (a scratch timing at
+#: ``[1, 8192, 32, 128]``, PERF.md section 6, PR 38: 1, 2, 4 and 8 heads a
+#: step ran a head and chunk forward in 2.16, 1.49, 1.14 and 1.07 us; 16 ask
+#: Mosaic for more VMEM than its scoped limit).
+HEADS_PER_STEP = 8
+#: VMEM the blocks of a grid step may plan for (:func:`kda_plan`'s
+#: ``vmem_bytes``), half of Mosaic's default scoped limit on a v5e: the body's
+#: own intermediates take the rest.
+STEP_VMEM_BUDGET = 8 * 2 ** 20
 
 
 # --------------------------------------------------------------------------
@@ -94,23 +109,40 @@ def kda_scan(q, k, v, g, beta):
 
 def kda_plan(t, heads_held, dk, dv, chunk, sub_block, impl, dtype):
     """What a call on ``T`` tokens runs: the account ``kernel.kda_plan``
-    carries. ``vmem_bytes``: what the reverse kernel, the largest, holds at
-    once: its blocks twice (the pipeline's two buffers) and the state's
-    gradient."""
+    carries. ``heads_per_step``: the heads a grid step of the kernels holds,
+    the largest divisor of ``heads_held`` not over :data:`HEADS_PER_STEP`
+    whose blocks fit :data:`STEP_VMEM_BUDGET`, 1 at the least.
+    ``vmem_bytes``: what a grid step of the reverse kernel, the largest,
+    holds at once: its blocks twice (the pipeline's two buffers) and the
+    states' gradients."""
     chunks = -(-t // chunk)
     size = jnp.dtype(dtype).itemsize
     wide = chunk * (2 * dk + dv)
     blocks = (2 * wide * size + chunk * dv * size       # q k v dq dk dv, do
-              + 2 * chunk * dk * 4 + 2 * chunk * 4      # g dg, beta dbeta
+              + 2 * chunk * dk * 4                      # g dg
+              + chunks * chunk * 4                      # dbeta, a whole row
               + dk * dv * size + chunk * chunk * size)  # saved state, T
+    per_head = 2 * blocks + 4 * dk * dv
+    shared = 2 * chunk * heads_held * 4                 # beta, every head
+    heads = max(1, min(HEADS_PER_STEP, heads_held,
+                       (STEP_VMEM_BUDGET - shared) // per_head))
+    while heads_held % heads:
+        heads -= 1
     return {'t': t, 'chunk': chunk, 'sub_block': sub_block,
             'chunks_per_row': chunks, 't_pad': chunks * chunk,
-            'heads_held': heads_held, 'key_width': dk, 'value_width': dv,
+            'heads_held': heads_held, 'heads_per_step': heads,
+            'key_width': dk, 'value_width': dv,
             'gate_lower_bound': GATE_LOWER_BOUND,
             'largest_exponent': -GATE_LOWER_BOUND * (sub_block - 1),
             'state_bytes_per_head': 4 * dk * dv,
-            'vmem_bytes': 2 * blocks + 4 * dk * dv,
+            'vmem_bytes': heads * per_head + shared,
             'impl': impl, 'dtype': dtype}
+
+
+def _plan(q, v, chunk, sub, impl):
+    _, t, h, dk = q.shape
+    return kda_plan(t, h, dk, v.shape[-1], chunk, sub, impl,
+                    jnp.dtype(q.dtype).name)
 
 
 # --------------------------------------------------------------------------
@@ -342,28 +374,60 @@ def _from_chunks(a):
 # the pass over chunks, Pallas: the model's own [B, T, H d] arrays
 # --------------------------------------------------------------------------
 
-def _beta_column(beta_ref):
-    """This head's column of the ``[C, H]`` block."""
+def _beta_columns(beta_ref, heads):
+    """The step's heads' columns of the chunk's ``[C, H]`` block, ``[s, C,
+    1]``."""
     import jax.experimental.pallas as pl
     block = beta_ref[...].astype(jnp.float32)
-    return jnp.sum(jnp.where(_iota(block.shape, 1) == pl.program_id(1),
-                             block, 0.0), axis=1, keepdims=True)
+    lane = _iota(block.shape, 1)
+    first = pl.program_id(1) * heads
+    return jnp.stack([
+        jnp.sum(jnp.where(lane == first + a, block, 0.0), axis=1,
+                keepdims=True) for a in range(heads)])
+
+
+def _heads(ref, heads):
+    """A ``[C, s d]`` block as ``[s, C, d]``: the step's heads on a leading
+    axis, from static 128-lane slices."""
+    width = ref.shape[1] // heads
+    return jnp.stack([ref[:, a * width:(a + 1) * width]
+                      for a in range(heads)])
+
+
+def _put(ref, x):
+    """``x [s, C, d]`` into the ``[C, s d]`` block ``ref``, a head's lanes
+    at a time."""
+    heads, _, width = x.shape
+    for a in range(heads):
+        ref[:, a * width:(a + 1) * width] = x[a].astype(ref.dtype)
+
+
+def _step_body(body, sub, heads):
+    """The one-head ``body`` over ``[s, ...]`` operands: ``jax.vmap``, so
+    that every product and vector operation takes the step's heads at once.
+    A lone head runs the body as it stands: batched over one it is slower
+    than today's kernel (PERF.md section 6, PR 38)."""
+    body = functools.partial(body, sub=sub)
+    if heads > 1:
+        return jax.vmap(body)
+    return lambda *a: tuple(x[None] for x in body(*(x[0] for x in a)))
 
 
 def _forward_kernel(sub, save, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
                     *rest):
     import jax.experimental.pallas as pl
     state_ref = rest[-1]
+    heads = state_ref.shape[0]
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     h = state_ref[...]
-    state_ref[...], o, inverse = _chunk_forward_kda(
-        h, q_ref[...], k_ref[...], v_ref[...], g_ref[...],
-        _beta_column(beta_ref), sub)
-    o_ref[...] = o.astype(o_ref.dtype)
+    state_ref[...], o, inverse = _step_body(_chunk_forward_kda, sub, heads)(
+        h, *(_heads(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
+        _beta_columns(beta_ref, heads))
+    _put(o_ref, o)
     if save:
         h_ref, t_ref = rest[:2]
         h_ref[...] = h.astype(h_ref.dtype)
@@ -375,21 +439,24 @@ def _backward_kernel(sub, do_ref, h_ref, t_ref, q_ref, k_ref, v_ref, g_ref,
                      grad_ref):
     import jax.experimental.pallas as pl
     i = pl.program_id(2)
+    heads = grad_ref.shape[0]
 
     @pl.when(i == 0)
     def _init():
         grad_ref[...] = jnp.zeros_like(grad_ref)
 
-    grad_ref[...], dq, dk, dv, dg, dbeta = _chunk_backward_kda(
-        grad_ref[...], do_ref[...], h_ref[...], t_ref[...], q_ref[...],
-        k_ref[...], v_ref[...], g_ref[...], _beta_column(beta_ref), sub)
-    dq_ref[...] = dq.astype(dq_ref.dtype)
-    dk_ref[...] = dk.astype(dk_ref.dtype)
-    dv_ref[...] = dv.astype(dv_ref.dtype)
-    dg_ref[...] = dg
+    grad_ref[...], dq, dk, dv, dg, dbeta = _step_body(
+        _chunk_backward_kda, sub, heads)(
+            grad_ref[...], _heads(do_ref, heads), h_ref[...], t_ref[...],
+            *(_heads(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
+            _beta_columns(beta_ref, heads))
+    for ref, x in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv), (dg_ref, dg)):
+        _put(ref, x)
     # The heads' write strengths share a lane block, so a head's gradient
     # goes out as a row of its own [chunks, C] block, the last chunk first.
-    dbeta_ref[pl.ds(pl.num_programs(2) - 1 - i, 1), :] = _row(dbeta)
+    for a in range(heads):
+        dbeta_ref[a, pl.ds(pl.num_programs(2) - 1 - i, 1), :] = \
+            _row(dbeta[a])
 
 
 def _lanes(a):
@@ -397,29 +464,31 @@ def _lanes(a):
     return a.reshape(a.shape[:2] + (-1,))
 
 
-def _call(kernel, grid, chunk_of, operands, h, chunk, outs, scratch,
+def _call(kernel, chunk_of, operands, h, heads, chunk, outs, scratch,
           interpret):
-    """``operands``: name -> array; ``outs``: name -> struct. Blocks by the
-    array's kind: a head's 128-lane band of a chunk of ``[B, T, H d]``, the
-    chunk's ``[C, H]`` write strengths, a chunk's ``[., .]`` of a ``[B, H,
-    N, ., .]`` residual, a head's whole ``[N, C]`` of ``dbeta``."""
+    """``operands``: name -> array; ``outs``: name -> struct; ``h`` heads of
+    the call, ``heads`` of a grid step. Blocks by the array's kind: the band
+    of ``heads`` 128-lane heads of a chunk of ``[B, T, H d]``, the chunk's
+    ``[C, H]`` write strengths, ``heads`` heads' chunk of a ``[B, H, N, .,
+    .]`` residual, their whole ``[N, C]`` of ``dbeta``."""
     import jax.experimental.pallas as pl
+    b, t = operands['q'].shape[:2]
 
     def spec(name, a):
         if name == 'beta':
             return pl.BlockSpec((None, chunk, h),
                                 lambda b, j, i: (b, chunk_of(i), 0))
         if name == 'dbeta':
-            return pl.BlockSpec((None, None) + a.shape[2:],
+            return pl.BlockSpec((None, heads) + a.shape[2:],
                                 lambda b, j, i: (b, j, 0, 0))
         if a.ndim == 5:
-            return pl.BlockSpec((None, None, None) + a.shape[3:],
+            return pl.BlockSpec((None, heads, None) + a.shape[3:],
                                 lambda b, j, i: (b, j, chunk_of(i), 0, 0))
-        return pl.BlockSpec((None, chunk, a.shape[2] // h),
+        return pl.BlockSpec((None, chunk, heads * a.shape[2] // h),
                             lambda b, j, i: (b, chunk_of(i), j))
 
     return pl.pallas_call(
-        kernel, grid=grid,
+        kernel, grid=(b, h // heads, t // chunk),
         in_specs=[spec(*item) for item in operands.items()],
         out_specs=[spec(*item) for item in outs.items()],
         out_shape=list(outs.values()), scratch_shapes=scratch,
@@ -427,7 +496,7 @@ def _call(kernel, grid, chunk_of, operands, h, chunk, outs, scratch,
         **_mosaic_params(interpret, independent_axes=2))(*operands.values())
 
 
-def _forward_pallas(q, k, v, g, beta, chunk, sub, save, interpret):
+def _forward_pallas(q, k, v, g, beta, chunk, sub, heads, save, interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -438,14 +507,14 @@ def _forward_pallas(q, k, v, g, beta, chunk, sub, save, interpret):
     if save:
         outs['h'] = _out_struct((b, h, n, dk, dv), q.dtype, q)
         outs['inverse'] = _out_struct((b, h, n, chunk, chunk), q.dtype, q)
-    out = _call(functools.partial(_forward_kernel, sub, save), (b, h, n),
-                lambda i: i, operands, h, chunk, outs,
-                [pltpu.VMEM((dk, dv), jnp.float32)], interpret)
+    out = _call(functools.partial(_forward_kernel, sub, save), lambda i: i,
+                operands, h, heads, chunk, outs,
+                [pltpu.VMEM((heads, dk, dv), jnp.float32)], interpret)
     return (out[0].reshape(b, t, h, dv),) + tuple(out[1:])
 
 
 def _backward_pallas(do, states, inverses, q, k, v, g, beta, chunk, sub,
-                     interpret):
+                     heads, interpret):
     from jax.experimental.pallas import tpu as pltpu
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -460,9 +529,9 @@ def _backward_pallas(do, states, inverses, q, k, v, g, beta, chunk, sub,
             'dg': _out_struct((b, t, h * dk), f32, q),
             'dbeta': _out_struct((b, h, n, chunk), f32, q)}
     dq, dk_, dv_, dg, dbeta = _call(
-        functools.partial(_backward_kernel, sub), (b, h, n),
-        lambda i: n - 1 - i, operands, h, chunk, outs,
-        [pltpu.VMEM((dk, dv), f32)], interpret)
+        functools.partial(_backward_kernel, sub), lambda i: n - 1 - i,
+        operands, h, heads, chunk, outs,
+        [pltpu.VMEM((heads, dk, dv), f32)], interpret)
     wide = (b, t, h, dk)
     return (dq.reshape(wide), dk_.reshape(wide), dv_.reshape(b, t, h, dv),
             dg.reshape(wide),
@@ -486,8 +555,9 @@ def _forward(q, k, v, g, beta, chunk, sub, impl, save):
             *(_to_chunks(a, n, chunk) for a in (q, k, v, g, beta[..., None])),
             sub)
         return _from_chunks(o), states, inverses
-    out = _forward_pallas(q, k, v, g, beta, chunk, sub, save,
-                          impl == 'pallas:interpret')
+    out = _forward_pallas(q, k, v, g, beta, chunk, sub,
+                          _plan(q, v, chunk, sub, impl)['heads_per_step'],
+                          save, impl == 'pallas:interpret')
     return out if save else out + (None, None)
 
 
@@ -513,8 +583,10 @@ def _rule_bwd(chunk, sub, impl, residuals, do):
         grads = tuple(_from_chunks(a) for a in grads)
         grads = grads[:4] + (grads[4][..., 0],)
     else:
-        grads = _backward_pallas(do, states, inverses, q, k, v, g, beta,
-                                 chunk, sub, impl == 'pallas:interpret')
+        grads = _backward_pallas(
+            do, states, inverses, q, k, v, g, beta, chunk, sub,
+            _plan(q, v, chunk, sub, impl)['heads_per_step'],
+            impl == 'pallas:interpret')
     return tuple(a.astype(like.dtype) for a, like in
                  zip(grads, (q, k, v, g, beta)))
 
@@ -543,7 +615,7 @@ def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked'):
     if chunk % sub_block:
         raise ValueError('chunk {} is not whole sub-blocks of {}'.format(
             chunk, sub_block))
-    b, t, h, dk = q.shape
+    _, t, _, dk = q.shape
     dv = v.shape[-1]
     if impl == 'pallas':
         if jax.devices()[0].platform != 'tpu':
@@ -554,9 +626,7 @@ def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked'):
         if dk % 128 or dv % 128:
             raise ValueError('the compiled kernels read a head as whole '
                              '128-lane blocks: widths {} and {}'.format(dk, dv))
-    plan = kda_plan(t, h, dk, dv, chunk, sub_block, impl,
-                    jnp.dtype(q.dtype).name)
-    report_plan('kernel.kda_plan', plan)
+    plan = report_plan('kernel.kda_plan', _plan(q, v, chunk, sub_block, impl))
     pad = plan['t_pad'] - t
 
     def padded(a):

@@ -2,7 +2,9 @@
 and token): the chunked ``jax.numpy`` form and the Pallas kernels (in the
 interpreter) against the recurrence token by token, forward and all five
 gradients, with a mild gate and with every token's gate at its bound; the
-plan instant; the kernels compiled for a TPU at the benchmark's widths."""
+kernels with several heads a grid step against one, to the bit; the plan
+instant and the rule that picks the heads a step; the kernels compiled for a
+TPU at the benchmark's widths."""
 
 import functools
 
@@ -104,6 +106,74 @@ def test_pallas_interpreter_runs_the_jax_numpy_bodies_exactly():
                                    atol=1e-7)
 
 
+def _kernels(operands, do, heads):
+    """``o``, the saved states and ``T``, and the five gradients of the two
+    Pallas calls (interpreted, the benchmark's chunks of 64 in sub-blocks of
+    16) with ``heads`` heads a grid step, each call jitted as the rule's
+    are."""
+    o, states, inverses = jax.jit(kd._forward_pallas, static_argnums=(
+        5, 6, 7, 8, 9))(*operands, 64, 16, heads, True, True)
+    return (o, states, inverses) + jax.jit(
+        kd._backward_pallas, static_argnums=(8, 9, 10, 11))(
+            do, states, inverses, *operands, 64, 16, heads, True)
+
+
+# four and six heads, which the rule takes whole, and twelve, which
+# HEADS_PER_STEP (8) does not divide: the rule falls to 6
+@pytest.mark.parametrize('h,gate', [(4, 'mild'), (6, 'bound'), (12, 'mild')])
+@pytest.mark.parametrize('heads', [2, 'rule'])
+def test_several_heads_a_grid_step_change_no_bit(h, gate, heads):
+    """A grid step runs the one-head body batched over its heads, each
+    head's operations today's, so ``o``, what the reverse pass reads and the
+    five gradients are those of one head a step to the bit; ``'rule'``:
+    through :func:`kda_rule` and its ``custom_vjp``, at the heads
+    :func:`kda_plan` chooses. At the cell's widths: with 8- or 16-wide heads
+    or chunks of 16 XLA's CPU products, which the interpreter runs, sum
+    otherwise batched four or more than alone, in a few last places; the
+    chip at the cell's shape does not (PERF.md section 6, PR 38)."""
+    operands = _operands(128, gate, h=h, dk=128, dv=128, dtype=jnp.bfloat16)
+    do = jax.random.normal(jax.random.PRNGKey(1), operands[2].shape,
+                           jnp.bfloat16)
+    want = _kernels(operands, do, 1)
+    if heads == 'rule':
+        plan = kd.kda_plan(128, h, 128, 128, 64, 16, 'pallas:interpret',
+                           'bfloat16')
+        assert plan['heads_per_step'] == {4: 4, 6: 6, 12: 6}[h]
+        o, vjp = jax.vjp(lambda *a: kd.kda_rule(
+            *a, impl='pallas:interpret'), *operands)
+        got = (o,) + vjp(do)
+        want = want[:1] + want[3:]
+    else:
+        got = _kernels(operands, do, heads)
+    assert len(got) == len(want)
+    for a, b in zip(got, want):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                      np.asarray(b).view(np.uint8))
+
+
+def test_heads_a_step_are_a_divisor_under_the_constant_and_the_budget(
+        monkeypatch):
+    monkeypatch.setattr(kd, 'HEADS_PER_STEP', 4)
+
+    def heads(h, width=128, t=8192):
+        return kd.kda_plan(t, h, width, width, 64, 16, 'pallas',
+                           'bfloat16')['heads_per_step']
+
+    assert [heads(h) for h in (1, 2, 3, 4, 6, 7, 8, 32)] == \
+        [1, 2, 3, 4, 3, 1, 4, 4]
+    # the blocks of a step stay under the budget: wider heads, longer rows
+    # take fewer a step, down to one
+    per_step = kd.kda_plan(8192, 32, 128, 128, 64, 16, 'pallas', 'bfloat16')
+    assert per_step['vmem_bytes'] <= kd.STEP_VMEM_BUDGET
+    assert heads(32, width=512) == 2
+    assert heads(32, width=1024) == 1
+    assert heads(32, t=1 << 20) == 1
+    monkeypatch.setattr(kd, 'HEADS_PER_STEP', 8)
+    assert [heads(h) for h in (6, 12, 32)] == [6, 6, 8]
+    assert heads(32, width=256) == 4
+
+
 def test_unknown_impl_sub_blocks_and_compiled_kernels_off_a_tpu_are_refused():
     operands = _operands(16, 'mild')
     with pytest.raises(ValueError, match='unknown impl'):
@@ -140,12 +210,14 @@ def test_kda_plan_instant_once_per_distinct_plan(monkeypatch):
     assert all(r[1] == 'kernel' and r[3] is None for r in plans)   # instants
     assert plans[0][7] == {
         't': 100, 'chunk': 64, 'sub_block': 16, 'chunks_per_row': 2,
-        't_pad': 128, 'heads_held': 2, 'key_width': 128, 'value_width': 128,
+        't_pad': 128, 'heads_held': 2, 'heads_per_step': 2,
+        'key_width': 128, 'value_width': 128,
         'gate_lower_bound': -5.0, 'largest_exponent': 75.0,
         'state_bytes_per_head': 4 * 128 * 128,
         'vmem_bytes': plans[0][7]['vmem_bytes'],
         'impl': 'pallas:interpret', 'dtype': 'bfloat16'}
-    assert 200_000 < plans[0][7]['vmem_bytes'] < 1_000_000
+    # a grid step's: both heads' blocks twice and their states' gradients
+    assert 400_000 < plans[0][7]['vmem_bytes'] < 2_000_000
     assert plans[1][7]['impl'] == 'chunked'
 
 
@@ -163,13 +235,20 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_the_kernels_compile_for_a_v5e_at_the_benchmark_s_widths(one_chip):
+@pytest.mark.parametrize('heads', [1, 'rule'])
+def test_the_kernels_compile_for_a_v5e_at_the_benchmark_s_widths(one_chip,
+                                                                  heads):
     """Mosaic takes what the interpreter cannot refuse: 128-wide keys and
-    values read as a head's lane band of ``[B, T, H 128]``, 64-token chunks
-    in sub-blocks of 16, bfloat16, forward and backward, as
-    ``ling3.tokens8k`` runs them (fewer heads and chunks a row)."""
-    b, t, h, d, c = 1, 256, 4, 128, 64
+    values read as bands of heads of ``[B, T, H 128]``, 64-token chunks in
+    sub-blocks of 16, bfloat16, forward and backward, as ``ling3.tokens8k``
+    runs them (its 32 heads at the heads a step the rule chooses, and one
+    head a step, the body unbatched; fewer chunks a row)."""
+    b, t, h, d, c = 1, 256, 32, 128, 64
     bf, f32 = jnp.bfloat16, jnp.float32
+    if heads == 'rule':
+        heads = kd.kda_plan(8192, h, d, d, c, 16, 'pallas',
+                            'bfloat16')['heads_per_step']
+        assert heads == kd.HEADS_PER_STEP
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
@@ -177,9 +256,10 @@ def test_the_kernels_compile_for_a_v5e_at_the_benchmark_s_widths(one_chip):
     wide, g, beta = (shape((b, t, h, d), bf), shape((b, t, h, d), f32),
                      shape((b, t, h), f32))
     forward = jax.jit(lambda *a: kd._forward_pallas(
-        *a, c, 16, True, False)).lower(wide, wide, wide, g, beta).compile()
+        *a, c, 16, heads, True, False)).lower(wide, wide, wide, g,
+                                              beta).compile()
     backward = jax.jit(lambda *a: kd._backward_pallas(
-        *a, c, 16, False)).lower(
+        *a, c, 16, heads, False)).lower(
             wide, shape((b, h, t // c, d, d), bf),
             shape((b, h, t // c, c, c), bf), wide, wide, wide, g,
             beta).compile()
