@@ -9,6 +9,7 @@ from petastorm_tpu.models.hybrid import HybridLM  # noqa: F401
 from petastorm_tpu.models.latent_moe import LatentMoELM  # noqa: F401
 from petastorm_tpu.models.ling_hybrid import LingHybridLM  # noqa: F401
 from petastorm_tpu.models.mlp import MLP  # noqa: F401
+from petastorm_tpu.models.nemotron_h import NemotronHLM  # noqa: F401
 from petastorm_tpu.models.resnet import ResNet, ResNet18, ResNet50  # noqa: F401
 from petastorm_tpu.models.moe import RoutedMoE, SwitchMoE  # noqa: F401
 from petastorm_tpu.models.pipeline import pipeline_apply  # noqa: F401

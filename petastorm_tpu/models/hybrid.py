@@ -59,11 +59,13 @@ def rms_normalise(x, scale, mean_square=None, eps=EPS):
 
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
+    eps: float = EPS
 
     @nn.compact
     def __call__(self, x, mean_square=None):
         scale = self.param('scale', nn.initializers.ones, (x.shape[-1],))
-        return rms_normalise(x.astype(self.dtype), scale, mean_square)
+        return rms_normalise(x.astype(self.dtype), scale, mean_square,
+                             self.eps)
 
 
 def _projection(x, features, name, dtype):
@@ -71,16 +73,19 @@ def _projection(x, features, name, dtype):
                            name=name)(x)
 
 
-def causal_conv_silu(x, kernel):
-    """Depthwise causal convolution along the sequence, then SiLU:
-    ``x [B, T, ...]``, ``kernel [K, ...]``; position ``t`` sees ``t - K + 1
-    .. t``, zeros before the row's start."""
+def causal_conv_silu(x, kernel, bias=None):
+    """Depthwise causal convolution along the sequence, plus ``bias`` where
+    one is given, then SiLU: ``x [B, T, ...]``, ``kernel [K, ...]``, ``bias
+    [...]``; position ``t`` sees ``t - K + 1 .. t``, zeros before the row's
+    start."""
     taps, t = kernel.shape[0], x.shape[1]
     with jax.named_scope('conv_silu'):
         x32 = x.astype(jnp.float32)
         padded = jnp.pad(x32, ((0, 0), (taps - 1, 0))
                          + ((0, 0),) * (x.ndim - 2))
         y = sum(padded[:, i:i + t] * kernel[i] for i in range(taps))
+        if bias is not None:
+            y = y + bias
         return nn.silu(y).astype(x.dtype)
 
 

@@ -252,6 +252,24 @@ def _swiglu(hidden):
     return nn.silu(hidden[..., :f]) * hidden[..., f:]
 
 
+def _relu2(hidden):
+    return jnp.square(nn.relu(hidden))
+
+
+#: An expert's activation by name, the one place the rows, their backward
+#: pass and the dense form look it up: ``'swiglu'``, ``silu(gate) * up`` over
+#: a ``[.., 2 f]`` hidden (gate columns first); ``'relu2'``, ``relu(h)^2``
+#: over a ``[.., f]`` one, no gate.
+ACTIVATIONS = {'swiglu': _swiglu, 'relu2': _relu2}
+
+
+def expert_activation(name):
+    if name not in ACTIVATIONS:
+        raise ValueError('unknown expert activation {!r}: one of {}'.format(
+            name, sorted(ACTIVATIONS)))
+    return ACTIVATIONS[name]
+
+
 def _row_weight(weights, plan):
     return jnp.where(plan.row_valid, weights.reshape(-1)[plan.row_pair], 0.0)
 
@@ -266,21 +284,23 @@ def _sum_rows(values, weights, plan, n, tile_m, impl):
                       impl)
 
 
-def _rows_forward(x, weights, w_gate_up, w_down, plan, tile_m, impl):
+def _rows_forward(x, weights, w_gate_up, w_down, plan, tile_m, impl,
+                  activation='swiglu'):
     """``(out [N, d], kept)``: every row's expert applied to its token, and a
     token the sum of its rows under their weights, float32 inside. ``kept``
     is what :func:`_rows_backward` reads again."""
     with jax.named_scope('gather'):
         rows = x[plan.row_token]
     hidden = grouped_matmul(rows, w_gate_up, plan.group_sizes, tile_m, impl)
-    y = grouped_matmul(_swiglu(hidden), w_down, plan.group_sizes, tile_m,
-                       impl)
+    y = grouped_matmul(expert_activation(activation)(hidden), w_down,
+                       plan.group_sizes, tile_m, impl)
     out = _sum_rows(y, _row_weight(weights, plan), plan, x.shape[0], tile_m,
                     impl)
     return out, (rows, hidden, y)
 
 
-def _rows_backward(g, weights, w_gate_up, w_down, plan, kept, tile_m, impl):
+def _rows_backward(g, weights, w_gate_up, w_down, plan, kept, tile_m, impl,
+                   activation='swiglu'):
     """The cotangents of ``x, weights, w_gate_up, w_down`` from ``g [N, d]``:
     :func:`_rows_forward` gone back through piece by piece."""
     rows, hidden, y = kept
@@ -292,7 +312,8 @@ def _rows_backward(g, weights, w_gate_up, w_down, plan, kept, tile_m, impl):
         jnp.where(plan.row_valid, plan.row_pair, weights.size)].set(
             jnp.sum(y.astype(jnp.float32) * g_rows, axis=-1),
             mode='drop').reshape(weights.shape)
-    activated, activation_back = jax.vjp(_swiglu, hidden)
+    activated, activation_back = jax.vjp(expert_activation(activation),
+                                         hidden)
     d_activated, d_w_down = grouped_matmul_grads(
         activated, w_down, plan.group_sizes, dy, tile_m, impl)
     d_rows, d_w_gate_up = grouped_matmul_grads(
@@ -303,12 +324,14 @@ def _rows_backward(g, weights, w_gate_up, w_down, plan, kept, tile_m, impl):
     return dx, d_weights.astype(weights.dtype), d_w_gate_up, d_w_down
 
 
-def held_experts_on_every_token(x, experts, weights, w_gate_up, w_down, held):
+def held_experts_on_every_token(x, experts, weights, w_gate_up, w_down, held,
+                                activation='swiglu'):
     """The same ``[N, d]`` with no rows at all: every held expert applied to
     every token, a token's sum taken under the weight of its pair that picked
     the expert and under 0 where none did. Two dense products (``[N, d]`` by
     ``[d, G 2f]``, ``[N, G f]`` by ``[G f, d]``, the second summing a token's
-    pairs in float32 as it goes), which cost what ``N G`` pairs cost however
+    pairs in float32 as it goes; a ``[G f]`` hidden where the activation has no
+    gate), which cost what ``N G`` pairs cost however
     many are held: what :func:`routed_experts` runs where the held pairs pass
     its rows, at most ``experts_published / (4 k)`` times the arithmetic the
     pairs asked for, on nothing but the matrix unit."""
@@ -316,7 +339,8 @@ def held_experts_on_every_token(x, experts, weights, w_gate_up, w_down, held):
         picked = experts[:, :, None] == jnp.asarray(held, jnp.int32)
         weight = jnp.sum(jnp.where(picked, weights[:, :, None], 0.0), axis=1)
         hidden = jnp.einsum('nd,gdf->ngf', x, w_gate_up.astype(x.dtype))
-        weighted = _swiglu(hidden).astype(jnp.float32) * weight[:, :, None]
+        weighted = expert_activation(activation)(hidden).astype(
+            jnp.float32) * weight[:, :, None]
         return jnp.einsum('ngf,gfd->nd', weighted.astype(x.dtype),
                           w_down.astype(x.dtype),
                           preferred_element_type=jnp.float32).astype(x.dtype)
@@ -349,7 +373,7 @@ def _routed(x, experts, weights, w_gate_up, w_down, static):
 
 @functools.partial(jax.jit, static_argnums=(5,), inline=True)
 def _routed_fwd(x, experts, weights, w_gate_up, w_down, static):
-    held, capacity, tile_m, impl, name = static
+    held, capacity, tile_m, impl, name, activation = static
     with jax.named_scope('dispatch'):
         counts, order = sorted_pairs(experts, held)
         plan = layout(counts, order, experts.shape[1], tile_m, capacity)
@@ -357,11 +381,11 @@ def _routed_fwd(x, experts, weights, w_gate_up, w_down, static):
 
     def rows(*operands):
         with jax.named_scope(name):
-            return _rows_forward(*operands, plan, tile_m, impl)
+            return _rows_forward(*operands, plan, tile_m, impl, activation)
 
     def every_token(x, weights, w_gate_up, w_down):
         out = held_experts_on_every_token(x, experts, weights, w_gate_up,
-                                          w_down, held)
+                                          w_down, held, activation)
         return out, tuple(
             _varying_like(jnp.zeros((plan.row_token.shape[0], width), x.dtype),
                           x)
@@ -382,18 +406,18 @@ def _routed_bwd(static, residuals, cotangents):
 
 @functools.partial(jax.jit, static_argnums=(0,), inline=True)
 def _routed_back(static, residuals, g):
-    held, capacity, tile_m, impl, name = static
+    held, capacity, tile_m, impl, name, activation = static
     experts, fits, (x, weights, w_gate_up, w_down), plan, kept = residuals
 
     def rows(g, x, weights, w_gate_up, w_down, kept):
         with jax.named_scope(name):
             return _rows_backward(g, weights, w_gate_up, w_down, plan, kept,
-                                  tile_m, impl)
+                                  tile_m, impl, activation)
 
     def every_token(g, x, weights, w_gate_up, w_down, kept):
         _, back = jax.vjp(
             lambda x, weights, w_gate_up, w_down: held_experts_on_every_token(
-                x, experts, weights, w_gate_up, w_down, held),
+                x, experts, weights, w_gate_up, w_down, held, activation),
             x, weights, w_gate_up, w_down)
         return back(g)
 
@@ -409,20 +433,23 @@ def _routed_back(static, residuals, g):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
-def _routed_static(pairs, held, experts_published, tile_m, impl, name):
+def _routed_static(pairs, held, experts_published, tile_m, impl, name,
+                   activation):
     return (tuple(held), pairs_capacity(pairs, len(held), experts_published,
-                                        tile_m), tile_m, impl, name)
+                                        tile_m), tile_m, impl, name,
+            activation)
 
 
 def routed_experts(x, experts, weights, w_gate_up, w_down, held,
                    experts_published, tile_m=TILE_M, impl='pallas',
-                   name='moe'):
+                   name='moe', activation='swiglu'):
     """The held experts' part of a routed layer. ``x [N, d]``, ``experts,
     weights [N, k]`` (:func:`top_k_routing`), ``w_gate_up [G, d, 2 f]`` (an
     expert's gate columns, then its up columns), ``w_down [G, f, d]``.
     Returns ``([N, d], counts [G])``: ``sum over a token's held pairs of
-    weight * expert(x)``, every expert ``down(silu(gate x) * up x)``, and the
-    pairs each held expert was sent. The rows are laid out for
+    weight * expert(x)``, every expert ``down(silu(gate x) * up x)`` (with
+    ``activation`` ``'relu2'``, ``w_gate_up [G, d, f]`` and ``down(relu(up
+    x)^2)``), and the pairs each held expert was sent. The rows are laid out for
     :func:`pairs_capacity` held pairs (:func:`layout`) and the experts'
     products run over them group by group; a step whose routing sends more
     (``sum(counts)`` says so) applies the held experts to every token instead
@@ -432,7 +459,8 @@ def routed_experts(x, experts, weights, w_gate_up, w_down, held,
     leaves, each direction holding its own ``cond``. ``name`` is the scope a
     device trace names the Pallas calls by: the module's."""
     return _routed(x, experts, weights, w_gate_up, w_down, _routed_static(
-        experts.size, held, experts_published, tile_m, impl, name))[:2]
+        experts.size, held, experts_published, tile_m, impl, name,
+        activation))[:2]
 
 
 class RoutedMoE(nn.Module):
@@ -444,8 +472,15 @@ class RoutedMoE(nn.Module):
     sigmoid(W_r x)`` in float32, the ``top_k`` experts of each token (among
     the best ``topk_group`` of ``n_group`` groups where there are groups:
     :func:`top_k_routing`), their scores normalised over the picked and times
-    ``scale``. ``held`` lists the published experts that live here (a chip
-    of an expert-parallel group);
+    ``scale``. ``activation`` names every expert's (:data:`ACTIVATIONS`):
+    ``'swiglu'``'s leaves are ``experts_gate_up [G, d, 2 f]`` and the shared
+    expert a SwiGLU, ``'relu2'``'s ``experts_up [G, d, f]`` and a
+    :class:`ReluSquaredMLP`. With ``latent`` the routed experts work in a
+    space of that width: ``latent_down`` takes a token there before its rows
+    are gathered, ``latent_up`` brings the sum of its rows back, and the
+    router and the shared expert stay on the hidden state. ``held`` lists
+    the published experts that live here (a chip of an expert-parallel
+    group);
     the layer computes ``shared(x) + sum over a token's picked experts that
     are held of weight * expert(x)`` and nothing for the absent ones: the
     partial result of the chip before the group's exchange, with the shared
@@ -476,6 +511,8 @@ class RoutedMoE(nn.Module):
     normalise: bool = True
     n_group: int = 1                    # groups the selection is limited by
     topk_group: int = 1
+    activation: str = 'swiglu'          # 'swiglu' | 'relu2' (ACTIVATIONS)
+    latent: int = 0                     # the experts' width in and out; 0: d
     impl: str = 'pallas'
     tile_m: int = TILE_M
     mesh: Any = None
@@ -484,10 +521,10 @@ class RoutedMoE(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        from petastorm_tpu.models.hybrid import SwiGLU
+        from petastorm_tpu.models.hybrid import SwiGLU, _projection
         from petastorm_tpu.models.transformer import usable_axis
 
-        b, t, d = x.shape
+        b, t, d_model = x.shape
         g = len(self.held)
         x = x.astype(self.dtype)
         scores = nn.sigmoid(nn.Dense(
@@ -497,9 +534,18 @@ class RoutedMoE(nn.Module):
         experts, weights = top_k_routing(scores, self.top_k, self.scale,
                                          self.normalise, self.n_group,
                                          self.topk_group)
+        # The router reads the hidden state; the routed experts live in the
+        # latent space, entered before the rows are gathered and left after
+        # a token's rows are summed.
+        hidden = x
+        if self.latent:
+            hidden = _projection(x, self.latent, 'latent_down', self.dtype)
+        d = hidden.shape[-1]
         init = nn.initializers.normal(0.02)
-        w_gate_up = self.param('experts_gate_up', init,
-                               (g, d, 2 * self.d_ff)).astype(self.dtype)
+        gated = self.activation == 'swiglu'
+        w_gate_up = self.param('experts_gate_up' if gated else 'experts_up',
+                               init, (g, d, (2 if gated else 1) * self.d_ff)
+                               ).astype(self.dtype)
         w_down = self.param('experts_down', init,
                             (g, self.d_ff, d)).astype(self.dtype)
 
@@ -519,7 +565,8 @@ class RoutedMoE(nn.Module):
                 weights.reshape(rows, self.top_k), w_gate_up, w_down,
                 _routed_static(rows * self.top_k, self.held,
                                self.experts_published, self.tile_m,
-                               self.impl, self.name or 'moe'))
+                               self.impl, self.name or 'moe',
+                               self.activation))
             return y.reshape(x.shape), counts[None], fell_back[None]
 
         if self.mesh is not None and self.impl.startswith('pallas'):
@@ -531,12 +578,29 @@ class RoutedMoE(nn.Module):
                 out_specs=(rows, PartitionSpec(axis, None),
                            PartitionSpec(axis)),
                 check_vma=self.impl == 'pallas')
-        y, counts, fell_back = routed(x, experts, weights, w_gate_up, w_down)
+        y, counts, fell_back = routed(hidden, experts, weights, w_gate_up,
+                                      w_down)
+        if self.latent:
+            y = _projection(y, d_model, 'latent_up', self.dtype)
         if self.shared_d_ff:
-            y = y + SwiGLU(self.shared_d_ff, dtype=self.dtype,
+            shared = SwiGLU if gated else ReluSquaredMLP
+            y = y + shared(self.shared_d_ff, dtype=self.dtype,
                            name='shared')(x)
         return y, {'expert_load': jnp.sum(counts, axis=0),
                    'layout_fallbacks': jnp.sum(fell_back)}
+
+
+class ReluSquaredMLP(nn.Module):
+    """``down(relu(up x)^2)``, no gate: a shared expert of the ``'relu2'``
+    kind."""
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        from petastorm_tpu.models.hybrid import _projection
+        up = _projection(x, self.d_ff, 'up', self.dtype)
+        return _projection(_relu2(up), x.shape[-1], 'down', self.dtype)
 
 
 def total_load(held, loads):

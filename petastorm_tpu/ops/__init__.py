@@ -9,6 +9,7 @@ from petastorm_tpu.ops.flash_attention import flash_attention  # noqa: F401
 from petastorm_tpu.ops.gated_delta import gated_delta_rule  # noqa: F401
 from petastorm_tpu.ops.grouped_matmul import grouped_matmul  # noqa: F401
 from petastorm_tpu.ops.kimi_delta import kda_rule  # noqa: F401
+from petastorm_tpu.ops.ssd import ssd_rule  # noqa: F401
 from petastorm_tpu.ops.image_ops import (normalize_images,  # noqa: F401
                                          normalize_images_reference,
                                          random_flip_and_normalize)

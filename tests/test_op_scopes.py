@@ -452,6 +452,9 @@ def _smaller(name, cfg):
     elif name == 'tiny-ling3':
         cfg.update(num_hidden_layers=3, layer_group_size=3)
         a.update(sequence_length=32, rows_per_chip_per_step=1)
+    elif name == 'tiny-nemotron3':
+        cfg.update(num_hidden_layers=3, hybrid_override_pattern='M*E')
+        a.update(sequence_length=32, rows_per_chip_per_step=1)
     elif name == 'tiny-gpt2':
         a.update(sequence_length=32, rows_per_chip_per_step=2)
     return cfg
@@ -468,6 +471,9 @@ FAMILIES = {
     'LingHybridLM': ('tiny-ling3', {'embed', 'mixer', 'ffn.dense',
                                     'ffn.routed', 'ffn.shared', 'head',
                                     'loss', 'optimizer'}),
+    'NemotronHLM': ('tiny-nemotron3', {'embed', 'mixer', 'ffn.routed',
+                                       'ffn.shared', 'head', 'loss',
+                                       'optimizer'}),
     'ResNet': ('tiny-resnet', {'body', 'norm', 'head', 'loss', 'optimizer'}),
 }
 _family_tables = {}
@@ -524,6 +530,7 @@ def test_a_family_s_step_is_scoped(family):
     for scope in {'TransformerLM': ['attn'], 'HybridLM': ['attn', 'gdn'],
                   'LatentMoELM': ['attn', 'moe', 'token_sums'],
                   'LingHybridLM': ['attn', 'kda', 'moe', 'token_sums'],
+                  'NemotronHLM': ['attn', 'ssd', 'moe', 'token_sums'],
                   'ResNet': []}[family]:
         assert re.search(r'/{}/(pallas_call|[a-z_]+)( |$)'.format(scope),
                          scoped), scope
