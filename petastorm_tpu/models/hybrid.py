@@ -41,6 +41,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
 from petastorm_tpu.models.transformer import self_attention, usable_axis
+from petastorm_tpu.ops.causal_conv import causal_conv_silu
 from petastorm_tpu.trace import get_global_tracer
 
 LAYER_TYPES = ('linear_attention', 'full_attention')
@@ -71,22 +72,6 @@ class RMSNorm(nn.Module):
 def _projection(x, features, name, dtype):
     return nn.DenseGeneral(features, axis=-1, use_bias=False, dtype=dtype,
                            name=name)(x)
-
-
-def causal_conv_silu(x, kernel, bias=None):
-    """Depthwise causal convolution along the sequence, plus ``bias`` where
-    one is given, then SiLU: ``x [B, T, ...]``, ``kernel [K, ...]``, ``bias
-    [...]``; position ``t`` sees ``t - K + 1 .. t``, zeros before the row's
-    start."""
-    taps, t = kernel.shape[0], x.shape[1]
-    with jax.named_scope('conv_silu'):
-        x32 = x.astype(jnp.float32)
-        padded = jnp.pad(x32, ((0, 0), (taps - 1, 0))
-                         + ((0, 0),) * (x.ndim - 2))
-        y = sum(padded[:, i:i + t] * kernel[i] for i in range(taps))
-        if bias is not None:
-            y = y + bias
-        return nn.silu(y).astype(x.dtype)
 
 
 def over_row_shards(rule, mesh, batch_axis, impl, *operands):
@@ -145,7 +130,8 @@ class GatedDeltaMixer(nn.Module):
             kernel = self.param('conv_' + name, nn.initializers.normal(0.02),
                                 (self.conv_kernel, h, width))
             y = _projection(x, (h, width), name + '_proj', self.dtype)
-            return causal_conv_silu(y, kernel)
+            return causal_conv_silu(y, kernel, mesh=self.mesh,
+                                    batch_axis=self.batch_axis)
 
         def unit(a):
             a32 = a.astype(jnp.float32)

@@ -11,8 +11,9 @@ stream), RMSNorm throughout, no bias anywhere. Layer ``i`` of a period of
 
 * **Kimi delta attention** (:class:`KimiDeltaMixer`; arXiv:2510.26692) where
   ``(i + 1) % layer_group_size != 0``: q, k, v pass a causal depthwise
-  convolution and SiLU (:func:`petastorm_tpu.models.hybrid.causal_conv_silu`);
-  q and k are L2-normalised a head; the decay is a vector a head and token,
+  convolution and SiLU
+  (:func:`petastorm_tpu.ops.causal_conv.causal_conv_silu`); q and k are
+  L2-normalised a head; the decay is a vector a head and token,
   ``g = gate_lower_bound * sigmoid(exp(A_log) * (x W_f + dt_bias))`` in
   ``(gate_lower_bound, 0)`` (flash-linear-attention's bounded gate, what the
   rule's sub-blocks are safe for); ``beta = sigmoid(x W_b)``; the rule is
@@ -111,7 +112,8 @@ class KimiDeltaMixer(nn.Module):
         def conv(name, width):
             kernel = self.param('conv_' + name, nn.initializers.normal(0.02),
                                 (self.conv_kernel, h, width))
-            return causal_conv_silu(heads(name, width), kernel)
+            return causal_conv_silu(heads(name, width), kernel,
+                                    mesh=self.mesh, batch_axis=self.batch_axis)
 
         def unit(a):
             a32 = a.astype(jnp.float32)

@@ -11,7 +11,7 @@ which share of each layer it holds.
 * ``M``, **Mamba-2** (:class:`MambaMixer`): ``z``, ``x``, ``B``, ``C`` and
   ``dt`` are column blocks of the in-projection; ``x``, ``B`` and ``C`` pass a
   causal depthwise convolution with a bias and SiLU
-  (:func:`petastorm_tpu.models.hybrid.causal_conv_silu`); ``dt =
+  (:func:`petastorm_tpu.ops.causal_conv.causal_conv_silu`); ``dt =
   softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the state-space rule is
   :func:`petastorm_tpu.ops.ssd.ssd_rule` (``ssm`` picks its implementation:
   ``'pallas'``, ``'pallas:interpret'``, ``'xla'``), heads 64 wide reading
