@@ -623,9 +623,12 @@ LOAD_LAG = 2
 
 class ExpertLoadCounter(object):
     """Running totals of a step's ``expert_load`` on the global tracer's
-    ring, as counters ``moe.expert_load.e<slot>`` (one a held expert), and of
+    ring, as counters ``moe.expert_load.e<slot>`` (one a held expert), of
     its ``layout_fallbacks`` as ``moe.layout_fallbacks`` (expert layers whose
-    held pairs passed their rows): what a reader takes the difference of at a window's
+    held pairs passed their rows), and of a Kimi-delta model's
+    ``decay_below_bound`` as ``kda.decay_below_bound.<j>`` (its ``j``-th
+    Kimi-delta layer's decay entries under the bounded kernels' floor, where
+    the model has them): what a reader takes the difference of at a window's
     two ends. ``add`` is handed every step's ``metrics`` and reads those of
     the step ``LOAD_LAG`` calls back, so it never waits for the device."""
 
@@ -634,15 +637,20 @@ class ExpertLoadCounter(object):
 
     def add(self, metrics):
         self._pending.append((metrics['expert_load'],
-                              metrics.get('layout_fallbacks', 0)))
+                              metrics.get('layout_fallbacks', 0),
+                              metrics.get('decay_below_bound', ())))
         if len(self._pending) <= LOAD_LAG:
             return
         step = np.concatenate([np.asarray(a, np.int64).reshape(-1)
                                for a in self._pending.popleft()])
         self._total = step if self._total is None else self._total + step
         tracer = get_global_tracer()
-        *load, fallbacks = self._total.tolist()
-        for slot, value in enumerate(load):
+        total = self._total.tolist()
+        held = len(metrics['expert_load'])
+        for slot, value in enumerate(total[:held]):
             tracer.counter('moe.expert_load.e{}'.format(slot), value,
                            cat='step')
-        tracer.counter('moe.layout_fallbacks', fallbacks, cat='step')
+        tracer.counter('moe.layout_fallbacks', total[held], cat='step')
+        for layer, value in enumerate(total[held + 1:]):
+            tracer.counter('kda.decay_below_bound.{}'.format(layer), value,
+                           cat='step')

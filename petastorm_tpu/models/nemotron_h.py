@@ -160,11 +160,14 @@ class GroupedQueryAttention(nn.Module):
     KV heads, ``head_dim`` wide, scores times ``head_dim ** -0.5``, no
     rotary positions: each KV head is repeated to its query heads before
     :func:`petastorm_tpu.models.transformer.self_attention` (``flash``: the
-    Pallas kernels), and the gradients of the copies are summed back."""
+    Pallas kernels), and the gradients of the copies are summed back.
+    ``gate``: the heads' output times ``sigmoid(x W_gate)``, one gate a
+    channel, before the out-projection (scope ``gqa_gate``)."""
     heads_held: int
     kv_heads_held: int
     head_dim: int = 128
     attention: str = 'flash'
+    gate: bool = False
     mesh: Any = None
     batch_axis: Optional[str] = 'data'
     dtype: Any = jnp.bfloat16
@@ -185,6 +188,10 @@ class GroupedQueryAttention(nn.Module):
         out = self_attention(q, k, v, attention=self.attention, causal=True,
                              mesh=self.mesh, batch_axis=self.batch_axis,
                              head_axis=None)
+        if self.gate:
+            with jax.named_scope('gqa_gate'):
+                out = out.astype(jnp.float32) * nn.sigmoid(
+                    proj('gate', h).astype(jnp.float32))
         return FlatDenseGeneral(x.shape[-1], contract=2, use_bias=False,
                                 dtype=self.dtype, name='o_proj')(
                                     out.astype(self.dtype))

@@ -39,7 +39,7 @@ PART_RULES = tuple((re.compile(r'(?:^|/)(?:' + pattern + r')(?:/|$)'), part)
     ('loss', 'loss'),
     ('shared', 'ffn.shared'),
     ('moe|router|routing|dispatch|token_sums', 'ffn.routed'),
-    ('mixer|attn|mixer_norm|attn_norm|gdn|kda|ssd', 'mixer'),
+    ('mixer|attn|mixer_norm|attn_norm|gdn|kda|kda_exact|ssd', 'mixer'),
     ('mlp|mlp_norm', 'ffn.dense'),
     (r'\w+_hc|hc|streams', 'streams'),
     (r'embed|pos_embed|Embed_\d+', 'embed'),

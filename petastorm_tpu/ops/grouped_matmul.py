@@ -96,11 +96,28 @@ def _block(n, row_bytes):
     return best
 
 
-def _dw_blocks(k, n, itemsize):
+def _dw_blocks(k, n, itemsize, tile_m=TILE_M):
     """``(block_k, block_n)`` of the weights' gradient: a ``[tile_m, block]``
     block of rows of either operand stays within 512 rows' worth of a block's
-    bytes, and the float32 accumulator is ``block_k x block_n``."""
-    return _block(k, 512 * itemsize), _block(n, 512 * itemsize)
+    bytes, and the float32 accumulator is ``block_k x block_n``. A grid step
+    holds the accumulator, the output block twice and both operands' blocks
+    twice, which has to stay within :data:`_VMEM_LIMIT`: where it would not
+    (``[4096, 2560]``: 83 MiB), the wider block takes the next size down
+    that divides its axis until it does."""
+    blocks = [_block(k, 512 * itemsize), _block(n, 512 * itemsize)]
+
+    def held(bk, bn):
+        return bk * bn * (4 + 2 * itemsize) + 2 * tile_m * (bk + bn) * itemsize
+
+    while held(*blocks) > _VMEM_LIMIT:
+        wide = int(blocks[1] >= blocks[0])
+        axis = (k, n)[wide]
+        smaller = [size for size in range(_LANES, blocks[wide], _LANES)
+                   if axis % size == 0]
+        if not smaller:
+            break
+        blocks[wide] = smaller[-1]
+    return tuple(blocks)
 
 
 def moe_plan(rows, k, n, groups, tile_m, dtype, impl):
@@ -109,7 +126,7 @@ def moe_plan(rows, k, n, groups, tile_m, dtype, impl):
     caller laid out, which holds any ``pairs_capacity = rows - groups *
     tile_m`` pairs (:func:`aligned_layout`)."""
     item = jnp.dtype(dtype).itemsize
-    block_k_dw, block_n_dw = _dw_blocks(k, n, item)
+    block_k_dw, block_n_dw = _dw_blocks(k, n, item, tile_m)
     return {'groups': groups, 'rows_capacity': rows,
             'pairs_capacity': rows - groups * tile_m, 'k': k, 'n': n,
             'tile_m': tile_m, 'tiles': rows // tile_m,
@@ -221,7 +238,7 @@ def _dw(x, dy, group, used, groups, tile_m, interpret):
 
     rows, k = x.shape
     n = dy.shape[1]
-    block_k, block_n = _dw_blocks(k, n, x.dtype.itemsize)
+    block_k, block_n = _dw_blocks(k, n, x.dtype.itemsize, tile_m)
     tiles = rows // tile_m
 
     def rows_of(axis):

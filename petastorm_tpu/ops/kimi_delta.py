@@ -31,6 +31,14 @@ sub-block's first): the rows of sub-block ``a`` take ``k_i exp(G_i - r_a)``
 are not needed and masked before the exponential. No exponent passes ``16 x
 5 = 80`` and none is positive without bound.
 
+**The exact path** (``exact=True``) is for a decay with no lower bound (Kimi
+Linear's ``g = -exp(A_log) softplus(.)``: a sub-block of 16 tokens at ``-11``
+a token spans ``e^176``). The columns of sub-block ``a``'s products stop
+before the sub-block (exponents ``r_a - G_j <= 0``), and the pairs inside it
+are formed one by one, ``sum_c k_ic k_jc exp(G_ic - G_jc)`` with ``i >= j``
+(:func:`_diagonal_pairs`), on the vector unit in float32: every exponent of
+the rule is ``<= 0`` for any ``g <= 0``, nothing is clamped or left out.
+
 One chunk of one head is :func:`_chunk_forward_kda` / :func:`_chunk_backward_kda`
 on 2-D arrays: the chunk-local quantities (:func:`_local`), then the products
 with the state, which are ``ops.gated_delta``'s own (``_chunk_forward``,
@@ -71,6 +79,11 @@ GATE_LOWER_BOUND = -5.0
 #: step ran a head and chunk forward in 2.16, 1.49, 1.14 and 1.07 us; 16 ask
 #: Mosaic for more VMEM than its scoped limit).
 HEADS_PER_STEP = 8
+#: The exact path's cap: its reverse kernel forms a sub-block's pairs one
+#: column after another (:func:`_diagonal_pairs_back`), and at 8 heads a step
+#: Mosaic asks 23 MiB of scoped VMEM for it where a v5e allows 16 (4 take it,
+#: compiled for a described v5e).
+EXACT_HEADS_PER_STEP = 4
 #: VMEM the blocks of a grid step may plan for (:func:`kda_plan`'s
 #: ``vmem_bytes``), half of Mosaic's default scoped limit on a v5e: the body's
 #: own intermediates take the rest.
@@ -107,14 +120,18 @@ def kda_scan(q, k, v, g, beta):
 # the plan: what a call will run, reported once
 # --------------------------------------------------------------------------
 
-def kda_plan(t, heads_held, dk, dv, chunk, sub_block, impl, dtype):
+def kda_plan(t, heads_held, dk, dv, chunk, sub_block, impl, dtype,
+             exact=False):
     """What a call on ``T`` tokens runs: the account ``kernel.kda_plan``
-    carries. ``heads_per_step``: the heads a grid step of the kernels holds,
-    the largest divisor of ``heads_held`` not over :data:`HEADS_PER_STEP`
-    whose blocks fit :data:`STEP_VMEM_BUDGET`, 1 at the least.
-    ``vmem_bytes``: what a grid step of the reverse kernel, the largest,
-    holds at once: its blocks twice (the pipeline's two buffers) and the
-    states' gradients."""
+    carries. ``path``: ``'bounded'`` (the gate at or over
+    :data:`GATE_LOWER_BOUND`, exponents up to ``largest_exponent``) or
+    ``'exact'`` (any gate, no exponent over 0). ``heads_per_step``: the
+    heads a grid step of the kernels holds, the largest divisor of
+    ``heads_held`` not over :data:`HEADS_PER_STEP` (the exact path's
+    :data:`EXACT_HEADS_PER_STEP`) whose blocks fit
+    :data:`STEP_VMEM_BUDGET`, 1 at the least. ``vmem_bytes``: what a grid
+    step of the reverse kernel, the largest, holds at once: its blocks twice
+    (the pipeline's two buffers) and the states' gradients."""
     chunks = -(-t // chunk)
     size = jnp.dtype(dtype).itemsize
     wide = chunk * (2 * dk + dv)
@@ -124,7 +141,8 @@ def kda_plan(t, heads_held, dk, dv, chunk, sub_block, impl, dtype):
               + dk * dv * size + chunk * chunk * size)  # saved state, T
     per_head = 2 * blocks + 4 * dk * dv
     shared = 2 * chunk * heads_held * 4                 # beta, every head
-    heads = max(1, min(HEADS_PER_STEP, heads_held,
+    heads = max(1, min(EXACT_HEADS_PER_STEP if exact else HEADS_PER_STEP,
+                       heads_held,
                        (STEP_VMEM_BUDGET - shared) // per_head))
     while heads_held % heads:
         heads -= 1
@@ -132,17 +150,19 @@ def kda_plan(t, heads_held, dk, dv, chunk, sub_block, impl, dtype):
             'chunks_per_row': chunks, 't_pad': chunks * chunk,
             'heads_held': heads_held, 'heads_per_step': heads,
             'key_width': dk, 'value_width': dv,
-            'gate_lower_bound': GATE_LOWER_BOUND,
-            'largest_exponent': -GATE_LOWER_BOUND * (sub_block - 1),
+            'path': 'exact' if exact else 'bounded',
+            'gate_lower_bound': None if exact else GATE_LOWER_BOUND,
+            'largest_exponent': 0.0 if exact else
+            -GATE_LOWER_BOUND * (sub_block - 1),
             'state_bytes_per_head': 4 * dk * dv,
             'vmem_bytes': heads * per_head + shared,
             'impl': impl, 'dtype': dtype}
 
 
-def _plan(q, v, chunk, sub, impl):
+def _plan(q, v, chunk, sub, impl, exact):
     _, t, h, dk = q.shape
     return kda_plan(t, h, dk, v.shape[-1], chunk, sub, impl,
-                    jnp.dtype(q.dtype).name)
+                    jnp.dtype(q.dtype).name, exact)
 
 
 # --------------------------------------------------------------------------
@@ -184,11 +204,104 @@ def _pick_row(a, index):
                    keepdims=True)
 
 
-def _local(q, k, v, g, beta, sub, inverse=None):
+def _decayed_key(big_g, k, j):
+    """``k_j exp(G_i - G_j)`` for the rows ``i >= j`` of a sub-block's ``G``
+    and ``k [sub, dk]`` (0 before ``j``, masked before the exponential), and
+    the exponentials."""
+    e = jnp.exp(jnp.where(_iota(big_g.shape, 0) >= j,
+                          big_g - _pick_row(big_g, j), -jnp.inf))
+    return _pick_row(k, j) * e, e
+
+
+def _sub_blocks(c, sub):
+    return [slice(a * sub, (a + 1) * sub) for a in range(c // sub)]
+
+
+def _diagonal_pairs(big_g, kf, qf, sub):
+    """The decayed ``k k^T`` and ``q k^T`` inside each sub-block, pair by
+    pair: ``sum_c k_ic k_jc exp(G_ic - G_jc)`` for ``i >= j`` of one
+    sub-block, float32, ``[c, c]`` with 0 outside the diagonal sub-blocks.
+    Every exponent is a difference ``G_i - G_j <= 0``."""
+    c = kf.shape[0]
+    col = _iota((sub, c), 1)
+    kk, qk = [], []
+    for a, rows in enumerate(_sub_blocks(c, sub)):
+        g_a, k_a, q_a = big_g[rows], kf[rows], qf[rows]
+        kk_a = qk_a = jnp.zeros((sub, c), jnp.float32)
+        for j in range(sub):
+            y, _ = _decayed_key(g_a, k_a, j)
+            mine = col == a * sub + j
+            kk_a = jnp.where(mine, jnp.sum(k_a * y, axis=1, keepdims=True),
+                             kk_a)
+            qk_a = jnp.where(mine, jnp.sum(q_a * y, axis=1, keepdims=True),
+                             qk_a)
+        kk.append(kk_a)
+        qk.append(qk_a)
+    return jnp.concatenate(kk, axis=0), jnp.concatenate(qk, axis=0)
+
+
+def _diagonal_pairs_back(big_g, kf, qf, da, db, sub):
+    """:func:`_diagonal_pairs` in reverse: ``da``, ``db [c, c]`` float32, the
+    gradients of its two products -> ``(dk, dq, dG) [c, dk]``."""
+    c = kf.shape[0]
+    col = _iota((sub, c), 1)
+    local = _iota((sub, kf.shape[1]), 0)
+    out = [], [], []
+    for a, rows in enumerate(_sub_blocks(c, sub)):
+        g_a, k_a, q_a, da_a, db_a = (x[rows] for x in (big_g, kf, qf, da, db))
+        dk_a = dq_a = dg_a = jnp.zeros(k_a.shape, jnp.float32)
+        for j in range(sub):
+            y, e = _decayed_key(g_a, k_a, j)
+            mine = col == a * sub + j
+            da_j = jnp.sum(jnp.where(mine, da_a, 0.0), axis=1, keepdims=True)
+            db_j = jnp.sum(jnp.where(mine, db_a, 0.0), axis=1, keepdims=True)
+            dk_a = dk_a + da_j * y
+            dq_a = dq_a + db_j * y
+            dy = da_j * k_a + db_j * q_a            # of y's rows
+            d_exp = dy * y                          # of G_i - G_j
+            at_j = local == j
+            dk_a = dk_a + jnp.where(
+                at_j, jnp.sum(dy * e, axis=0, keepdims=True), 0.0)
+            dg_a = dg_a + d_exp - jnp.where(
+                at_j, jnp.sum(d_exp, axis=0, keepdims=True), 0.0)
+        for kept, x in zip(out, (dk_a, dq_a, dg_a)):
+            kept.append(x)
+    return tuple(jnp.concatenate(x, axis=0) for x in out)
+
+
+def _inverse_by_substitution(low, sub):
+    """``(I + L)^-1`` for strictly lower ``L [c, c]`` by forward
+    substitution, float32 products at full precision: the sub-blocks' own
+    inverses a row of every sub-block at a time (``sub - 1`` products), then
+    a block row at a time from the rows already formed (two products each).
+    No power of ``L`` is formed: the finite Neumann product of
+    ``ops.gated_delta`` forms ``L^32``, which where keys align, the decay is
+    weak and ``beta`` nears 2 reaches 1e20, and its sums cancel in float32
+    to nothing (or to inf - inf)."""
+    c = low.shape[0]
+    row, col = _iota((c, c), 0), _iota((c, c), 1)
+    same = row // sub == col // sub
+    own, across = jnp.where(same, low, 0.0), jnp.where(same, 0.0, low)
+    inv = (row == col).astype(jnp.float32)
+    for s in range(1, sub):
+        rows = row % sub == s
+        inv = jnp.where(rows, inv - _dot32(jnp.where(rows, own, 0.0), inv,
+                                           _NN), inv)
+    own_inverse = inv
+    for a in range(1, c // sub):
+        rows = row // sub == a
+        inv = jnp.where(rows, inv - _dot32(own_inverse, _dot32(
+            jnp.where(rows, across, 0.0), inv, _NN), _NN), inv)
+    return inv
+
+
+def _local(q, k, v, g, beta, sub, inverse=None, exact=False):
     """The chunk's own quantities, from ``q, k [c, dk]``, ``v [c, dv]``, ``g
     [c, dk]`` float32 and ``beta [c, 1]`` float32; everything float32 but
     the operands of the products, which take ``q``'s dtype. ``inverse``: ``T``
-    as a forward pass saved it, where the caller has it."""
+    as a forward pass saved it, where the caller has it. ``exact``: the
+    sub-blocks' products stop before the sub-block and its own pairs are
+    :func:`_diagonal_pairs`."""
     f32 = jnp.float32
     dtype = q.dtype
     c = q.shape[0]
@@ -207,23 +320,30 @@ def _local(q, k, v, g, beta, sub, inverse=None):
     xkb, xqb = xk.astype(dtype), xq.astype(dtype)
     a_kk = jnp.zeros((c, c), f32)
     a_qk = jnp.zeros((c, c), f32)
-    e_out, ys = [], []
+    blocks_run = []
     for a in range(blocks):
         # Columns past the sub-block are not needed; masked before the
-        # exponential, where the difference is positive without bound.
-        e = jnp.exp(jnp.where(token < (a + 1) * sub,
+        # exponential, where the difference is positive without bound. The
+        # exact path stops before the sub-block, where it turns positive.
+        end = a * sub if exact else (a + 1) * sub
+        if not end:
+            continue
+        e = jnp.exp(jnp.where(token < end,
                               _pick_row(big_g, a * sub) - big_g, -jnp.inf))
         y = kf * e
         yb = y.astype(dtype)
         mine = row // sub == a
         a_kk = a_kk + jnp.where(mine, _dot(xkb, yb, _NT), 0.0)
         a_qk = a_qk + jnp.where(mine, _dot(xqb, yb, _NT), 0.0)
-        e_out.append(e)
-        ys.append(y)
+        blocks_run.append((a, e, y))
+    if exact:
+        pairs_kk, pairs_qk = _diagonal_pairs(big_g, kf, qf, sub)
+        a_kk, a_qk = a_kk + pairs_kk, a_qk + pairs_qk
     a_kk = jnp.where(row > col, a_kk, 0.0)
     p = jnp.where(row >= col, a_qk, 0.0)
     if inverse is None:
-        inverse = _inverse_of_unit_lower(beta * a_kk)
+        inverse = (_inverse_by_substitution(beta * a_kk, sub) if exact else
+                   _inverse_of_unit_lower(beta * a_kk))
     decay = jnp.exp(big_g)
     last = _pick_row(big_g, c - 1)
     e_last = jnp.exp(last - big_g)
@@ -232,16 +352,17 @@ def _local(q, k, v, g, beta, sub, inverse=None):
     w = _dot(tb, (beta * kg).astype(dtype), _NN)
     u = _dot(tb, (beta * v.astype(f32)).astype(dtype), _NN)
     return dict(tri=tri, intra=intra, row=row, col=col, token=token,
-                e_in=e_in, xk=xk, xq=xq, e_out=e_out, ys=ys, a_kk=a_kk, p=p,
-                inverse=inverse, decay=decay, e_last=e_last, kg=kg,
-                qg=qf * decay, kd=kf * e_last, ec_row=jnp.exp(last), w=w, u=u)
+                e_in=e_in, xk=xk, xq=xq, blocks_run=blocks_run, a_kk=a_kk,
+                p=p, inverse=inverse, decay=decay, e_last=e_last, kg=kg,
+                qg=qf * decay, kd=kf * e_last, ec_row=jnp.exp(last), w=w, u=u,
+                big_g=big_g, kf=kf, qf=qf)
 
 
-def _chunk_forward_kda(h, q, k, v, g, beta, sub):
+def _chunk_forward_kda(h, q, k, v, g, beta, sub, exact=False):
     """One chunk of one head: the state ``h`` (float32 ``[dk, dv]``) it
     starts from -> ``(h', o, T)``."""
     dtype = q.dtype
-    m = _local(q, k, v, g, beta, sub)
+    m = _local(q, k, v, g, beta, sub, exact=exact)
     h_next, o, _ = _chunk_forward(
         h, m['qg'].astype(dtype), m['p'].astype(dtype),
         m['kd'].astype(dtype), m['w'].astype(dtype), m['u'],
@@ -249,7 +370,8 @@ def _chunk_forward_kda(h, q, k, v, g, beta, sub):
     return h_next, o, m['inverse']
 
 
-def _chunk_backward_kda(grad, do, h, inverse, q, k, v, g, beta, sub):
+def _chunk_backward_kda(grad, do, h, inverse, q, k, v, g, beta, sub,
+                        exact=False):
     """One chunk of one head in reverse: ``grad`` (float32 ``[dk, dv]``), the
     gradient of the state the chunk ends in, ``h`` the state it started from
     and ``inverse`` its ``T`` as the forward pass saved them -> ``(grad', dq,
@@ -257,7 +379,7 @@ def _chunk_backward_kda(grad, do, h, inverse, q, k, v, g, beta, sub):
     f32 = jnp.float32
     dtype = q.dtype
     c = q.shape[0]
-    m = _local(q, k, v, g, beta, sub, inverse=inverse)
+    m = _local(q, k, v, g, beta, sub, inverse=inverse, exact=exact)
     beta = beta.astype(f32)
     row, col, token = m['row'], m['col'], m['token']
     qg, p, kd, w = (m[name].astype(dtype) for name in ('qg', 'p', 'kd', 'w'))
@@ -287,7 +409,7 @@ def _chunk_backward_kda(grad, do, h, inverse, q, k, v, g, beta, sub):
     dk = jnp.zeros(m['xk'].shape, f32)
     d_big = dkg * m['kg'] + dqg * m['qg']
     zero = jnp.zeros((), dtype)
-    for a, (e, y) in enumerate(zip(m['e_out'], m['ys'])):
+    for a, e, y in m['blocks_run']:
         mine = row // sub == a
         da_a, db_a = jnp.where(mine, da, zero), jnp.where(mine, db, zero)
         yb = y.astype(dtype)
@@ -305,6 +427,11 @@ def _chunk_backward_kda(grad, do, h, inverse, q, k, v, g, beta, sub):
         + _row(d_ec) * m['ec_row'], 0.0)
     dq = dxq * m['e_in'] + dqg * m['decay']
     dk = dk + dxk * m['e_in'] + dkg * m['decay'] + dkd * m['e_last']
+    if exact:
+        dk_pairs, dq_pairs, dg_pairs = _diagonal_pairs_back(
+            m['big_g'], m['kf'], m['qf'], beta * d_low,
+            jnp.where(row >= col, dp, 0.0), sub)
+        dk, dq, d_big = dk + dk_pairs, dq + dq_pairs, d_big + dg_pairs
     dg = _dot32(m['intra'], d_in, _TN) + _dot32(m['tri'], d_big, _TN)
     return grad, dq, dk, dv, dg, dbeta
 
@@ -326,10 +453,11 @@ def _over_heads(fn):
     return jax.vmap(jax.vmap(fn))
 
 
-def _forward_jnp(q, k, v, g, beta, sub):
+def _forward_jnp(q, k, v, g, beta, sub, exact):
     b, h, _, _, dk = q.shape
     dv = v.shape[-1]
-    body = _over_heads(functools.partial(_chunk_forward_kda, sub=sub))
+    body = _over_heads(functools.partial(_chunk_forward_kda, sub=sub,
+                                         exact=exact))
 
     def step(state, xs):
         state_next, o, inverse = body(state, *xs)
@@ -342,10 +470,11 @@ def _forward_jnp(q, k, v, g, beta, sub):
     return o.astype(q.dtype), states, inverses
 
 
-def _backward_jnp(do, states, inverses, q, k, v, g, beta, sub):
+def _backward_jnp(do, states, inverses, q, k, v, g, beta, sub, exact):
     b, h, _, _, dk = q.shape
     dv = v.shape[-1]
-    body = _over_heads(functools.partial(_chunk_backward_kda, sub=sub))
+    body = _over_heads(functools.partial(_chunk_backward_kda, sub=sub,
+                                         exact=exact))
 
     def step(grad, xs):
         out = body(grad, *xs)
@@ -402,19 +531,19 @@ def _put(ref, x):
         ref[:, a * width:(a + 1) * width] = x[a].astype(ref.dtype)
 
 
-def _step_body(body, sub, heads):
+def _step_body(body, sub, exact, heads):
     """The one-head ``body`` over ``[s, ...]`` operands: ``jax.vmap``, so
     that every product and vector operation takes the step's heads at once.
     A lone head runs the body as it stands: batched over one it is slower
     than today's kernel (PERF.md section 6, PR 38)."""
-    body = functools.partial(body, sub=sub)
+    body = functools.partial(body, sub=sub, exact=exact)
     if heads > 1:
         return jax.vmap(body)
     return lambda *a: tuple(x[None] for x in body(*(x[0] for x in a)))
 
 
-def _forward_kernel(sub, save, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
-                    *rest):
+def _forward_kernel(sub, exact, save, q_ref, k_ref, v_ref, g_ref, beta_ref,
+                    o_ref, *rest):
     import jax.experimental.pallas as pl
     state_ref = rest[-1]
     heads = state_ref.shape[0]
@@ -424,7 +553,8 @@ def _forward_kernel(sub, save, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     h = state_ref[...]
-    state_ref[...], o, inverse = _step_body(_chunk_forward_kda, sub, heads)(
+    state_ref[...], o, inverse = _step_body(
+        _chunk_forward_kda, sub, exact, heads)(
         h, *(_heads(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
         _beta_columns(beta_ref, heads))
     _put(o_ref, o)
@@ -434,9 +564,9 @@ def _forward_kernel(sub, save, q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref,
         t_ref[...] = inverse.astype(t_ref.dtype)
 
 
-def _backward_kernel(sub, do_ref, h_ref, t_ref, q_ref, k_ref, v_ref, g_ref,
-                     beta_ref, dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref,
-                     grad_ref):
+def _backward_kernel(sub, exact, do_ref, h_ref, t_ref, q_ref, k_ref, v_ref,
+                     g_ref, beta_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                     dbeta_ref, grad_ref):
     import jax.experimental.pallas as pl
     i = pl.program_id(2)
     heads = grad_ref.shape[0]
@@ -446,7 +576,7 @@ def _backward_kernel(sub, do_ref, h_ref, t_ref, q_ref, k_ref, v_ref, g_ref,
         grad_ref[...] = jnp.zeros_like(grad_ref)
 
     grad_ref[...], dq, dk, dv, dg, dbeta = _step_body(
-        _chunk_backward_kda, sub, heads)(
+        _chunk_backward_kda, sub, exact, heads)(
             grad_ref[...], _heads(do_ref, heads), h_ref[...], t_ref[...],
             *(_heads(ref, heads) for ref in (q_ref, k_ref, v_ref, g_ref)),
             _beta_columns(beta_ref, heads))
@@ -496,7 +626,8 @@ def _call(kernel, chunk_of, operands, h, heads, chunk, outs, scratch,
         **_mosaic_params(interpret, independent_axes=2))(*operands.values())
 
 
-def _forward_pallas(q, k, v, g, beta, chunk, sub, heads, save, interpret):
+def _forward_pallas(q, k, v, g, beta, chunk, sub, heads, save, interpret,
+                    exact=False):
     from jax.experimental.pallas import tpu as pltpu
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -507,14 +638,15 @@ def _forward_pallas(q, k, v, g, beta, chunk, sub, heads, save, interpret):
     if save:
         outs['h'] = _out_struct((b, h, n, dk, dv), q.dtype, q)
         outs['inverse'] = _out_struct((b, h, n, chunk, chunk), q.dtype, q)
-    out = _call(functools.partial(_forward_kernel, sub, save), lambda i: i,
+    out = _call(functools.partial(_forward_kernel, sub, exact, save),
+                lambda i: i,
                 operands, h, heads, chunk, outs,
                 [pltpu.VMEM((heads, dk, dv), jnp.float32)], interpret)
     return (out[0].reshape(b, t, h, dv),) + tuple(out[1:])
 
 
 def _backward_pallas(do, states, inverses, q, k, v, g, beta, chunk, sub,
-                     heads, interpret):
+                     heads, interpret, exact=False):
     from jax.experimental.pallas import tpu as pltpu
     b, t, h, dk = q.shape
     dv = v.shape[-1]
@@ -529,7 +661,7 @@ def _backward_pallas(do, states, inverses, q, k, v, g, beta, chunk, sub,
             'dg': _out_struct((b, t, h * dk), f32, q),
             'dbeta': _out_struct((b, h, n, chunk), f32, q)}
     dq, dk_, dv_, dg, dbeta = _call(
-        functools.partial(_backward_kernel, sub), lambda i: n - 1 - i,
+        functools.partial(_backward_kernel, sub, exact), lambda i: n - 1 - i,
         operands, h, heads, chunk, outs,
         [pltpu.VMEM((heads, dk, dv), f32)], interpret)
     wide = (b, t, h, dk)
@@ -545,48 +677,50 @@ def _backward_pallas(do, states, inverses, q, k, v, g, beta, chunk, sub,
 # ``_once_a_shape`` (flash_attention): one trace a shape, not one a layer, and
 # the kernels keep the name of the scope they were called in.
 
-@functools.partial(_once_a_shape, static_argnums=(5, 6, 7, 8))
-def _forward(q, k, v, g, beta, chunk, sub, impl, save):
+@functools.partial(_once_a_shape, static_argnums=(5, 6, 7, 8, 9))
+def _forward(q, k, v, g, beta, chunk, sub, impl, exact, save):
     """``[B, T_pad, H, .]`` operands -> ``(o, states, inverses)``, the last
     two ``None`` where ``save`` is false and the kernels run."""
     if impl == 'chunked':
         n = q.shape[1] // chunk
         o, states, inverses = _forward_jnp(
             *(_to_chunks(a, n, chunk) for a in (q, k, v, g, beta[..., None])),
-            sub)
+            sub, exact)
         return _from_chunks(o), states, inverses
-    out = _forward_pallas(q, k, v, g, beta, chunk, sub,
-                          _plan(q, v, chunk, sub, impl)['heads_per_step'],
-                          save, impl == 'pallas:interpret')
+    out = _forward_pallas(
+        q, k, v, g, beta, chunk, sub,
+        _plan(q, v, chunk, sub, impl, exact)['heads_per_step'], save,
+        impl == 'pallas:interpret', exact)
     return out if save else out + (None, None)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _rule(q, k, v, g, beta, chunk, sub, impl):
-    return _forward(q, k, v, g, beta, chunk, sub, impl, False)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _rule(q, k, v, g, beta, chunk, sub, impl, exact):
+    return _forward(q, k, v, g, beta, chunk, sub, impl, exact, False)[0]
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, sub, impl):
-    o, states, inverses = _forward(q, k, v, g, beta, chunk, sub, impl, True)
+def _rule_fwd(q, k, v, g, beta, chunk, sub, impl, exact):
+    o, states, inverses = _forward(q, k, v, g, beta, chunk, sub, impl, exact,
+                                   True)
     return o, (states, inverses, q, k, v, g, beta)
 
 
-@functools.partial(_once_a_shape, static_argnums=(0, 1, 2))
-def _rule_bwd(chunk, sub, impl, residuals, do):
+@functools.partial(_once_a_shape, static_argnums=(0, 1, 2, 3))
+def _rule_bwd(chunk, sub, impl, exact, residuals, do):
     states, inverses, q, k, v, g, beta = residuals
     if impl == 'chunked':
         n = q.shape[1] // chunk
         grads = _backward_jnp(
             _to_chunks(do, n, chunk), states, inverses,
             *(_to_chunks(a, n, chunk) for a in (q, k, v, g, beta[..., None])),
-            sub)
+            sub, exact)
         grads = tuple(_from_chunks(a) for a in grads)
         grads = grads[:4] + (grads[4][..., 0],)
     else:
         grads = _backward_pallas(
             do, states, inverses, q, k, v, g, beta, chunk, sub,
-            _plan(q, v, chunk, sub, impl)['heads_per_step'],
-            impl == 'pallas:interpret')
+            _plan(q, v, chunk, sub, impl, exact)['heads_per_step'],
+            impl == 'pallas:interpret', exact)
     return tuple(a.astype(like.dtype) for a, like in
                  zip(grads, (q, k, v, g, beta)))
 
@@ -598,12 +732,14 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 # the public function
 # --------------------------------------------------------------------------
 
-def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked'):
+def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked',
+             exact=False):
     """``q, k [B, T, H, dk]`` (normalised and scaled by the caller), ``v [B,
     T, H, dv]``, ``g [B, T, H, dk]`` float32 (log of the decay a key channel,
     in ``[GATE_LOWER_BOUND, 0]``: the sub-blocks keep every exponent under
-    ``-GATE_LOWER_BOUND * sub_block`` only for such a gate) and ``beta [B, T,
-    H]`` -> ``o [B, T, H, dv]`` in ``q``'s dtype.
+    ``-GATE_LOWER_BOUND * sub_block`` only for such a gate; with ``exact``
+    any ``g <= 0``, the sub-blocks' own pairs formed one by one) and ``beta
+    [B, T, H]`` -> ``o [B, T, H, dv]`` in ``q``'s dtype.
 
     ``impl`` as :func:`petastorm_tpu.ops.gated_delta.gated_delta_rule`'s;
     the compiled kernels read a head as a band of whole 128-lane blocks, so
@@ -626,7 +762,8 @@ def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked'):
         if dk % 128 or dv % 128:
             raise ValueError('the compiled kernels read a head as whole '
                              '128-lane blocks: widths {} and {}'.format(dk, dv))
-    plan = report_plan('kernel.kda_plan', _plan(q, v, chunk, sub_block, impl))
+    plan = report_plan('kernel.kda_plan',
+                       _plan(q, v, chunk, sub_block, impl, exact))
     pad = plan['t_pad'] - t
 
     def padded(a):
@@ -634,5 +771,5 @@ def kda_rule(q, k, v, g, beta, chunk=64, sub_block=16, impl='chunked'):
 
     o = _rule(padded(q), padded(k), padded(v.astype(q.dtype)),
               padded(g.astype(jnp.float32)), padded(beta.astype(jnp.float32)),
-              chunk, sub_block, impl)
+              chunk, sub_block, impl, exact)
     return o[:, :t]

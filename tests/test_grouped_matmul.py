@@ -227,6 +227,54 @@ def test_the_kernels_compile_for_a_v5e_at_the_cell_s_widths(v5e, k, n):
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
 
 
+def test_the_weights_gradient_blocks_fit_the_vmem_they_may_use():
+    """A grid step of ``x^T dy`` holds the float32 accumulator, the output
+    block twice and both operands' blocks twice: at ``solar2.tokens8k``'s
+    ``[4096, 2560]`` the blocks of the rule before (the whole axes, 83 MiB)
+    passed Mosaic's 64 MiB and the wider block takes the next size down;
+    the accepted cells' shapes keep the blocks they had."""
+    def held(k, n, item=2):
+        bk, bn = gm._dw_blocks(k, n, item)
+        return bk * bn * (4 + 2 * item) + 2 * 128 * (bk + bn) * item
+
+    for (k, n), blocks in {(3584, 2048): (3584, 2048),      # xing4
+                           (1024, 3584): (1024, 3584),
+                           (2560, 1536): (2560, 1536),      # ling3
+                           (768, 2560): (768, 2560),
+                           (1024, 2688): (1024, 2688),      # nemotron3
+                           (2688, 1024): (2688, 1024),
+                           (4096, 2560): (2048, 2560),      # solar2
+                           (1280, 4096): (1280, 4096)}.items():
+        assert gm._dw_blocks(k, n, 2) == blocks, (k, n)
+        assert held(k, n) <= gm._VMEM_LIMIT, (k, n)
+    plan = gm.moe_plan(7680, 4096, 2560, 8, 128, jnp.bfloat16, 'pallas')
+    assert (plan['block_k_dw'], plan['block_n_dw']) == (2048, 2560)
+
+
+@pytest.mark.parametrize('k,n', [(4096, 2560), (1280, 4096)])
+def test_solar_s_products_compile_for_a_v5e(v5e, k, n):
+    """``solar2.tokens8k``'s two products, bf16, 60 tiles of 128 rows (four
+    times the held share of 8 of 320 experts' pairs) against eight experts,
+    forward and both gradients: Mosaic takes the weights' gradient's blocks
+    within its 64 MiB."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(v5e[0])
+
+    def struct(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def grads(x, w, sizes, c):
+        return jax.value_and_grad(lambda x, w: jnp.sum(gm._grouped(
+            x, w, sizes, 128, False).astype(jnp.float32) * c),
+            argnums=(0, 1))(x, w)
+
+    rows = 7680
+    compiled = jax.jit(grads).lower(
+        struct((rows, k), jnp.bfloat16), struct((8, k, n), jnp.bfloat16),
+        struct((8,), jnp.int32), struct((rows, n), jnp.float32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
 @pytest.mark.parametrize('n,rows,d', [(8192, 5120, 2560), (4096, 9216, 3584),
                                       (8192, 66560, 2560)])
 def test_the_token_sums_compile_for_a_v5e_at_the_cells_shapes(v5e, n, rows, d):

@@ -1,10 +1,11 @@
 """Kimi delta attention's rule (a delta rule whose decay is a vector a head
 and token): the chunked ``jax.numpy`` form and the Pallas kernels (in the
 interpreter) against the recurrence token by token, forward and all five
-gradients, with a mild gate and with every token's gate at its bound; the
+gradients, with a mild gate and with every token's gate at its bound, and on
+the exact path with a decay of no bound and write strengths in (0, 2); the
 kernels with several heads a grid step against one, to the bit; the plan
-instant and the rule that picks the heads a step; the kernels compiled for a
-TPU at the benchmark's widths."""
+instant and the rule that picks the heads a step; the kernels of both paths
+compiled for a TPU at the benchmarks' widths."""
 
 import functools
 
@@ -74,6 +75,109 @@ def test_chunked_forms_agree_with_the_recurrence(impl, gate, t, chunk, sub):
         np.testing.assert_allclose(
             got, want, err_msg=name,
             atol=4e-5 * (scale if name == 'g' else own))
+
+
+def _unbounded(t, b=1, h=2, dk=8, dv=16, seed=0):
+    """Operands of the exact path: a decay from 0 down to -40 a token and
+    channel (most near 0, a tail far past the bounded path's floor: a
+    sub-block of 16 spans up to e^640) and write strengths in (0, 2), many
+    near 2, float32."""
+    q, k, v, _, _ = _operands(t, 'mild', b=b, h=h, dk=dk, dv=dv, seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 2)
+    g = -40.0 * jax.random.uniform(ks[0], (b, t, h, dk)) ** 3
+    beta = 2 * jax.nn.sigmoid(3 * jax.random.normal(ks[1], (b, t, h)))
+    return q, k, v, g, beta
+
+
+@functools.lru_cache(maxsize=None)
+def _unbounded_reference(t):
+    operands = _unbounded(t)
+    return (operands,) + _value_and_grads('scan', None, None, operands)
+
+
+def _exact_value_and_grads(impl, chunk, sub, operands, exact=True):
+    def f(*a):
+        o = kd.kda_rule(*a, chunk=chunk, sub_block=sub, impl=impl,
+                        exact=exact)
+        return jnp.sum(jnp.sin(o) * o), o
+    (_, o), grads = jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
+                                       has_aux=True)(*operands)
+    return o, grads
+
+
+@pytest.mark.parametrize('impl', ['chunked', 'pallas:interpret'])
+@pytest.mark.parametrize('t,chunk,sub', [(48, 16, 4), (75, 16, 8),
+                                         (70, 64, 16)])
+def test_the_exact_path_agrees_with_the_recurrence_for_any_decay(impl, t,
+                                                                   chunk, sub):
+    """``g`` down to -40 and ``beta`` up to 2, float32: ``o`` and the five
+    gradients within 1e-4 of each array's largest value (read: 1.4e-5 for
+    ``o``, up to 3.4e-5 for the gate's gradient at chunks of 64: with
+    ``beta`` near 2 the unit-lower inverse grows, and what is left is the
+    order of a chunk's float32 sums against a token's; bfloat16 products
+    would read 1e-3 and more). The bounded path on the same operands leaves
+    float32 (its sub-block columns ``exp(r_a - G_j)`` overflow)."""
+    operands, o_ref, g_ref = _unbounded_reference(t)
+    o, grads = _exact_value_and_grads(impl, chunk, sub, operands)
+    for name, got, want in zip('o q k v g beta'.split(), (o,) + grads,
+                               (o_ref,) + g_ref):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(
+            got, want, rtol=0, err_msg=name,
+            atol=1e-4 * float(jnp.max(jnp.abs(want))))
+    if impl == 'chunked':
+        bounded, _ = _exact_value_and_grads(impl, chunk, sub, operands,
+                                            exact=False)
+        assert not np.isfinite(np.asarray(bounded)).all()
+
+
+def _aligned(t, b=1, h=2, dk=128, dv=16, seed=0):
+    """Keys that align (a shared direction and noise of a third of its size:
+    cosines near 0.9), a decay that all but keeps the state (``g`` in
+    (-1e-3, 0)) and ``beta`` at 1.99: where a chunk's unit-lower system is
+    hardest, float32."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = jax.random.normal(ks[0], (1, 1, h, dk)) \
+        + 0.3 * jax.random.normal(ks[1], (b, t, h, dk))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    g = -1e-3 * jax.random.uniform(ks[3], (b, t, h, dk))
+    return k / np.sqrt(dk), k, v, g, jnp.full((b, t, h), 1.99)
+
+
+@pytest.mark.parametrize('impl', ['chunked', 'pallas:interpret'])
+def test_the_exact_path_solves_the_chunk_where_keys_align(impl):
+    """``T = (I + L)^-1`` by substitution: ``o`` and the five gradients
+    within 1e-4 of each array's largest value of the recurrence's (read: 1e-5
+    and under). The finite Neumann product (the bounded path's) forms powers
+    of ``L`` near 1e20 here and loses every digit: shown on the inverse
+    alone."""
+    operands = _aligned(128)
+    o_ref, g_ref = _value_and_grads('scan', None, None, operands)
+    o, grads = _exact_value_and_grads(impl, 64, 16, operands)
+    for name, got, want in zip('o q k v g beta'.split(), (o,) + grads,
+                               (o_ref,) + g_ref):
+        assert np.isfinite(np.asarray(got)).all(), name
+        np.testing.assert_allclose(
+            got, want, rtol=0, err_msg=name,
+            atol=1e-4 * float(jnp.max(jnp.abs(want))))
+    low = 1.99 * 0.9 * jnp.tril(jnp.ones((64, 64)), -1)
+    eye = jnp.eye(64)
+    exact = kd._inverse_by_substitution(low, 16)
+    assert float(jnp.max(jnp.abs(exact @ (eye + low) - eye))) < 1e-4
+    neumann = gd._inverse_of_unit_lower(low)
+    assert not float(jnp.max(jnp.abs(neumann @ (eye + low) - eye))) < 1.0
+
+
+def test_the_exact_path_s_plan_says_so_and_holds_fewer_heads_a_step():
+    plan = kd.kda_plan(8192, 16, 128, 128, 64, 16, 'pallas', 'bfloat16',
+                       exact=True)
+    assert (plan['path'], plan['gate_lower_bound'],
+            plan['largest_exponent']) == ('exact', None, 0.0)
+    assert plan['heads_per_step'] == kd.EXACT_HEADS_PER_STEP == 4
+    bounded = kd.kda_plan(8192, 16, 128, 128, 64, 16, 'pallas', 'bfloat16')
+    assert (bounded['path'], bounded['largest_exponent'],
+            bounded['heads_per_step']) == ('bounded', 75.0, 8)
 
 
 def test_a_whole_chunk_formulation_overflows_where_the_sub_blocks_do_not():
@@ -211,7 +315,7 @@ def test_kda_plan_instant_once_per_distinct_plan(monkeypatch):
     assert plans[0][7] == {
         't': 100, 'chunk': 64, 'sub_block': 16, 'chunks_per_row': 2,
         't_pad': 128, 'heads_held': 2, 'heads_per_step': 2,
-        'key_width': 128, 'value_width': 128,
+        'key_width': 128, 'value_width': 128, 'path': 'bounded',
         'gate_lower_bound': -5.0, 'largest_exponent': 75.0,
         'state_bytes_per_head': 4 * 128 * 128,
         'vmem_bytes': plans[0][7]['vmem_bytes'],
@@ -260,6 +364,34 @@ def test_the_kernels_compile_for_a_v5e_at_the_benchmark_s_widths(one_chip,
                                               beta).compile()
     backward = jax.jit(lambda *a: kd._backward_pallas(
         *a, c, 16, heads, False)).lower(
+            wide, shape((b, h, t // c, d, d), bf),
+            shape((b, h, t // c, c, c), bf), wide, wide, wide, g,
+            beta).compile()
+    assert 'tpu_custom_call' in forward.as_text()
+    assert 'tpu_custom_call' in backward.as_text()
+
+
+def test_the_exact_kernels_compile_for_a_v5e_at_the_benchmark_s_widths(
+        one_chip):
+    """The exact path as ``solar2.tokens8k`` runs it: 16 heads of 128 at the
+    heads a step its plan chooses (4: at 8 the reverse kernel's pairs ask
+    Mosaic for more scoped VMEM than a v5e allows), chunks of 64 in
+    sub-blocks of 16, bfloat16, forward and backward; fewer chunks a row."""
+    b, t, h, d, c = 1, 256, 16, 128, 64
+    bf, f32 = jnp.bfloat16, jnp.float32
+    heads = kd.kda_plan(8192, h, d, d, c, 16, 'pallas', 'bfloat16',
+                        exact=True)['heads_per_step']
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    wide, g, beta = (shape((b, t, h, d), bf), shape((b, t, h, d), f32),
+                     shape((b, t, h), f32))
+    forward = jax.jit(lambda *a: kd._forward_pallas(
+        *a, c, 16, heads, True, False, True)).lower(
+            wide, wide, wide, g, beta).compile()
+    backward = jax.jit(lambda *a: kd._backward_pallas(
+        *a, c, 16, heads, False, True)).lower(
             wide, shape((b, h, t // c, d, d), bf),
             shape((b, h, t // c, c, c), bf), wide, wide, wide, g,
             beta).compile()

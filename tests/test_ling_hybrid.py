@@ -3,9 +3,11 @@ reference of the Ling-3.0-flash configuration (loss, every leaf's gradient,
 one AdamW update through ``make_train_step``), the shares of the heads of both
 kinds of mixer, the layer plan, and a vocabulary slice."""
 
+import hashlib
 import importlib.util
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -262,3 +264,39 @@ def test_the_program_refuses_what_it_does_not_build(cfg, program):
         ling_hybrid.LingHybridBlock('full', False, {}, {}, {'held': (0,)},
                                     8).init(jax.random.PRNGKey(0),
                                             jnp.zeros((1, 4, 8)))
+
+
+#: sha256 of the printed jaxpr of the small configuration's loss and
+#: gradient (one row of 40 tokens, bfloat16, the weights of seed 7; object
+#: addresses left out) as the commit before the rule's exact path traced it:
+#: with the kernels interpreted, and with the rule ``chunked``, attention
+#: dense and the experts by ``ragged_dot``.
+BEFORE_THE_EXACT_PATH = {
+    'pallas:interpret':
+        '7684aff1cebd53545879276fdc45ee1cb21316ca3272043a0271f1759ee0e2cf',
+    'chunked':
+        '22c8f2e40ee97e1f0d531006b44e5a72ea1d394ecd0c17ecc3c3ae635c2a1a61'}
+
+
+@pytest.mark.parametrize('impl', sorted(BEFORE_THE_EXACT_PATH))
+def test_the_bounded_path_traces_the_program_it_traced_before(cfg, ref,
+                                                              program, impl):
+    """The bounded decay is chosen statically: the Ling model traces, to the
+    equation, the program it traced before the rule gained its exact path
+    (and the model its options), so it computes the same bits on any
+    backend, the kernels and their backward pass included."""
+    model = program.model_for(cfg, None, interpret=True)
+    if impl == 'chunked':
+        model = model.clone(attention='dense', linear_attention='chunked',
+                            experts='ragged_dot')
+    params = ref.init_params(cfg, 7)
+    tokens = jnp.zeros((1, 41), jnp.int32)
+
+    def loss(p):
+        out = model.apply({'params': p}, tokens[:, :-1])
+        return summed_loss(out['logits'], tokens[:, 1:])[0]
+
+    text = re.sub(r' at 0x[0-9a-f]+', '',
+                  str(jax.make_jaxpr(jax.value_and_grad(loss))(params)))
+    assert hashlib.sha256(text.encode()).hexdigest() == \
+        BEFORE_THE_EXACT_PATH[impl]

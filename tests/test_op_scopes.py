@@ -455,6 +455,9 @@ def _smaller(name, cfg):
     elif name == 'tiny-nemotron3':
         cfg.update(num_hidden_layers=3, hybrid_override_pattern='M*E')
         a.update(sequence_length=32, rows_per_chip_per_step=1)
+    elif name == 'tiny-solar2':
+        cfg.update(num_hidden_layers=2, gqa_layers=[0])
+        a.update(sequence_length=32, rows_per_chip_per_step=1)
     elif name == 'tiny-gpt2':
         a.update(sequence_length=32, rows_per_chip_per_step=2)
     return cfg
@@ -475,6 +478,11 @@ FAMILIES = {
                                        'ffn.shared', 'head', 'loss',
                                        'optimizer'}),
     'ResNet': ('tiny-resnet', {'body', 'norm', 'head', 'loss', 'optimizer'}),
+    # LingHybridLM laid out as Solar-Open2: gated grouped-query attention and
+    # Kimi delta attention on the rule's exact path
+    'SolarHybrid': ('tiny-solar2', {'embed', 'mixer', 'ffn.routed',
+                                    'ffn.shared', 'head', 'loss',
+                                    'optimizer'}),
 }
 _family_tables = {}
 
@@ -531,6 +539,7 @@ def test_a_family_s_step_is_scoped(family):
                   'LatentMoELM': ['attn', 'moe', 'token_sums'],
                   'LingHybridLM': ['attn', 'kda', 'moe', 'token_sums'],
                   'NemotronHLM': ['attn', 'ssd', 'moe', 'token_sums'],
+                  'SolarHybrid': ['attn', 'kda_exact', 'moe', 'token_sums'],
                   'ResNet': []}[family]:
         assert re.search(r'/{}/(pallas_call|[a-z_]+)( |$)'.format(scope),
                          scoped), scope
@@ -552,3 +561,17 @@ def test_every_name_the_rules_know_is_met():
             if not any(re.fullmatch(alternative, name) for name in names):
                 missing.append((part, alternative))
     assert missing == [('streams', 'hc')]
+
+
+def test_what_solar_s_layers_add_lands_in_named_parts():
+    """The exact rule's kernels, the low-rank decay and output gates, the
+    attention's output gate and the routing over the published experts each
+    land in a part of the model's own, not in ``unscoped`` or ``other``."""
+    rows = family_table('SolarHybrid').values()
+    for scope, part in (('kda_exact', 'mixer'), ('f_a_proj', 'mixer'),
+                        ('f_b_proj', 'mixer'), ('g_a_proj', 'mixer'),
+                        ('g_b_proj', 'mixer'), ('gqa_gate', 'mixer'),
+                        ('router', 'ffn.routed'), ('routing', 'ffn.routed')):
+        mine = [r for r in rows if scope in r['path'].split('/')]
+        assert mine, scope
+        assert {r['part'] for r in mine} == {part}, (scope, mine[:3])
