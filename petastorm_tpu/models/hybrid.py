@@ -33,7 +33,7 @@ loss are over that slice.
 ``model.layer_plan`` instant on the global tracer says what a process built.
 """
 
-from typing import Any, Optional, Sequence
+from typing import Any, Optional, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -42,6 +42,7 @@ from jax.sharding import PartitionSpec
 
 from petastorm_tpu.models.transformer import self_attention, usable_axis
 from petastorm_tpu.ops.causal_conv import causal_conv_silu
+from petastorm_tpu.ops.swiglu import swiglu
 from petastorm_tpu.trace import get_global_tracer
 
 LAYER_TYPES = ('linear_attention', 'full_attention')
@@ -195,15 +196,36 @@ class FullAttentionMixer(nn.Module):
                                    out.astype(self.dtype))
 
 
+class _Kernel(nn.Module):
+    """A bias-free dense layer's kernel alone, under the path, shape and
+    initialiser ``nn.DenseGeneral`` gives it, for an op that does the
+    product itself."""
+    shape: Tuple[int, ...]
+
+    @nn.compact
+    def __call__(self):
+        return self.param('kernel', nn.initializers.lecun_normal(),
+                          self.shape)
+
+
 class SwiGLU(nn.Module):
+    """``down(silu(gate x) · up x)`` through :func:`petastorm_tpu.ops.swiglu.
+    swiglu`; the kernels are ``gate``, ``up`` and ``down`` as three dense
+    layers would hold them."""
     d_ff: int
     dtype: Any = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
-        gate = _projection(x, self.d_ff, 'gate', self.dtype)
-        up = _projection(x, self.d_ff, 'up', self.dtype)
-        return _projection(nn.silu(gate) * up, x.shape[-1], 'down', self.dtype)
+        d_model = x.shape[-1]
+
+        def kernel(name, shape):
+            return _Kernel(shape, name=name)().astype(self.dtype)
+
+        return swiglu(x.astype(self.dtype),
+                      kernel('gate', (d_model, self.d_ff)),
+                      kernel('up', (d_model, self.d_ff)),
+                      kernel('down', (self.d_ff, d_model)))
 
 
 class HybridBlock(nn.Module):

@@ -270,12 +270,15 @@ def test_the_program_refuses_what_it_does_not_build(cfg, program):
 #: gradient (one row of 40 tokens, bfloat16, the weights of seed 7; object
 #: addresses left out) as the commit before the rule's exact path traced it:
 #: with the kernels interpreted, and with the rule ``chunked``, attention
-#: dense and the experts by ``ragged_dot``.
+#: dense and the experts by ``ragged_dot``. Pinned again when the SwiGLUs
+#: (the dense layer and the shared experts) went through ``ops.swiglu``: the
+#: two printed programs differ from the ones pinned before in those
+#: equations alone.
 BEFORE_THE_EXACT_PATH = {
     'pallas:interpret':
-        '7684aff1cebd53545879276fdc45ee1cb21316ca3272043a0271f1759ee0e2cf',
+        '5a4f518e6c7d1908769a2ad34ca155af5dcf540de63bcd6bca4fdfd3aec98cbb',
     'chunked':
-        '22c8f2e40ee97e1f0d531006b44e5a72ea1d394ecd0c17ecc3c3ae635c2a1a61'}
+        'bd1c334f1b9f1ea4b52d97c377a5e540352b2c6b4bc2caa509cc034a08faf3e4'}
 
 
 @pytest.mark.parametrize('impl', sorted(BEFORE_THE_EXACT_PATH))

@@ -575,3 +575,25 @@ def test_what_solar_s_layers_add_lands_in_named_parts():
         mine = [r for r in rows if scope in r['path'].split('/')]
         assert mine, scope
         assert {r['part'] for r in mine} == {part}, (scope, mine[:3])
+
+
+@pytest.mark.parametrize('family, parts', [
+    ('HybridLM', {'ffn.dense'}),
+    ('LingHybridLM', {'ffn.dense', 'ffn.shared'}),
+])
+def test_the_swiglu_s_backward_lands_in_its_sub_layer(family, parts):
+    """``ops.swiglu``'s backward pass, its six products and the one
+    elementwise pass between them (scope ``silu``), is charged to the part
+    of the sub-layer that holds it, the dense feed-forward's or the shared
+    expert's, in the backward pass: the benchmark's ``step.*`` readers see
+    it where they saw the dense layers' gradients."""
+    rows = [r for r in family_table(family).values() if r['pass'] == 'backward']
+    elementwise = [r for r in rows if 'silu' in r['path'].split('/')]
+    assert {r['part'] for r in elementwise} == parts, elementwise[:3]
+    products = [r for r in rows if re.search(
+        r'/(?:mlp|shared)/(?:gate|up|down)/dot_general$', r['path'])]
+    assert {r['part'] for r in products} == parts, products[:3]
+    # every SwiGLU of the step: three weight gradients and three products
+    # of the input and hidden gradients each
+    assert len(products) >= 6 * len(
+        {r['path'].rsplit('/', 3)[0] for r in products})
